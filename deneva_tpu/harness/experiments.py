@@ -231,9 +231,7 @@ def repair_ablation(quick: bool) -> list[Config]:
     curve is committed txns/s and abort rate vs the baseline
     (rep_salvaged_cnt / rep_fallback_cnt in each [summary] line break
     the ratio down).  Quick mode shrinks shapes for CI; the full mode
-    keeps the paper shape for chip runs (capture provenance recorded by
-    ``python bench.py --experiment repair_ablation``, the PR 2 wedge
-    protocol)."""
+    keeps the paper shape for chip runs."""
     base = paper_base(quick).replace(zipf_theta=0.9, read_perc=0.1,
                                      write_perc=0.9)
     if quick:

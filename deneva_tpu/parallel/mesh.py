@@ -31,18 +31,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AXIS = "part"
 
 
-def shard_map_fn():
-    """``jax.shard_map`` where it exists; the ``jax.experimental``
-    spelling on older jax (0.4.x exposes it only there — same
-    signature).  Call sites take the function from here instead of
-    hard-binding one location."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 _current: dict = {"mesh": None}
 
 

@@ -37,13 +37,15 @@ TAG_RING = 1 << 22            # outstanding-tag ring per client: must
 
 
 class ClientNode:
-    def __init__(self, cfg: Config, endpoints: str, platform: str | None):
+    def __init__(self, cfg: Config, endpoints: str, platform: str,
+                 setup_wait_s: float = wire.SETUP_WAIT_S):
         import jax
-        if platform:
-            jax.config.update("jax_platforms", platform)
+        from deneva_tpu.runtime.jaxenv import init_jax
+        self.info = init_jax(platform)
         from deneva_tpu.workloads import get_workload
 
         self.cfg = cfg
+        self.setup_wait_s = setup_wait_s
         self.me = cfg.node_id                   # transport id (>= node_cnt)
         self.n_srv = cfg.node_cnt
         self.n_all = (self.n_srv + cfg.client_node_cnt
@@ -53,7 +55,7 @@ class ClientNode:
                                   msg_size_max=cfg.msg_size_max,
                                   send_threads=cfg.send_thread_cnt,
                                   recv_threads=cfg.rem_thread_cnt)
-        self.tp.start()
+        self.tp.start(int(setup_wait_s * 1000))
         if cfg.net_delay_us:
             self.tp.set_delay_us(int(cfg.net_delay_us))
         # ---- fault mode (chaos harness): the open loop must DEGRADE
@@ -468,11 +470,11 @@ class ClientNode:
             self._route(*m, lat_arr)
             timeout_us = 0
 
-    def barrier(self, timeout_s: float = 60.0) -> None:
+    def barrier(self) -> None:
         lat = self.stats.arr("client_client_latency")
         wire.run_barrier(self.tp, self.me, self.n_all,
                          lambda s, r, p: self._route(s, r, p, lat),
-                         f"client {self.me}", timeout_s)
+                         f"client {self.me}", self.setup_wait_s)
 
     def _resend_sweep(self) -> None:
         """Repair message loss: batches older than fault_resend_us with

@@ -56,6 +56,10 @@ OWNER: dict[str, str] = {
     "_failover": DISPATCH, "_dedup_on": DISPATCH, "_kill_at": DISPATCH,
     "_committed_cap": DISPATCH, "log_path": DISPATCH,
     "repl_ids": DISPATCH, "_overlap": DISPATCH, "_own_installed": DISPATCH,
+    "setup_wait_s": DISPATCH,
+    # device / set-up / compile record the launcher carries back
+    # (runtime/jaxenv.py; filled in __init__ and at run()'s closing)
+    "info": DISPATCH, "_compiles": SHARED, "_compiles_meas": DISPATCH,
     # engine state + counters (dispatch-loop positions only)
     "db": DISPATCH, "cc_state": DISPATCH, "dev_stats": DISPATCH,
     "stats": DISPATCH, "_ph": DISPATCH, "_retry_hist": DISPATCH,

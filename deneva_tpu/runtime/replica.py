@@ -40,8 +40,16 @@ _EPOCH_HDR = struct.Struct("<Iq")   # magic, epoch (prefix of logger._FRAME)
 
 
 class ReplicaNode:
-    def __init__(self, cfg: Config, endpoints: str):
+    def __init__(self, cfg: Config, endpoints: str, platform: str = "cpu",
+                 setup_wait_s: float = wire.SETUP_WAIT_S):
         self.cfg = cfg
+        self.setup_wait_s = setup_wait_s
+        # a plain log sink never touches JAX; a geo follower replays
+        # through the per-epoch jit and picks its device like any node
+        self.info: dict = {}
+        if cfg.geo:
+            from deneva_tpu.runtime.jaxenv import init_jax
+            self.info = init_jax(platform)
         self.me = cfg.node_id
         self.n_srv = cfg.node_cnt
         self.n_cl = cfg.client_node_cnt
@@ -75,7 +83,7 @@ class ReplicaNode:
                                   msg_size_max=cfg.msg_size_max,
                                   send_threads=cfg.send_thread_cnt,
                                   recv_threads=cfg.rem_thread_cnt)
-        self.tp.start()
+        self.tp.start(int(setup_wait_s * 1000))
         if cfg.net_delay_us:
             self.tp.set_delay_us(int(cfg.net_delay_us))
         if self._geo and cfg.geo_wan_us:
@@ -96,9 +104,9 @@ class ReplicaNode:
         self._tl_last = 0.0
         self._tl_serve_last = 0.0
 
-    def barrier(self, timeout_s: float = 60.0) -> None:
+    def barrier(self) -> None:
         wire.run_barrier(self.tp, self.me, self.n_all, self._handle,
-                         f"replica {self.me}", timeout_s)
+                         f"replica {self.me}", self.setup_wait_s)
 
     def _handle(self, src: int, rtype: str, payload: bytes) -> None:
         if rtype == "LOG_MSG":

@@ -239,8 +239,16 @@ def decode_shutdown(buf: bytes) -> int:
 # ---- INIT_DONE barrier (reference sim_manager setup counting,
 # `system/sim_manager.cpp:95-100`) ---------------------------------------
 
+# Set-up waits (peer dial + INIT_DONE barrier) of a node nobody
+# supervises — a test posing as the cluster.  Under the launcher every
+# node waits as long as the launcher itself does (`launch.run_cluster`
+# passes its own limit and ends the run when a peer dies), because a
+# peer's cold compile of the epoch-group program sits inside both waits.
+SETUP_WAIT_S = 120.0
+
+
 def run_barrier(tp, me: int, n_all: int, on_other, who: str,
-                timeout_s: float = 60.0) -> None:
+                timeout_s: float = SETUP_WAIT_S) -> None:
     """Send INIT_DONE to every peer, then drain until all peers' INIT_DONEs
     arrive.  Non-barrier messages that race in early are handed to
     ``on_other(src, rtype, payload)`` so no protocol traffic is lost."""

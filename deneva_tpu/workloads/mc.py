@@ -166,8 +166,7 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
                for n, v in dbv.items()}
         return out, jax.lax.psum(st["read_checksum"], AXIS), st["write_cnt"]
 
-    from deneva_tpu.parallel.mesh import shard_map_fn
-    out_db, cks, wcnt = shard_map_fn()(
+    out_db, cks, wcnt = jax.shard_map(
         body, mesh=mesh,
         in_specs=(db_spec, P(), P(), P(), P()),
         out_specs=(db_spec, P(), P()))(db, queries, commit, order, level)

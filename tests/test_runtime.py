@@ -190,9 +190,10 @@ def test_replica_barrier_timeout_and_clean_close(tmp_path):
     t = threading.Thread(target=run_peer)
     t.start()
     node = ReplicaNode(cfg, eps)
+    node.setup_wait_s = 0.8     # the barrier's wait; the dial has passed
     try:
         with pytest.raises(TimeoutError, match="replica 1"):
-            node.barrier(timeout_s=0.8)
+            node.barrier()
     finally:
         node.close()
         peer_box["ev"].set()
