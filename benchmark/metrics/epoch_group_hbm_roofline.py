@@ -1,0 +1,18 @@
+"""The epoch group program against the chip's memory roofline: bytes the
+algorithm needs per epoch (`peaks.ycsb_epoch_bytes`: committed accesses x
+row bytes) over the bytes the chip could move in the device time an epoch
+took.  The program is memory-bound (gathers and scatters of 100 B rows);
+empty lanes, plans and sorts are overhead and lower the share."""
+
+
+def read(ctx):
+    t, s, info = ctx["trace"], ctx["server"]["summary"], ctx["server"]["info"]
+    if not t or not t.get("epochs") or not s.get("epoch_cnt"):
+        return None
+    peak = ctx["peaks"].peak_for(info["kind"])
+    f = ctx["fields"]
+    need = ctx["peaks"].ycsb_epoch_bytes(
+        info["run_commit_cnt"] / s["epoch_cnt"], int(f["req_per_query"]),
+        int(f["tup_size"]))
+    return 100.0 * need / (t["group_busy_s"] / t["epochs"]
+                           * peak["hbm_bytes_per_s"])
