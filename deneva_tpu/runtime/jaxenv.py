@@ -53,6 +53,14 @@ def place_compile_cache() -> None:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    # the epoch programs' `jax.named_scope`s are what a device trace is
+    # read by (benchmark/phase_reduce.py), and JAX's cache key leaves
+    # op metadata out by default: a hit would then hand back an
+    # executable with the scopes of whoever compiled it first — a
+    # parent commit's, none at all — and the profiler would report
+    # those.  With the metadata in the key a program whose scopes (or
+    # source lines) changed compiles anew instead.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def init_jax(platform: str) -> dict:

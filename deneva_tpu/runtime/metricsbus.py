@@ -234,8 +234,9 @@ class CritLedger:
         self.stage_s = {s: 0.0 for s in CRIT_STAGES}
         self.quorum_s, self.quorum_n = 0.0, 0
 
-    def lap(self, stage: str) -> None:
-        now = self._time()
+    def lap(self, stage: str, now: float | None = None) -> None:
+        if now is None:
+            now = self._time()
         self.stage_s[stage] += now - self._t_mark
         self._t_mark = now
 

@@ -62,13 +62,18 @@ OWNER: dict[str, str] = {
     "info": DISPATCH, "_compiles": SHARED, "_compiles_meas": DISPATCH,
     # engine state + counters (dispatch-loop positions only)
     "db": DISPATCH, "cc_state": DISPATCH, "dev_stats": DISPATCH,
-    "stats": DISPATCH, "_ph": DISPATCH, "_retry_hist": DISPATCH,
+    "stats": DISPATCH, "_retry_hist": DISPATCH,
     "_wait_hist": DISPATCH, "_uniq_aborts": DISPATCH,
     "_dup_admits": DISPATCH, "_reacks": DISPATCH,
     "stop_epoch": DISPATCH, "measure_epoch": DISPATCH,
     "_resume_epoch": DISPATCH, "_inflight": DISPATCH,
     "_t_meas": DISPATCH, "_uniq_meas": DISPATCH, "_retry_meas": DISPATCH,
     "_wait_meas": DISPATCH,
+    # the dispatch loop's stage clock (runtime/stages.py): every
+    # boundary call, its window snapshot and the queue's running count
+    # are dispatch-thread positions (the retire WORKER opens only a
+    # `srv.prefetch` trace span, which keeps no state)
+    "clk": DISPATCH, "_stage_meas": DISPATCH, "_queue_txns": DISPATCH,
     # admission / retirement queues and dedup state (adm = the overload
     # tier's AdmissionController: admits in _route, pops in the
     # contribution paths, ticks at group boundaries — all dispatch)

@@ -49,6 +49,7 @@ from deneva_tpu.runtime.telemetry import (ST_ADMIT, ST_BATCH, ST_HOLD,
                                           V_COMMIT, V_DEFER, V_SALVAGE,
                                           telemetry_line)
 from deneva_tpu.runtime.native import NativeTransport
+from deneva_tpu.runtime.stages import StageClock, span as stage_span
 from deneva_tpu.stats import Stats
 
 _TAG_MASK = np.int64((1 << 40) - 1)
@@ -78,7 +79,20 @@ def _make_epoch_body(cfg: Config, wl, be):
     never an input to any verdict, and the log replay path feeds the
     recorded epoch numbers back so replay reproduces the observations
     bit for bit.
+
+    The phases of an epoch carry `jax.named_scope`s — metadata only, the
+    compiled program is the same — so a device trace can say which phase
+    an operation belongs to whatever the compiler numbers it: `ep.plan`
+    (the workload's plan, the access batch, and on the forwarding path
+    the plan sort of `forward_verdict`, which validates nothing),
+    `ep.validate` (incidence + the backend's sweep: sweep backends
+    only), `ep.read` / `ep.write` (inside the workload's executor, where
+    the gather and the scatter are), `ep.levels`, `ep.repair`,
+    `ep.stats` (counters); the group program adds `ep.decode` and
+    `grp.pack`.  Nested scopes read innermost-first: a gather under
+    `ep.levels/ep.read` is a read.
     """
+    import jax
     import jax.numpy as jnp
 
     import dataclasses as _dc
@@ -98,15 +112,17 @@ def _make_epoch_body(cfg: Config, wl, be):
         srounds = None
         dens = None
         aud_out = None
-        rank = jnp.arange(b, dtype=jnp.int32)
-        planned = wl.plan(db, query)
-        batch = AccessBatch(
-            table_ids=planned["table_ids"], keys=planned["keys"],
-            is_read=planned["is_read"], is_write=planned["is_write"],
-            valid=planned["valid"], ts=ts, rank=rank, active=active,
-            order_free=gate_order_free(cfg, be,
-                                       planned.get("order_free")))
-        forced = forced_sentinel_mask(batch) if cfg.ycsb_abort_mode else None
+        with jax.named_scope("ep.plan"):
+            rank = jnp.arange(b, dtype=jnp.int32)
+            planned = wl.plan(db, query)
+            batch = AccessBatch(
+                table_ids=planned["table_ids"], keys=planned["keys"],
+                is_read=planned["is_read"], is_write=planned["is_write"],
+                valid=planned["valid"], ts=ts, rank=rank, active=active,
+                order_free=gate_order_free(cfg, be,
+                                           planned.get("order_free")))
+            forced = forced_sentinel_mask(batch) \
+                if cfg.ycsb_abort_mode else None
         inc = None
         if forwarding:
             fbatch = batch if forced is None else _dc.replace(
@@ -124,7 +140,8 @@ def _make_epoch_body(cfg: Config, wl, be):
                     forced = forced & ~(verdict.abort | verdict.defer)
                 exec_commit = verdict.commit
             else:
-                verdict, fwd = forward_verdict(fbatch)
+                with jax.named_scope("ep.plan"):
+                    verdict, fwd = forward_verdict(fbatch)
                 # forward_verdict never aborts/defers, so the CC-retry
                 # filter below is a no-op here — applied anyway to keep
                 # the forced semantics identical to Engine.step (and
@@ -145,13 +162,15 @@ def _make_epoch_body(cfg: Config, wl, be):
                 # pure replicated function of the merged batch, so the
                 # three verdict planes stay bit-identical across nodes
                 # and dp shardings — exactly CALVIN's cluster shape.
-                verdict, cc_state = be.validate(cfg, cc_state, batch,
-                                                None, stats=stats)
+                with jax.named_scope("ep.validate"):
+                    verdict, cc_state = be.validate(cfg, cc_state, batch,
+                                                    None, stats=stats)
             else:
-                inc = build_conflict_incidence(cfg, be, batch,
-                                               batch.order_free)
-                verdict, cc_state = be.validate(cfg, cc_state, batch,
-                                                inc)
+                with jax.named_scope("ep.validate"):
+                    inc = build_conflict_incidence(cfg, be, batch,
+                                                   batch.order_free)
+                    verdict, cc_state = be.validate(cfg, cc_state, batch,
+                                                    inc)
             if cfg.audit_mutate:
                 # seeded edge-derivation fault (the audit plane's
                 # anti-inert knob): flipped losers execute and ack like
@@ -178,9 +197,10 @@ def _make_epoch_body(cfg: Config, wl, be):
                                 if be.alg == CCAlg.DGCC else None)
             elif be.chained:
                 from deneva_tpu.engine.step import _run_levels
-                db, stats = _run_levels(cfg, wl, db, query, exec_commit,
-                                        verdict, stats,
-                                        level_exec=be.alg != CCAlg.DGCC)
+                with jax.named_scope("ep.levels"):
+                    db, stats = _run_levels(
+                        cfg, wl, db, query, exec_commit, verdict, stats,
+                        level_exec=be.alg != CCAlg.DGCC)
             else:
                 db = wl.execute(db, query, exec_commit, verdict.order,
                                 stats)
@@ -192,9 +212,10 @@ def _make_epoch_body(cfg: Config, wl, be):
             if cfg.repair and be.repair_rule is not None \
                     and not be.chained:
                 from deneva_tpu.engine.repair import run_repair
-                db, cc_state, verdict, rep, srounds = run_repair(
-                    cfg, wl, be, db, query, batch, inc, verdict,
-                    cc_state, stats, exec_commit, forced)
+                with jax.named_scope("ep.repair"):
+                    db, cc_state, verdict, rep, srounds = run_repair(
+                        cfg, wl, be, db, query, batch, inc, verdict,
+                        cc_state, stats, exec_commit, forced)
                 exec_commit = exec_commit | rep
         if cfg.metrics:
             # metrics bus: per-partition observed-conflict density off
@@ -205,19 +226,21 @@ def _make_epoch_body(cfg: Config, wl, be):
             dens = conflict_density(cfg, batch, planned["owner"], inc)
         # forced txns complete (acked + released by the caller via the
         # commit mask) but count as aborts, exactly like the engine
-        commit = exec_commit & active
-        done = commit if forced is None else (commit | (forced & active))
-        abort = verdict.abort & active
-        if forced is not None:
-            abort = abort | (forced & active)
-        defer = verdict.defer & active
-        stats = dict(stats)
-        stats["total_txn_commit_cnt"] += commit.sum(dtype=jnp.uint32)
-        stats["total_txn_abort_cnt"] += abort.sum(dtype=jnp.uint32)
-        stats["defer_cnt"] += defer.sum(dtype=jnp.uint32)
-        from deneva_tpu.engine.step import count_by_type
-        count_by_type(stats, wl, query, commit, abort)
-        rep = jnp.zeros_like(done) if rep is None else rep & active
+        with jax.named_scope("ep.stats"):
+            commit = exec_commit & active
+            done = commit if forced is None \
+                else (commit | (forced & active))
+            abort = verdict.abort & active
+            if forced is not None:
+                abort = abort | (forced & active)
+            defer = verdict.defer & active
+            stats = dict(stats)
+            stats["total_txn_commit_cnt"] += commit.sum(dtype=jnp.uint32)
+            stats["total_txn_abort_cnt"] += abort.sum(dtype=jnp.uint32)
+            stats["defer_cnt"] += defer.sum(dtype=jnp.uint32)
+            from deneva_tpu.engine.step import count_by_type
+            count_by_type(stats, wl, query, commit, abort)
+            rep = jnp.zeros_like(done) if rep is None else rep & active
         if cfg.audit:
             # isolation audit (cc/base.audit_observe): dependency
             # observations of the FINAL committed set — pure
@@ -332,7 +355,8 @@ def make_dist_group(cfg: Config, wl, be, width: int, n_scalars: int):
         else:
             active, ts, keys, types, scal = xs
             ep = None
-        query = wl.from_wire_dev(keys, types, scal)
+        with jax.named_scope("ep.decode"):
+            query = wl.from_wire_dev(keys, types, scal)
         db, cc_state, stats, done, abort, defer, rep, dens, aud = body(
             db, cc_state, stats, active, ts, query, epoch=ep)
         outs = (done[sl], abort[sl], defer[sl], rep[sl])
@@ -385,7 +409,8 @@ def make_dist_group(cfg: Config, wl, be, width: int, n_scalars: int):
             xs = xs + (epochs_f,)
         (db, cc_state, stats), masks = jax.lax.scan(
             scan_body, (db, cc_state, stats), xs)
-        planes = jnp.stack([pack(masks[i]) for i in range(n_planes)])
+        with jax.named_scope("grp.pack"):
+            planes = jnp.stack([pack(masks[i]) for i in range(n_planes)])
         out = (db, cc_state, stats, planes)
         if cfg.metrics:
             # int32[C, P] per-epoch density beside the packed planes
@@ -1038,6 +1063,10 @@ class ServerNode:
         # new_txn_queue: FIFO of (src client id, query block)
         self.pending: deque[tuple[int, wire.QueryBlock]] = deque()
         self.retry = _RetryQueue(cfg.backoff)
+        # transactions waiting in `pending` + the retry queue: a running
+        # count kept at append/pop (the stage clock samples it at every
+        # group boundary), never a walk of the queues
+        self._queue_txns = 0
         self.blob_buf: dict[int, dict] = {}
         self.vote_buf: dict[int, dict] = {}
         self.vote2_buf: dict[int, dict] = {}
@@ -1217,6 +1246,7 @@ class ServerNode:
                     (np.int64(src) << 40) | (blk.tags & _TAG_MASK),
                     ST_ADMIT)
             self.pending.append((src, blk))
+            self._queue_txns += len(blk)
         elif rtype == "EPOCH_BLOB":
             if self._fencing:
                 # fence envelope: the sender's map_version precedes the
@@ -1631,6 +1661,7 @@ class ServerNode:
             tss.append(np.full(len(use), -1, np.int64))   # -1 = stamp me
             dfcs.append(np.zeros(len(use), np.int32))
             n += len(use)
+        self._queue_txns -= n
         if self.adm is not None and n > n_retry:
             # admission-queue delay ledger: these fresh rows just left
             # the bounded queue for epoch formation
@@ -1735,6 +1766,7 @@ class ServerNode:
             counts.append(np.zeros(m, np.int32))
             dfcs.append(np.zeros(m, np.int32))
             n += m
+        self._queue_txns -= n
         if self.adm is not None and n > n_retry:
             # same admission-delay ledger position as _contribution
             self.adm.on_pop(n - n_retry, time.monotonic_ns() // 1000)
@@ -1859,8 +1891,9 @@ class ServerNode:
         sends only — at the same loop position as the serial path."""
         import jax
 
-        pk = np.asarray(jax.device_get(group["masks"]))
-        planes = np.unpackbits(pk, axis=-1, bitorder="little")
+        with stage_span("prefetch", group["eps"][0][0]):
+            pk = np.asarray(jax.device_get(group["masks"]))
+            planes = np.unpackbits(pk, axis=-1, bitorder="little")
         bools = planes[:, :, :self._plane_n].astype(bool)
         done, abort, defer = bools[0], bools[1], bools[2]
         rep = bools[3] if self._repair else None
@@ -2079,11 +2112,9 @@ class ServerNode:
                 raise TimeoutError(
                     f"server {self.me}: epoch {epoch} {what} wait: have "
                     f"{sorted(have)}")
-        wait = time.monotonic() - t0
-        self._ph["idle"] += wait
-        # the caller's process-time span covers this whole round: carve
-        # the network wait back out so idle + process partition wall time
-        self._ph["process"] -= wait
+        # the caller's dispatch stage covers this whole round: charge the
+        # network wait to the peer wait, so the stages partition wall time
+        self.clk.shift("dispatch", "collect", time.monotonic() - t0)
 
     # -- blob barrier ----------------------------------------------------
     def _exp_peers(self, epoch: int) -> list[int]:
@@ -2589,13 +2620,16 @@ class ServerNode:
             tl.spans.append(("ctrl", time.monotonic() - t0))
 
     # -- verdict retirement (the back half of an epoch) ------------------
-    def _retire(self, group: dict, tl) -> None:
+    def _retire(self, group: dict) -> None:
         """Fetch a dispatched group's commit masks (ONE host<->device
         transfer for all its epochs) and finish its host-side epoch work:
         CL_RSP acks, retry/backoff routing, exact unique-abort counts."""
         import jax
 
-        t0 = time.monotonic()
+        # blocked on the device's verdicts (the prefetch future or the
+        # d2h itself): the host waiting for the device
+        epoch0 = group["eps"][0][0]
+        t_wait = self.clk.enter("retire_wait", epoch0)
         pre = None
         rep = None
         if group.get("prefetch") is not None:
@@ -2610,9 +2644,7 @@ class ServerNode:
             self._prefetch_polls += 1
             if group["prefetch"].done():
                 self._prefetch_hits += 1
-            tw = time.monotonic()
             done, abort, defer, rep, pre = group["prefetch"].result()
-            self._prefetch_wait_s += time.monotonic() - tw
         elif group["packed"]:
             # uint8 bit-planes [3 (+1 repaired), C, pb/8]; the d2h copy
             # was started asynchronously at dispatch, so this normally
@@ -2626,7 +2658,11 @@ class ServerNode:
         else:
             done, abort, defer = (np.asarray(m)
                                   for m in jax.device_get(group["masks"]))
-        self._ph["process"] += time.monotonic() - t0
+        # the work after the wait: ack splits, CL_RSP sends, retry routing
+        t_retire = self.clk.enter("retire", epoch0)
+        if group.get("prefetch") is not None:
+            # the mesh track's wait ledger: this group's `retire_wait`
+            self._prefetch_wait_s += t_retire - t_wait
         dens = None
         if self.mbus is not None and group.get("dens_dev") is not None:
             # per-epoch density plane [C, P]: same d2h cadence as the
@@ -2786,6 +2822,7 @@ class ServerNode:
             restart = ab | df
             if restart.any():
                 idx = np.where(restart)[0]
+                self._queue_txns += len(idx)
                 # aborts bump the backoff counter; defers restart free
                 # (with their wait budget spent recorded)
                 self.retry.push(block.take(idx), abort_cnt[idx] + ab[idx],
@@ -2801,8 +2838,8 @@ class ServerNode:
             f.result()
         if group.get("feed") is not None:
             self._feed_free.append(group["feed"])
-        if tl:
-            tl.mark("retire")
+        self.clk.enter("other")
+        self.clk.retired(group["t_dispatch"])
 
     # -- the pipelined epoch-group loop ----------------------------------
     def run(self, progress=None) -> Stats:
@@ -2898,14 +2935,19 @@ class ServerNode:
         measured = None     # counter snapshot at measure start
         epoch0 = self._resume_epoch   # 0, or the recovery group boundary
         tl = _Timeline() if cfg.debug_timeline else None
-        # phase-time ledger (reference Stats_thd worker time breakdowns,
-        # `statistics/stats.h:116` worker_idle_time etc.)
-        self._ph = {"idle": 0.0, "process": 0.0}
+        # the ONE stage clock of this loop (runtime/stages.py): always-on
+        # seconds per stage (the reference Stats_thd worker time
+        # breakdowns, `statistics/stats.h:116` worker_idle_time etc., are
+        # read from it), the `[timeline]` and `[crit]` ledgers where
+        # armed, and `srv.<stage>` spans in a live profiler trace
+        self.clk = clk = StageClock(
+            tl, self.mbus.crit if self.mbus is not None else None)
+        self._stage_meas = None     # its snapshot at measure start
         inflight: deque[dict] = deque()
         self._inflight = inflight   # reassignment replay drains wire futs
         while True:
-            if tl:
-                tl.mark("loop")
+            # ---- stage: drain (inbound decode) --------------------------
+            clk.begin_pass(epoch0, C, self._queue_txns)
             if self._kill_at is not None and epoch0 >= self._kill_at:
                 # injected crash (fault_kill "node:epoch"): die at this
                 # group boundary with no teardown or farewell — but let
@@ -2975,7 +3017,9 @@ class ServerNode:
             # (controller) and apply pending cutovers at their boundary
             if self._elastic and self._elastic_tick(epoch0) and tl:
                 tl.mark("membership")
-            # ---- assemble + broadcast contributions for the group -----
+            # ---- stage: admit — assemble + broadcast contributions for
+            # the group (its inner drains are part of it) ---------------
+            clk.enter("admit")
             eps: list[tuple[int, wire.QueryBlock, np.ndarray, np.ndarray,
                             np.ndarray]] = []
 
@@ -3005,7 +3049,8 @@ class ServerNode:
                 for i in range(C):
                     e = epoch0 + i
                     if i:
-                        self._drain()
+                        with stage_span("drain", epoch0):
+                            self._drain()
                     block, abort_cnt, birth_ts, dfc = \
                         self._contribution_into(e, fs, i)
                     if self.tel is not None:
@@ -3027,7 +3072,8 @@ class ServerNode:
                     for i in range(C):
                         e = epoch0 + i
                         if i:
-                            self._drain()
+                            with stage_span("drain", epoch0):
+                                self._drain()
                         block, abort_cnt, birth_ts, dfc = \
                             self._contribution(e)
                         if self.tel is not None:
@@ -3050,21 +3096,16 @@ class ServerNode:
                 for f in futs:
                     f.result()   # surface any _bcast error after the drain
                 self.tp.flush()
-            if tl:
-                tl.mark("admit")
-            if self.mbus is not None:
-                # critical-path ledger: everything since the last pass
-                # closed (inbound drain, heartbeats, contribution
-                # assembly, admission, blob broadcast staging) is the
-                # admit stage
-                self.mbus.crit.lap("admit")
-            # ---- collect every peer's contributions -------------------
-            t0 = time.monotonic()
+            # ---- stage: collect every peer's contributions (on the
+            # `[crit]` ledger everything since the last pass closed —
+            # inbound drain, heartbeats, contribution assembly, admission,
+            # blob broadcast staging — is its admit stage, and the
+            # blob-collect wait its wire stage: peer skew + network
+            # transit show up exactly here) ------------------------------
+            clk.enter("collect")
+            decode_s = 0.0
             if self._overlap:
                 decode_s = self._collect_into(eps, fs)
-                # decode work is process time, not network wait
-                self._ph["idle"] += time.monotonic() - t0 - decode_s
-                self._ph["process"] += decode_s
             else:
                 merged_parts = []
                 for e, block, _, birth_ts, _ in eps:
@@ -3072,14 +3113,11 @@ class ServerNode:
                     parts = self.blob_buf.pop(e, {})
                     parts[self.me] = (block, birth_ts)
                     merged_parts.append(parts)
-                self._ph["idle"] += time.monotonic() - t0
-            if tl:
-                tl.mark("collect")
-            if self.mbus is not None:
-                # the blob-collect wait: the wire stage (peer skew +
-                # network transit show up exactly here)
-                self.mbus.crit.lap("wire")
-            # ---- build the stacked device feed [C, b] -----------------
+            # ---- stage: feed — build the stacked device feed [C, b],
+            # submit the log records ------------------------------------
+            clk.enter("feed")
+            # decode work is feed building, not network wait
+            clk.shift("collect", "feed", decode_s)
             if self._overlap:
                 keys, types, scal = fs["keys"], fs["types"], fs["scal"]
                 tags, ts_np, active_np = fs["tags"], fs["ts"], fs["active"]
@@ -3142,7 +3180,10 @@ class ServerNode:
                             self._fenced_send(r, "LOG_MSG", framed)
             # ---- dispatch (async for merged mode; the masks are fetched
             # at retirement, K groups later) ----------------------------
-            t_step = time.monotonic()
+            # stage: dispatch (`device_put` + the group call + the d2h
+            # starts; `[crit]` charges feed + dispatch to its device
+            # stage: a recompile spike is the jit watchdog's signature)
+            t_dispatch = clk.enter("dispatch")
             if self.vote_mode:
                 # C == K == 1: the vote exchange is a host round trip
                 # inside the epoch, so this path stays synchronous
@@ -3212,16 +3253,11 @@ class ServerNode:
                 # d2h round trip synchronously
                 if hasattr(masks, "copy_to_host_async"):
                     masks.copy_to_host_async()
-            self._ph["process"] += time.monotonic() - t_step
-            if tl:
-                tl.mark("dispatch")
-            if self.mbus is not None:
-                # feed build + device dispatch: the device stage (a
-                # recompile spike is the jit watchdog's signature)
-                self.mbus.crit.lap("device")
+            clk.enter("other")
             group = {"eps": eps, "masks": masks, "packed": packed,
                      "feed": fs, "wire_futs": wire_futs,
-                     "dens_dev": dens_dev, "aud_dev": aud_dev}
+                     "dens_dev": dens_dev, "aud_dev": aud_dev,
+                     "t_dispatch": t_dispatch}
             if self._full_planes and packed:
                 # full-plane retirement needs every slice's packed tags
                 # (copied: overlap feed buffers recycle under the group)
@@ -3241,11 +3277,10 @@ class ServerNode:
                 # aborts) cover exactly the same epoch prefix as the
                 # device counters
                 while inflight:
-                    self._retire(inflight.popleft(), tl)
-                t0 = time.monotonic()
+                    self._retire(inflight.popleft())
                 measured = {k: np.asarray(v) for k, v in
                             jax.device_get(self.dev_stats).items()}
-                self._ph["process"] += time.monotonic() - t0
+                self._stage_meas = clk.snapshot()
                 self._t_meas = time.monotonic()
                 self._compiles_meas = self._compiles.snapshot()[0]
                 self._uniq_meas = self._uniq_aborts
@@ -3254,11 +3289,7 @@ class ServerNode:
                 self._rep_meas = self._rep_salvaged
             # ---- retire the oldest group once K are in flight ----------
             while len(inflight) > K - 1:
-                self._retire(inflight.popleft(), tl)
-            if self.mbus is not None:
-                # verdict retirement (mask fetch + acks + retry
-                # routing): the retire stage
-                self.mbus.crit.lap("retire")
+                self._retire(inflight.popleft())
             now = time.monotonic()
             if progress and group_end % 50 < C:
                 progress(self, group_end)
@@ -3348,9 +3379,19 @@ class ServerNode:
                     self.magg.tick(time.monotonic())
             if self.stop_epoch is not None and group_end >= self.stop_epoch:
                 while inflight:
-                    self._retire(inflight.popleft(), tl)
+                    self._retire(inflight.popleft())
                 break
             epoch0 += C
+        clk.end()
+        # the stage clock's WINDOW values and the window's wall on
+        # readings of its own (an empty window when the measurement
+        # never began, like the counters below)
+        stage_whole = clk.since(None)
+        if self._stage_meas is None:
+            self._stage_meas = clk.snapshot()
+        stage_keys = clk.since(self._stage_meas)
+        stage_keys["stage_wall_time"] = \
+            time.monotonic() - self._t_meas if measured is not None else 0.0
         epochs_run = epoch0 + C
         # final: release remaining group-committed acks, notify clients
         # and my replica, emit summary
@@ -3426,8 +3467,17 @@ class ServerNode:
             d = (hist - base).astype(np.float64)
             if d.sum() > 0:
                 st.arr(name).extend_weighted(np.arange(len(d)), d)
-        st.set("worker_idle_time", self._ph["idle"])
-        st.set("worker_process_time", self._ph["process"])
+        # the reference's two worker times, whole run, off the stage
+        # clock: idle = blocked on the peers' blobs and votes; process =
+        # feed build, dispatch, and — as it always did — the blocked wait
+        # for the device's verdicts (`stage_retire_wait_time` beside it
+        # says how much of "process" is that wait)
+        st.set("worker_idle_time", stage_whole["stage_collect_time"])
+        st.set("worker_process_time", sum(
+            stage_whole[f"stage_{s}_time"]
+            for s in ("feed", "dispatch", "retire_wait")))
+        for k, v in stage_keys.items():
+            st.set(k, v)
         chaos = cfg.faults_enabled
         if chaos:
             st.set("dup_admit_cnt", float(self._dup_admits))
@@ -3628,8 +3678,9 @@ class _Timeline:
         self.t = time.monotonic()
         self.spans: list[tuple[str, float]] = []
 
-    def mark(self, name: str) -> None:
-        now = time.monotonic()
+    def mark(self, name: str, now: float | None = None) -> None:
+        if now is None:
+            now = time.monotonic()
         self.spans.append((name, now - self.t))
         self.t = now
 

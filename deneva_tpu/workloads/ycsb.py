@@ -126,27 +126,37 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     otherwise inserts inside
     every scatter lowering (~0.6 ms at 655k lanes on v5e — the roofline
     ledger's sort.67).  The legacy trash-steered scatter remains for
-    non-monotone slot maps (mono=False)."""
-    vals = jnp.take(f0, jnp.where(p.is_read, slots, trash), axis=0)
-    if f0.ndim == 2:
-        nbytes = f0.shape[1]
-        vals = jnp.where((p.fwd >= 0)[:, None],
-                         _field_bytes(p.keys, p.fwd, nbytes), vals)
-        cks = jnp.sum(jnp.where(p.is_read[:, None], vals, 0),
-                      dtype=jnp.uint32)
-    else:
-        vals = jnp.where(p.fwd >= 0, _field_fingerprint(p.keys, p.fwd), vals)
-        cks = jnp.sum(jnp.where(p.is_read, vals, 0), dtype=jnp.uint32)
-    if mono:
-        wslot, wkey, wrank = _mono_winner_lanes(p, slots, f0.shape[0])
-        wvals = _field_bytes(wkey, wrank, f0.shape[1]) if f0.ndim == 2 \
-            else _field_fingerprint(wkey, wrank).astype(f0.dtype)
-        f0 = f0.at[wslot].set(wvals, mode="drop", indices_are_sorted=True)
-    else:
-        wvals = _field_bytes(p.keys, p.rank, f0.shape[1]) if f0.ndim == 2 \
-            else _field_fingerprint(p.keys, p.rank).astype(f0.dtype)
-        f0 = f0.at[jnp.where(p.win, slots, trash)].set(wvals)
-    return f0, cks, p.is_write.sum(dtype=jnp.uint32)
+    non-monotone slot maps (mono=False).
+
+    The two halves carry the epoch's `ep.read` / `ep.write` scopes
+    (metadata: `runtime/server._make_epoch_body`)."""
+    with jax.named_scope("ep.read"):
+        vals = jnp.take(f0, jnp.where(p.is_read, slots, trash), axis=0)
+        if f0.ndim == 2:
+            nbytes = f0.shape[1]
+            vals = jnp.where((p.fwd >= 0)[:, None],
+                             _field_bytes(p.keys, p.fwd, nbytes), vals)
+            cks = jnp.sum(jnp.where(p.is_read[:, None], vals, 0),
+                          dtype=jnp.uint32)
+        else:
+            vals = jnp.where(p.fwd >= 0,
+                             _field_fingerprint(p.keys, p.fwd), vals)
+            cks = jnp.sum(jnp.where(p.is_read, vals, 0), dtype=jnp.uint32)
+    with jax.named_scope("ep.write"):
+        if mono:
+            wslot, wkey, wrank = _mono_winner_lanes(p, slots, f0.shape[0])
+            wvals = _field_bytes(wkey, wrank, f0.shape[1]) \
+                if f0.ndim == 2 \
+                else _field_fingerprint(wkey, wrank).astype(f0.dtype)
+            f0 = f0.at[wslot].set(wvals, mode="drop",
+                                  indices_are_sorted=True)
+        else:
+            wvals = _field_bytes(p.keys, p.rank, f0.shape[1]) \
+                if f0.ndim == 2 \
+                else _field_fingerprint(p.keys, p.rank).astype(f0.dtype)
+            f0 = f0.at[jnp.where(p.win, slots, trash)].set(wvals)
+        wcnt = p.is_write.sum(dtype=jnp.uint32)
+    return f0, cks, wcnt
 
 
 class YCSBWorkload:
@@ -564,65 +574,70 @@ class YCSBWorkload:
             db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
             return db
         full = self.cfg.sim_full_row
-        slots = self._local_slots(db, q.keys)                  # [n, R]
-        act = mask[:, None] & jnp.ones_like(q.is_write)
-        # reads: gather F0, fold into checksum (keeps the load alive);
-        # through .gather so the multi-chip McTableView can interpose
-        rmask = act & ~q.is_write
-        rslots = jnp.where(rmask, slots, tab.capacity)
-        vals = tab.gather(rslots, ("F0",))["F0"]
-        ver: VersionRing | None = db.get(VER_TABLE)
-        if ver is not None:
-            # MVCC stale reads serve HISTORICAL bytes (row_mvcc.cpp:
-            # 172-196), reconstructed from the version law f(key, v*)
-            # (VersionRing.select_version).  Verdict.order is the
-            # serialization ts, with read-only txns forced to 0 (they
-            # serialize AT the epoch snapshot, so the live gather already
-            # gave them the right version — exclude them by reading "at
-            # +inf").  Safe because real txn ts are >= 1 by construction
-            # — pool.next_seq starts at 1 and server._contribution raises
-            # on a sub-1 stamp.
-            big = jnp.int32(jnp.iinfo(jnp.int32).max)
-            ver_ts = jnp.where(order > 0, order, big)
-            # ONE row gather serves both the version select here and the
-            # push below (each gather against the big ring array costs a
-            # fixed ~ms-scale pass on v5e; see VersionRing.rows).  Raw
-            # slots: write-lane rows are garbage for select (masked by
-            # rmask downstream) and exactly what push needs.
-            ver_rows = ver.rows(slots)
-            vstar, has = ver.version_from(
-                ver_rows, jnp.broadcast_to(ver_ts[:, None], slots.shape))
-            if full:
-                vals = jnp.where(has[..., None],
-                                 _field_bytes(q.keys, vstar,
-                                              self.cfg.tup_size), vals)
+        # the epoch's `ep.read` / `ep.write` scopes (metadata:
+        # `runtime/server._make_epoch_body`) live here, where the row
+        # gather and the row scatter are
+        with jax.named_scope("ep.read"):
+            slots = self._local_slots(db, q.keys)                  # [n, R]
+            act = mask[:, None] & jnp.ones_like(q.is_write)
+            # reads: gather F0, fold into checksum (keeps the load alive);
+            # through .gather so the multi-chip McTableView can interpose
+            rmask = act & ~q.is_write
+            rslots = jnp.where(rmask, slots, tab.capacity)
+            vals = tab.gather(rslots, ("F0",))["F0"]
+            ver: VersionRing | None = db.get(VER_TABLE)
+            if ver is not None:
+                # MVCC stale reads serve HISTORICAL bytes (row_mvcc.cpp:
+                # 172-196), reconstructed from the version law f(key, v*)
+                # (VersionRing.select_version).  Verdict.order is the
+                # serialization ts, with read-only txns forced to 0 (they
+                # serialize AT the epoch snapshot, so the live gather already
+                # gave them the right version — exclude them by reading "at
+                # +inf").  Safe because real txn ts are >= 1 by construction
+                # — pool.next_seq starts at 1 and server._contribution raises
+                # on a sub-1 stamp.
+                big = jnp.int32(jnp.iinfo(jnp.int32).max)
+                ver_ts = jnp.where(order > 0, order, big)
+                # ONE row gather serves both the version select here and the
+                # push below (each gather against the big ring array costs a
+                # fixed ~ms-scale pass on v5e; see VersionRing.rows).  Raw
+                # slots: write-lane rows are garbage for select (masked by
+                # rmask downstream) and exactly what push needs.
+                ver_rows = ver.rows(slots)
+                vstar, has = ver.version_from(
+                    ver_rows, jnp.broadcast_to(ver_ts[:, None], slots.shape))
+                if full:
+                    vals = jnp.where(has[..., None],
+                                     _field_bytes(q.keys, vstar,
+                                                  self.cfg.tup_size), vals)
+                else:
+                    vals = jnp.where(has, _field_fingerprint(q.keys, vstar),
+                                     vals)
+            rm = rmask[..., None] if full else rmask
+            stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
+                jnp.where(rm, vals, 0), dtype=jnp.uint32)
+        with jax.named_scope("ep.write"):
+            # writes: new payload versioned by serialization order
+            wmask = (act & q.is_write).reshape(-1)
+            wslots = jnp.where(act & q.is_write, slots, tab.capacity).reshape(-1)
+            worder = jnp.broadcast_to(order[:, None], slots.shape).reshape(-1)
+            if level_exec:
+                # caller guarantees the committed set is write-conflict-free
+                # (chained sub-round): cross-txn duplicates cannot exist and
+                # a txn's own duplicate lanes write identical values, so the
+                # scatter-max tournament is redundant
+                win = wmask
             else:
-                vals = jnp.where(has, _field_fingerprint(q.keys, vstar),
-                                 vals)
-        rm = rmask[..., None] if full else rmask
-        stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
-            jnp.where(rm, vals, 0), dtype=jnp.uint32)
-        # writes: new payload versioned by serialization order
-        wmask = (act & q.is_write).reshape(-1)
-        wslots = jnp.where(act & q.is_write, slots, tab.capacity).reshape(-1)
-        worder = jnp.broadcast_to(order[:, None], slots.shape).reshape(-1)
-        if level_exec:
-            # caller guarantees the committed set is write-conflict-free
-            # (chained sub-round): cross-txn duplicates cannot exist and
-            # a txn's own duplicate lanes write identical values, so the
-            # scatter-max tournament is redundant
-            win = wmask
-        else:
-            win = last_writer(wslots, worder, wmask, tab.capacity)
-        wvals = _field_bytes(q.keys.reshape(-1), worder, self.cfg.tup_size) \
-            if full else _field_fingerprint(q.keys.reshape(-1), worder)
-        db = dict(db)
-        if ver is not None:
-            # record each winning overwrite's commit ts (one winner per
-            # row per epoch, so each row advances at most one ring slot);
-            # no value bytes — reads reconstruct via f(key, v*)
-            db[VER_TABLE] = ver.push_rows(
-                ver_rows.reshape(-1, ver.depth), wslots, worder, win)
-        db[TABLE] = tab.scatter(wslots, {"F0": wvals}, mask=win)
-        stats["write_cnt"] = stats["write_cnt"] + wmask.sum(dtype=jnp.uint32)
+                win = last_writer(wslots, worder, wmask, tab.capacity)
+            wvals = _field_bytes(q.keys.reshape(-1), worder, self.cfg.tup_size) \
+                if full else _field_fingerprint(q.keys.reshape(-1), worder)
+            db = dict(db)
+            if ver is not None:
+                # record each winning overwrite's commit ts (one winner per
+                # row per epoch, so each row advances at most one ring slot);
+                # no value bytes — reads reconstruct via f(key, v*)
+                db[VER_TABLE] = ver.push_rows(
+                    ver_rows.reshape(-1, ver.depth), wslots, worder, win)
+            db[TABLE] = tab.scatter(wslots, {"F0": wvals}, mask=win)
+            stats["write_cnt"] = stats["write_cnt"] + wmask.sum(dtype=jnp.uint32)
         return db

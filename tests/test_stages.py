@@ -1,0 +1,343 @@
+"""The dispatch loop's stage clock (`runtime/stages.py`) and the epoch
+program's named phases: one recorder call per loop boundary, window
+sums that add up to the wall, the `[timeline]` / `[crit]` lines as they
+were, `srv.*` spans in a live profiler trace, and `jax.named_scope`s that
+change no verdict and no byte of the table."""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from deneva_tpu.runtime import stages
+from deneva_tpu.runtime.stages import STAGES, StageClock
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    """A hand-driven `time.monotonic` for the recorder: t[0] is now."""
+    t = [100.0]
+    monkeypatch.setattr(stages.time, "monotonic", lambda: t[0])
+    return t
+
+
+def _pass(clk, t, epoch0, secs):
+    """One dispatch pass spending ``secs[stage]`` in each stage."""
+    clk.begin_pass(epoch0, 4, queue_txns=10)
+    for s in ("drain", "admit", "collect", "feed", "dispatch"):
+        t[0] += secs.get(s, 0.0)
+        nxt = {"drain": "admit", "admit": "collect", "collect": "feed",
+               "feed": "dispatch", "dispatch": "other"}[s]
+        at = clk.enter(nxt)
+        if nxt == "dispatch":
+            t_disp = at
+    clk.enter("retire_wait", epoch0)
+    t[0] += secs.get("retire_wait", 0.0)
+    clk.enter("retire", epoch0)
+    t[0] += secs.get("retire", 0.0)
+    clk.enter("other")
+    clk.retired(t_disp)
+    t[0] += secs.get("other", 0.0)
+
+
+def test_every_second_of_the_loop_belongs_to_one_stage(clock):
+    clk = StageClock()
+    secs = dict(drain=0.01, admit=0.2, collect=0.03, feed=0.004,
+                dispatch=0.05, retire_wait=0.6, retire=0.1, other=0.006)
+    t0 = clock[0]
+    for g in range(3):
+        _pass(clk, clock, 4 * g, secs)
+    clk.end()
+    got = clk.since(None)
+    for s in STAGES:
+        assert got[f"stage_{s}_time"] == pytest.approx(3 * secs[s])
+    assert sum(got[f"stage_{s}_time"] for s in STAGES) == \
+        pytest.approx(clock[0] - t0)
+    assert got["stage_epoch_cnt"] == 12
+    # every key of the line has a reader: no per-stage or group counts
+    assert not [k for k in got if k.endswith("_cnt")
+                and k != "stage_epoch_cnt"]
+    assert got["queue_txn_mean"] == 10
+    # dispatch start -> end of the group's retire
+    assert got["pipeline_time_mean"] == pytest.approx(0.05 + 0.6 + 0.1)
+
+
+def test_the_window_snapshot_leaves_the_warm_up_out(clock):
+    clk = StageClock()
+    _pass(clk, clock, 0, dict(collect=0.5, admit=0.1))   # warm-up only
+    clock[0] += 0.25                  # the snapshot falls inside `other`
+    snap = clk.snapshot()
+    t_edge = clock[0]
+    _pass(clk, clock, 4, dict(admit=0.2, retire=0.3, other=0.05))
+    clk.end()
+    win = clk.since(snap)
+    assert win["stage_collect_time"] == 0.0       # ran only in warm-up
+    assert win["stage_admit_time"] == pytest.approx(0.2)
+    assert win["stage_other_time"] == pytest.approx(0.05)
+    assert win["stage_epoch_cnt"] == 4
+    assert sum(win[f"stage_{s}_time"] for s in STAGES) == \
+        pytest.approx(clock[0] - t_edge)
+    # the whole run still holds both
+    assert clk.since(None)["stage_collect_time"] == pytest.approx(0.5)
+
+
+def test_an_empty_window_reads_zero_and_divides_by_nothing(clock):
+    clk = StageClock()
+    _pass(clk, clock, 0, dict(admit=0.1))
+    clk.end()
+    win = clk.since(clk.snapshot())
+    assert all(v == 0 for v in win.values())
+
+
+def test_a_wait_inside_a_working_stage_is_recharged(clock):
+    clk = StageClock()
+    _pass(clk, clock, 0, dict(dispatch=1.0, collect=0.5))
+    clk.shift("dispatch", "collect", 0.4)     # a vote wait inside dispatch
+    clk.shift("collect", "feed", 0.1)         # blob decode inside collect
+    clk.end()
+    got = clk.since(None)
+    assert got["stage_dispatch_time"] == pytest.approx(0.6)
+    assert got["stage_collect_time"] == pytest.approx(0.8)
+    assert got["stage_feed_time"] == pytest.approx(0.1)
+
+
+def test_one_boundary_call_feeds_the_timeline_and_the_crit_ledger(
+        clock, capsys):
+    """The recorder's reading is the older ledgers' reading: the
+    `[timeline]` line keeps its span names and order, the `[crit]`
+    stages sum to its wall, both lines parse as before."""
+    from deneva_tpu.harness.parse import parse_metrics
+    from deneva_tpu.harness.timeline import parse_timeline
+    from deneva_tpu.runtime import metricsbus as MB
+    from deneva_tpu.runtime.server import _Timeline
+
+    tl, crit = _Timeline(), MB.CritLedger(0)
+    crit._time = stages.time.monotonic
+    crit.reset()
+    clk = StageClock(tl, crit)
+    secs = dict(drain=0.001, admit=0.010, collect=0.040, feed=0.002,
+                dispatch=0.018, retire_wait=0.300, retire=0.020,
+                other=0.700)
+    _pass(clk, clock, 8, secs)
+    clk.begin_pass(12, 4, 0)          # closes the pass: `loop` on the line
+    tl.emit(0, 12)
+    assert crit.end_pass(12) is not None          # 1 s passed: emits
+    out = capsys.readouterr().out.splitlines()
+    line = [ln for ln in out if ln.startswith("[timeline] ")][0]
+    assert re.findall(r"(\w+)=[0-9.]+ms", line) == [
+        "loop", "admit", "collect", "dispatch", "retire", "loop"]
+    ph = parse_timeline(out)[0]["phases"]
+    assert ph["admit"] == pytest.approx(11.0)     # drain + admit
+    assert ph["collect"] == pytest.approx(40.0)
+    assert ph["dispatch"] == pytest.approx(20.0)  # feed + dispatch
+    assert ph["retire"] == pytest.approx(320.0)   # the wait + the work
+    c = [r for r in parse_metrics(out) if r["family"] == "crit"][0]
+    assert c["admit_ms"] == pytest.approx(11.0)
+    assert c["wire_ms"] == pytest.approx(40.0)
+    assert c["device_ms"] == pytest.approx(20.0)
+    assert c["retire_ms"] == pytest.approx(320.0)
+    assert sum(c[s + "_ms"] for s in MB.CRIT_STAGES) == \
+        pytest.approx(c["wall_ms"])
+    assert c["gate"] == "other"
+
+
+def test_the_spans_land_in_a_live_profiler_trace(tmp_path):
+    """With a profiler session open, every stage is a `srv.<stage>` span
+    on the host plane tagged with its group; with none, nothing is
+    written and nothing fails."""
+    import glob
+
+    import jax
+    clk = StageClock()
+    _pass(clk, [0.0], 0, {})                      # no session: no-ops
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        clk.begin_pass(40, 4, 0)
+        clk.enter("admit")
+        with stages.span("drain", 40):
+            pass
+        clk.enter("dispatch")
+        clk.enter("retire_wait", 36)
+        clk.enter("retire", 36)
+        clk.end()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    prof = jax.profiler.ProfileData.from_file(path)
+    got = [(e.name, dict(e.stats).get("group"))
+           for p in prof.planes if p.name.startswith("/host:")
+           for ln in p.lines for e in ln.events
+           if e.name.startswith("srv.")]
+    assert sorted(got) == sorted([
+        ("srv.drain", 40), ("srv.admit", 40), ("srv.drain", 40),
+        ("srv.dispatch", 40), ("srv.retire_wait", 36), ("srv.retire", 36)])
+
+
+# ---- a short served run --------------------------------------------------
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One server + one client through the launcher, `[timeline]` and
+    `[crit]` armed (OCC on a hot toy table: aborts and retries too)."""
+    d = tmp_path_factory.mktemp("served")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "-m", "deneva_tpu.runtime.launch", "--node_cnt=1",
+         "--client_node_cnt=1", "--cc_alg=OCC", "--epoch_batch=128",
+         "--synth_table_size=4096", "--req_per_query=4", "--max_accesses=4",
+         "--zipf_theta=0.9", "--warmup_secs=0.5", "--done_secs=1.5",
+         "--debug_timeline=true", "--metrics=true", f"--log_dir={d}"],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return p.stdout.splitlines()
+
+
+def test_window_stage_seconds_add_up_to_the_window_wall(served):
+    from deneva_tpu.stats import parse_summary
+    line = [ln for ln in served if ln.startswith("node 0 (server): ")][0]
+    s = parse_summary(line.split(": ", 1)[1])
+    total = sum(s[f"stage_{st}_time"] for st in STAGES)
+    assert total == pytest.approx(s["stage_wall_time"], rel=0.01)
+    # the window, not the run: warm-up is a quarter of the run
+    assert s["stage_wall_time"] == pytest.approx(s["total_runtime"],
+                                                 rel=0.02)
+    assert s["stage_epoch_cnt"] < s["epoch_cnt"]
+    assert s["pipeline_time_mean"] > 0
+    assert s["stage_retire_wait_time"] > 0 and s["stage_admit_time"] > 0
+    assert s["queue_txn_mean"] >= 0
+    # the reference's two worker times are read off the same clock
+    assert s["worker_process_time"] >= s["stage_retire_wait_time"]
+    assert s["worker_idle_time"] >= 0
+
+
+def test_timeline_and_crit_lines_are_what_they_were(served):
+    from deneva_tpu.harness.parse import parse_metrics
+    from deneva_tpu.harness.timeline import parse_timeline
+    rows = parse_timeline(served)
+    assert len(rows) > 10
+    steady = [ln for ln in served
+              if ln.startswith("[timeline] ") and " retire=" in ln]
+    loop = ["loop", "admit", "collect", "dispatch", "retire"]
+    # (a `crit_<gate>` ledger span may ride in front of a pass's marks)
+    assert steady and all(
+        [n for n in re.findall(r"(\w+)=[0-9.]+ms", ln) if n in loop][:5]
+        == loop for ln in steady)
+    crit = [r for r in parse_metrics(served) if r["family"] == "crit"]
+    assert crit
+    for r in crit:
+        stages_ms = sum(r[k + "_ms"] for k in
+                        ("admit", "wire", "device", "retire", "other"))
+        assert stages_ms == pytest.approx(r["wall_ms"], rel=0.05, abs=0.1)
+
+
+# ---- the named phases ----------------------------------------------------
+
+# recorded from the parent tree (commit 3393394, before any scope
+# existed) by this same seeded feed: scopes are metadata, so the commit
+# and abort counts, the verdict planes and the table's digest are these
+PARENT = {
+    "TPU_BATCH": dict(
+        commits=1392, aborts=0, writes=2827, planes=15064,
+        digest="5ed0474b85d6e6053fe5bec7509574bb3eac139bde94c3712cd83aa7"
+               "c9209fed"),
+    "OCC": dict(
+        commits=488, aborts=904, writes=986, planes=15064,
+        digest="d2a3765f0d1c16e53df6d2524ac16a4a004da8355fa9df382f0b9481"
+               "3eeef707"),
+}
+SCOPES = {
+    "TPU_BATCH": {"ep.decode", "ep.plan", "ep.read", "ep.write", "ep.stats",
+                  "grp.pack"},
+    "OCC": {"ep.decode", "ep.plan", "ep.validate", "ep.read", "ep.write",
+            "ep.stats", "grp.pack"},
+}
+
+
+@pytest.mark.parametrize("cc_alg", ["TPU_BATCH", "OCC"])
+def test_group_program_carries_its_scopes_and_the_parents_answers(cc_alg):
+    import jax
+
+    from deneva_tpu.cc import get_backend
+    from deneva_tpu.config import Config
+    from deneva_tpu.engine.step import init_device_stats
+    from deneva_tpu.runtime.logger import state_digest
+    from deneva_tpu.runtime.server import make_dist_group
+    from deneva_tpu.workloads import get_workload
+
+    cfg = Config.from_args([f"--{k}={v}" for k, v in dict(
+        workload="YCSB", cc_alg=cc_alg, node_cnt=1, sim_full_row="true",
+        synth_table_size=4096, tup_size=100, epoch_batch=128,
+        pipeline_epochs=4, conflict_buckets=512, req_per_query=4,
+        max_accesses=4, zipf_theta=0.9).items()]
+        ).replace(node_id=0, part_cnt=1)
+    wl, be = get_workload(cfg), get_backend(cfg.cc_alg)
+    C, b = 4, 128
+    k, t, s = (np.asarray(x) for x in wl.to_wire(
+        wl.generate(jax.random.PRNGKey(7), C * b * 3)))
+    group = make_dist_group(cfg, wl, be, k.shape[1], s.shape[1])
+    db, cc = wl.load(), be.init_state(cfg)
+    st = init_device_stats(len(wl.txn_type_names))
+    rng = np.random.default_rng(11)
+    text = None
+    for g in range(3):
+        sl = slice(g * C * b, (g + 1) * C * b)
+        active = rng.random(C * b) < 0.9
+        ts = (np.arange(C * b) + 1 + g * C * b).astype(np.int32)
+        feed = (active, ts, k[sl].reshape(-1), t[sl].reshape(-1),
+                s[sl].reshape(-1))
+        if text is None:
+            text = group.lower(db, cc, st, *feed).compile().as_text()
+        db, cc, st, planes = group(db, cc, st, *feed)
+    got = dict(commits=int(st["total_txn_commit_cnt"]),
+               aborts=int(st["total_txn_abort_cnt"]),
+               writes=int(st["write_cnt"]),
+               planes=int(np.asarray(planes).astype(np.int64).sum()),
+               digest=state_digest(db))
+    assert got == PARENT[cc_alg]
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    found = {part for n in names for part in n.split("/")
+             if part.startswith(("ep.", "grp."))}
+    assert found == SCOPES[cc_alg]
+    # the scopes sit where the work is: the row gather under ep.read,
+    # the row scatter under ep.write, inside the scanned body
+    assert any("while/body" in n and "ep.read" in n and "gather" in n
+               for n in names)
+    assert any("while/body" in n and "ep.write" in n and "scatter" in n
+               for n in names)
+
+
+def test_the_thread_registry_knows_the_recorder():
+    from deneva_tpu.runtime.ownercheck import DISPATCH, OWNER
+    assert "_ph" not in OWNER                 # the inline sums are gone
+    for attr in ("clk", "_stage_meas", "_queue_txns", "_prefetch_wait_s"):
+        assert OWNER[attr] == DISPATCH
+    # the retire worker keeps no ledger: its `srv.prefetch` span is all
+    assert "_prefetch_s" not in OWNER and "_prefetch_meas" not in OWNER
+
+
+def test_scope_reading_survives_in_the_compile_cache_key():
+    """Op metadata is what the device trace is read by, so it has to be
+    part of the persistent cache's key (JAX leaves it out by default: a
+    hit would hand back the first compiler's scopes)."""
+    src = open(os.path.join(ROOT, "deneva_tpu", "runtime",
+                            "jaxenv.py")).read()
+    assert '"jax_compilation_cache_include_metadata_in_key", True' in src
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import json, sys; sys.path.insert(0, sys.argv[1]);"
+         "import jax;"
+         "from deneva_tpu.runtime import jaxenv;"
+         "jax.config.update('jax_platforms', 'tpu');"
+         "jaxenv.place_compile_cache();"
+         "print(json.dumps(jax.config."
+         "jax_compilation_cache_include_metadata_in_key))", ROOT],
+        capture_output=True, text=True, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert out.returncode == 0, out.stderr[-1500:]
+    assert json.loads(out.stdout.strip().splitlines()[-1]) is True
