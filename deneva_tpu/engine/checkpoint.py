@@ -35,8 +35,9 @@ import numpy as np
 #              device stats — the metrics bus's contention signal);
 #          8 = round-17 (isolation audit plane: audit_edge_cnt/
 #              audit_drop_cnt device counters, and with audit armed the
-#              db pytree gains the __audit__ version-stamp tables).
-SCHEMA_VERSION = 8
+#              db pytree gains the __audit__ version-stamp tables);
+#          9 = PR 26 (write_scatter_lanes device counter).
+SCHEMA_VERSION = 9
 
 
 def save_state(path: str, state) -> None:

@@ -85,6 +85,10 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1) -> dict:
         "total_txn_commit_cnt": z(), "total_txn_abort_cnt": z(),
         "unique_txn_abort_cnt": z(),
         "defer_cnt": z(), "write_cnt": z(), "read_checksum": z(),
+        # lanes handed to YCSB's F0 scatter (ops/scatter.
+        # scatter_winner_rows): against write_cnt and the epoch's lane
+        # count it says how far the winner compaction engages
+        "write_scatter_lanes": z(),
         # commit latency in epochs, PER TXN TYPE (round-4: the
         # reference's per-txn StatsArr families, stats_array.cpp);
         # the driver calibrates buckets to wall seconds per chunk

@@ -8,7 +8,8 @@ managers (`concurrency_control/*`, dispatched from `storage/row.cpp:197-310`).
 
 from deneva_tpu.ops.hashing import bucket_hash, combine_key  # noqa: F401
 from deneva_tpu.ops.sampling import HotSet, Zipfian, uniform_keys  # noqa: F401
-from deneva_tpu.ops.scatter import last_writer  # noqa: F401
+from deneva_tpu.ops.scatter import (compact_winners,  # noqa: F401
+                                    last_writer, scatter_winner_rows)
 from deneva_tpu.ops.forward import (ForwardPlan,  # noqa: F401
                                     commit_all_verdict, forward_plan,
                                     forward_plan_flat, forward_verdict,

@@ -3447,6 +3447,9 @@ class ServerNode:
         for k in ("total_txn_commit_cnt", "total_txn_abort_cnt",
                   "defer_cnt", "write_cnt"):
             st.set(k, float(final[k] - measured[k]))
+        st.set("write_scatter_lane_cnt",
+               float(final["write_scatter_lanes"]
+                     - measured["write_scatter_lanes"]))
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):
                 key = f"{fam}_by_type"

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deneva_tpu.ops.scatter import scatter_winner_rows
 from deneva_tpu.storage.catalog import TableSchema
 
 
@@ -122,6 +123,19 @@ class DeviceTable:
         for n, v in updates.items():
             cols[n] = cols[n].at[slots].set(v.astype(cols[n].dtype))
         return self._replace(columns=cols)
+
+    def scatter_winners(self, name: str, slots: jax.Array, win: jax.Array,
+                        carry: tuple, value_fn, after):
+        """Row scatter of the ``win`` lanes alone into column ``name``
+        (`deneva_tpu.ops.scatter.scatter_winner_rows`: values computed
+        from ``carry`` after compaction; the trash row is never written;
+        ``after`` = what was read from the column, ordered first).
+        Returns (table, lanes handed to the scatter, after)."""
+        col, lanes, after = scatter_winner_rows(
+            self.columns[name], slots, win, carry, value_fn, self.capacity,
+            after)
+        return (self._replace(columns={**self.columns, name: col}), lanes,
+                after)
 
     def scatter_add(self, slots: jax.Array, updates: dict[str, jax.Array],
                     mask: jax.Array | None = None) -> "DeviceTable":

@@ -100,6 +100,12 @@ class McTableView:
         loc, _ = self._loc(slots)
         return self._with(self.local.scatter(loc, updates, mask=mask))
 
+    def scatter_winners(self, name, slots, win, carry, value_fn, after):
+        loc, _ = self._loc(slots)
+        local, lanes, after = self.local.scatter_winners(
+            name, loc, win, carry, value_fn, after)
+        return self._with(local), lanes, after
+
     def scatter_add(self, slots, updates, mask=None) -> "McTableView":
         loc, _ = self._loc(slots)
         return self._with(self.local.scatter_add(loc, updates, mask=mask))
@@ -152,8 +158,8 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
         me = jax.lax.axis_index(AXIS)
         dbv = {n: (McTableView(t, me) if t.mc_parts > 1 else t)
                for n, t in db.items()}
-        st = {"read_checksum": jnp.zeros((), jnp.uint32),
-              "write_cnt": jnp.zeros((), jnp.uint32)}
+        st = {k: jnp.zeros((), jnp.uint32) for k in
+              ("read_checksum", "write_cnt", "write_scatter_lanes")}
         if chained:
             for lvl in range(n_levels if n_levels is not None
                              else cfg.exec_subrounds):
@@ -164,12 +170,17 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
             dbv = wl.execute(dbv, queries, commit, order, st)
         out = {n: (v.assemble() if isinstance(v, McTableView) else v)
                for n, v in dbv.items()}
-        return out, jax.lax.psum(st["read_checksum"], AXIS), st["write_cnt"]
+        # lanes issued differ per chip (each compacts its own winners)
+        return (out, jax.lax.psum(st["read_checksum"], AXIS),
+                st["write_cnt"],
+                jax.lax.psum(st["write_scatter_lanes"], AXIS))
 
-    out_db, cks, wcnt = jax.shard_map(
+    out_db, cks, wcnt, lanes = jax.shard_map(
         body, mesh=mesh,
         in_specs=(db_spec, P(), P(), P(), P()),
-        out_specs=(db_spec, P(), P()))(db, queries, commit, order, level)
+        out_specs=(db_spec, P(), P(), P()))(db, queries, commit, order,
+                                            level)
     stats["read_checksum"] = stats["read_checksum"] + cks
     stats["write_cnt"] = stats["write_cnt"] + wcnt
+    stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + lanes
     return out_db

@@ -11,7 +11,9 @@ TPU shape: the table is a `DeviceTable` (SoA, fingerprint strings), the
 primary index is the identity `DenseIndex` (YCSB keys are dense,
 `ycsb_wl.cpp:70-74`), queries are generated on device per epoch, and
 execute is one gather (reads, checksummed into stats so XLA cannot
-dead-code them) plus one last-writer scatter (writes).
+dead-code them) plus one last-writer scatter (writes); with full rows
+(`sim_full_row`) only the final writers' lanes reach that scatter,
+compacted first (`ops.scatter.scatter_winner_rows`).
 
 Multi-partition control (`FIRST_PART_LOCAL`, `PART_PER_TXN`, MPR
 `ycsb_query.cpp:303-376`) maps to the mesh build: keys are striped
@@ -28,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deneva_tpu.config import CCAlg, Config
-from deneva_tpu.ops import HotSet, Zipfian, forward_plan, last_writer
+from deneva_tpu.ops import (HotSet, Zipfian, forward_plan, last_writer,
+                            scatter_winner_rows)
 from deneva_tpu.storage.catalog import parse_schema
 from deneva_tpu.storage.index import DenseIndex, SortedIndex
 from deneva_tpu.storage.table import DeviceTable, VersionRing, to_mc_layout
@@ -109,24 +112,32 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     single-chip `execute` and each shard of `execute_mc` so their
     semantics cannot diverge: reads gather F0 (forwarded lanes take
     f(key, writer rank) instead), the checksum folds over reads, and
-    only final writers scatter.  Returns (f0', checksum, write_cnt) —
-    the caller decides whether the scalars need a psum.
+    only final writers scatter.  Returns (f0', checksum, write_cnt,
+    scatter lanes) — the caller decides whether the scalars need a psum.
 
     ``f0`` is uint32[N] in fingerprint mode or uint8[N, S] under
     SIM_FULL_ROW — the full-row branch moves the real payload bytes, so
     benchmark numbers measure reference-width HBM traffic.
 
-    ``mono`` (callers with key-monotone slot maps, i.e. every current
-    caller: slot order follows the plan's sorted key order and masked
-    lanes steer to a trash at/above the top): the write scatter hands
-    XLA MONOTONE, pre-sorted indices — ``cummax`` carries the latest
-    winner's slot into following lanes and two head-propagation scans
-    carry its (key, rank) so the duplicate lanes rewrite the same value
-    idempotently (`_mono_winner_lanes`).  This skips the sort XLA
-    otherwise inserts inside
-    every scatter lowering (~0.6 ms at 655k lanes on v5e — the roofline
-    ledger's sort.67).  The legacy trash-steered scatter remains for
-    non-monotone slot maps (mono=False).
+    The write half has three forms.  Full rows under ``mono`` (callers
+    with key-monotone slot maps, i.e. every current caller: slot order
+    follows the plan's sorted key order and masked lanes steer to a
+    trash at/above the top): `ops.scatter.scatter_winner_rows` — the
+    final writers are compacted to the front and only they reach the
+    row scatter, in chunks whose number follows the epoch's winner
+    count (on v5e a row lane costs 71 ns one by one, or the whole column
+    a 4 ms pass with the sorted promise, whatever the lanes: PERF.md
+    section 6, PR 26); the trash row is never written.  Fingerprints
+    under ``mono``: every lane is issued with MONOTONE, pre-sorted
+    indices — ``cummax`` carries the latest winner's slot into following
+    lanes and two head-propagation scans carry its (key, rank), so the
+    duplicate lanes rewrite the same value idempotently
+    (`_mono_winner_lanes`); that skips the sort XLA otherwise inserts
+    inside a scatter's lowering (~0.6 ms at 655k lanes on v5e), and a
+    uint32 lane costs the same 4.9 ns either way (BASELINE.md).  The
+    legacy trash-steered scatter remains for non-monotone slot maps
+    (mono=False).  Returns the lanes handed to the scatter as a fourth
+    value (`stats["write_scatter_lanes"]`).
 
     The two halves carry the epoch's `ep.read` / `ep.write` scopes
     (metadata: `runtime/server._make_epoch_body`)."""
@@ -143,20 +154,24 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
                              _field_fingerprint(p.keys, p.fwd), vals)
             cks = jnp.sum(jnp.where(p.is_read, vals, 0), dtype=jnp.uint32)
     with jax.named_scope("ep.write"):
-        if mono:
+        lanes = jnp.uint32(slots.shape[0])
+        if mono and f0.ndim == 2:
+            f0, lanes, cks = scatter_winner_rows(
+                f0, slots, p.win, (p.keys, p.rank),
+                lambda k, r: _field_bytes(k, r, nbytes), n_rows=trash,
+                after=cks)
+        elif mono:
             wslot, wkey, wrank = _mono_winner_lanes(p, slots, f0.shape[0])
-            wvals = _field_bytes(wkey, wrank, f0.shape[1]) \
-                if f0.ndim == 2 \
-                else _field_fingerprint(wkey, wrank).astype(f0.dtype)
-            f0 = f0.at[wslot].set(wvals, mode="drop",
-                                  indices_are_sorted=True)
+            f0 = f0.at[wslot].set(
+                _field_fingerprint(wkey, wrank).astype(f0.dtype),
+                mode="drop", indices_are_sorted=True)
         else:
             wvals = _field_bytes(p.keys, p.rank, f0.shape[1]) \
                 if f0.ndim == 2 \
                 else _field_fingerprint(p.keys, p.rank).astype(f0.dtype)
             f0 = f0.at[jnp.where(p.win, slots, trash)].set(wvals)
         wcnt = p.is_write.sum(dtype=jnp.uint32)
-    return f0, cks, wcnt
+    return f0, cks, wcnt, lanes
 
 
 class YCSBWorkload:
@@ -487,20 +502,21 @@ class YCSBWorkload:
             # mono holds per shard: plan keys are sorted with non-owned
             # lanes already masked to the big sentinel, so slots ascend
             # toward the block-local trash at the top
-            f0, cks, wcnt = _forward_execute_f0(f0, p, slots, trash,
-                                                mono=True)
-            return (f0, jax.lax.psum(cks, AXIS),
-                    jax.lax.psum(wcnt, AXIS), dfr)
+            f0, cks, wcnt, lanes = _forward_execute_f0(
+                f0, p, slots, trash, mono=True)
+            return (f0, jax.lax.psum(cks, AXIS), jax.lax.psum(wcnt, AXIS),
+                    jax.lax.psum(lanes, AXIS), dfr)
 
-        f0, cks, wcnt, dfr = jax.shard_map(
+        f0, cks, wcnt, lanes, dfr = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(AXIS), P(), P(), P(), P(), P()),
-            out_specs=(P(AXIS), P(), P(),
+            out_specs=(P(AXIS), P(), P(), P(),
                        P(AXIS) if pair_cap else P()))(
                 tab.columns["F0"], batch.keys, batch.rank, batch.ts,
                 batch.is_write, valid)
         stats["read_checksum"] = stats["read_checksum"] + cks
         stats["write_cnt"] = stats["write_cnt"] + wcnt
+        stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + lanes
         db = dict(db)
         db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
         return db, dfr
@@ -565,11 +581,13 @@ class YCSBWorkload:
             # under part_cnt striping (or an elastic mask at n_parts>1)
             # non-owned keys hit miss_slot INTERLEAVED between owned
             # slots — not monotone
-            f0, cks, wcnt = _forward_execute_f0(
+            f0, cks, wcnt, lanes = _forward_execute_f0(
                 tab.columns["F0"], p, slots, tab.capacity,
                 mono=self.n_parts == 1)
             stats["read_checksum"] = stats["read_checksum"] + cks
             stats["write_cnt"] = stats["write_cnt"] + wcnt
+            stats["write_scatter_lanes"] = \
+                stats["write_scatter_lanes"] + lanes
             db = dict(db)
             db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
             return db
@@ -629,8 +647,6 @@ class YCSBWorkload:
                 win = wmask
             else:
                 win = last_writer(wslots, worder, wmask, tab.capacity)
-            wvals = _field_bytes(q.keys.reshape(-1), worder, self.cfg.tup_size) \
-                if full else _field_fingerprint(q.keys.reshape(-1), worder)
             db = dict(db)
             if ver is not None:
                 # record each winning overwrite's commit ts (one winner per
@@ -638,6 +654,22 @@ class YCSBWorkload:
                 # no value bytes — reads reconstruct via f(key, v*)
                 db[VER_TABLE] = ver.push_rows(
                     ver_rows.reshape(-1, ver.depth), wslots, worder, win)
-            db[TABLE] = tab.scatter(wslots, {"F0": wvals}, mask=win)
+            wkeys = q.keys.reshape(-1)
+            if full:
+                # the winners alone reach the row scatter, compacted, and
+                # the trash row is never written; the gather above (all
+                # of it is in the checksum) comes first (ops/scatter)
+                db[TABLE], lanes, stats["read_checksum"] = \
+                    tab.scatter_winners(
+                        "F0", wslots, win, (wkeys, worder),
+                        lambda k, o: _field_bytes(k, o, self.cfg.tup_size),
+                        after=stats["read_checksum"])
+            else:
+                db[TABLE] = tab.scatter(
+                    wslots, {"F0": _field_fingerprint(wkeys, worder)},
+                    mask=win)
+                lanes = jnp.uint32(wslots.shape[0])
             stats["write_cnt"] = stats["write_cnt"] + wmask.sum(dtype=jnp.uint32)
+            stats["write_scatter_lanes"] = \
+                stats["write_scatter_lanes"] + lanes
         return db

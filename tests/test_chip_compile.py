@@ -129,6 +129,37 @@ def test_served_group_compiles_for_v5e(name, over, one_chip, monkeypatch):
     assert table + cfg.pipeline_groups * per_group < HBM_BYTES
 
 
+@pytest.mark.parametrize("over", [
+    dict(), dict(cc_alg="OCC", epoch_batch=1024, max_txn_in_flight=1 << 17,
+                 client_batch_size=1024)], ids=["tpu_batch", "occ"])
+def test_full_row_column_is_written_in_place_inside_the_epoch_scan(
+        over, one_chip, monkeypatch):
+    """The winners-only row scatter (`ops.scatter.scatter_winner_rows`)
+    writes the column inside a loop inside a conditional inside the
+    epoch scan.  With the epoch's gather left unordered against it the
+    chip's compiler copied the whole column twice an EPOCH (my chip run,
+    PR 26: 5 ms of copies around 2.3 ms of scatter; its `after` argument
+    is the cure).  The only copies of the column are the entry
+    computation's two relayouts, once a GROUP, as at the parent."""
+    import re
+    cfg = served_cfg(sim_full_row="true", synth_table_size=1 << 21, **over)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    state, feed = _with_sharding((state, feed), one_chip)
+    compiled, _ = _compile(group, state["db"], state["cc_state"],
+                           state["stats"], *feed)
+    f0 = state["db"]["MAIN_TABLE"].columns["F0"]
+    shape = f"u8[{f0.shape[0]},{f0.shape[1]}]"
+    where = None
+    copies = []
+    for ln in compiled.as_text().splitlines():
+        head = re.match(r"^(ENTRY )?%[\w.\-]+ \(", ln)
+        if head:
+            where = "entry" if head.group(1) else "inner"
+        if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln):
+            copies.append(where)
+    assert copies and set(copies) == {"entry"}, copies
+
+
 def test_ycsb_loader_compiles_for_v5e(one_chip):
     """The YCSB loader as one program at the served 8M rows (on the chip
     it runs op by op; one program bounds what any of its ops needs)."""

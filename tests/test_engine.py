@@ -177,8 +177,8 @@ def test_forwarding_executor_equals_serial_execution():
     rank = np.arange(B, dtype=np.int32)
     order = jnp.asarray(rank)
     fwd = forward_plan(q.keys, order, q.is_write, jnp.ones((B, R), bool))
-    stats = {"read_checksum": jnp.zeros((), jnp.uint32),
-             "write_cnt": jnp.zeros((), jnp.uint32)}
+    stats = {k: jnp.zeros((), jnp.uint32) for k in
+             ("read_checksum", "write_cnt", "write_scatter_lanes")}
     db2 = wl.execute(dict(db), q, None, order, stats, fwd_rank=fwd)
     got_sum = int(stats["read_checksum"])
     got_f0 = np.asarray(db2["MAIN_TABLE"].columns["F0"])[:32]
@@ -197,6 +197,54 @@ def test_forwarding_executor_equals_serial_execution():
                     _field_fingerprint(keys[i, r], rank[i]))
     assert got_sum == sum_ref
     assert (got_f0 == f0).all()
+
+
+@pytest.mark.parametrize("site", ["forwarding", "masked"])
+def test_write_scatter_lanes_counts_the_lanes_the_row_scatter_was_handed(
+        site):
+    """`write_scatter_lanes` on one seeded full-row epoch: whole chunks
+    of N/32 lanes covering the epoch's final writers (one per written
+    key) — not the epoch's N lanes — at both call sites of
+    `ops.scatter.scatter_winner_rows`; the rows are serial execution's."""
+    import jax.numpy as jnp
+    from deneva_tpu.ops import forward_plan
+    from deneva_tpu.ops import scatter as sc
+    from deneva_tpu.workloads.ycsb import (YCSBQuery, YCSBWorkload,
+                                           _field_bytes)
+
+    rows = 32768
+    cfg = small_cfg(cc_alg="TPU_BATCH" if site == "forwarding" else "OCC",
+                    synth_table_size=rows, sim_full_row=True, tup_size=24,
+                    field_per_tuple=2, req_per_query=4, max_accesses=4,
+                    epoch_batch=64)
+    wl = YCSBWorkload(cfg)
+    db = wl.load()
+    rng = np.random.default_rng(26)
+    B, R = 64, 4
+    keys = rng.integers(0, 40, (B, R)).astype(np.int32)   # hot keys repeat
+    is_w = rng.random((B, R)) < 0.5
+    q = YCSBQuery(keys=jnp.asarray(keys), is_write=jnp.asarray(is_w))
+    order = jnp.arange(B, dtype=jnp.int32)
+    stats = {k: jnp.zeros((), jnp.uint32) for k in
+             ("read_checksum", "write_cnt", "write_scatter_lanes")}
+    if site == "forwarding":
+        fwd = forward_plan(q.keys, order, q.is_write, jnp.ones((B, R), bool))
+        db2 = wl.execute(dict(db), q, None, order, stats, fwd_rank=fwd)
+    else:
+        db2 = wl.execute(dict(db), q, jnp.ones((B,), bool), order, stats)
+    winners = len(np.unique(keys[is_w]))
+    chunk = -(-(B * R) // sc._CHUNKS)
+    assert chunk * sc._CHUNKS * sc._ROWS_PER_LANE < rows   # the loop
+    assert 0 < winners < int(is_w.sum())
+    assert int(stats["write_scatter_lanes"]) == chunk * -(-winners // chunk)
+    assert int(stats["write_cnt"]) == int(is_w.sum())
+    f0 = np.asarray(db["MAIN_TABLE"].columns["F0"]).copy()
+    for i in range(B):
+        for r in range(R):
+            if is_w[i, r]:
+                f0[keys[i, r]] = np.asarray(_field_bytes(keys[i, r], i, 24))
+    np.testing.assert_array_equal(
+        np.asarray(db2["MAIN_TABLE"].columns["F0"]), f0)
 
 
 @pytest.mark.parametrize("alg", ["TPU_BATCH", "NO_WAIT", "OCC"])

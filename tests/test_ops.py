@@ -293,12 +293,16 @@ def test_forward_execute_mono_scatter_matches_legacy():
     big = jnp.int32(np.iinfo(np.int32).max)
     slots = jnp.where(p.keys != big, p.keys, tab)     # identity index
     f0 = jnp.asarray(rng.integers(0, 2**32, tab + 1, dtype=np.uint32))
-    a_f0, a_cks, a_w = _forward_execute_f0(f0, p, slots, tab, mono=False)
-    b_f0, b_cks, b_w = _forward_execute_f0(f0, p, slots, tab, mono=True)
+    a_f0, a_cks, a_w, a_l = _forward_execute_f0(f0, p, slots, tab,
+                                                mono=False)
+    b_f0, b_cks, b_w, b_l = _forward_execute_f0(f0, p, slots, tab,
+                                                mono=True)
     # trash slot may differ (legacy parks losers there); data rows must not
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     assert int(a_cks) == int(b_cks) and int(a_w) == int(b_w)
+    # fingerprint mode hands the scatter every lane, in both forms
+    assert int(a_l) == int(b_l) == n
 
 
 def test_forward_execute_mono_scatter_matches_legacy_full_row():
@@ -314,11 +318,15 @@ def test_forward_execute_mono_scatter_matches_legacy_full_row():
                           jnp.asarray(w))
     slots = p.keys
     f0 = jnp.asarray(rng.integers(0, 256, (tab + 1, width), dtype=np.uint8))
-    a_f0, a_cks, _ = _forward_execute_f0(f0, p, slots, tab, mono=False)
-    b_f0, b_cks, _ = _forward_execute_f0(f0, p, slots, tab, mono=True)
+    a_f0, a_cks, _, a_l = _forward_execute_f0(f0, p, slots, tab, mono=False)
+    b_f0, b_cks, _, b_l = _forward_execute_f0(f0, p, slots, tab, mono=True)
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     assert int(a_cks) == int(b_cks)
+    # full rows: mono goes through `scatter_winner_rows`, which leaves
+    # the trash row alone and is handed fewer lanes than there are
+    np.testing.assert_array_equal(np.asarray(b_f0)[tab], np.asarray(f0)[tab])
+    assert int(a_l) == n and 0 < int(b_l) <= n
 
 
 @pytest.mark.parametrize("write_frac", [0.5, 0.02, 0.0])
@@ -360,11 +368,151 @@ def test_mono_scatter_indices_keep_the_sorted_promise(write_frac):
     else:
         assert (wslot == rows).all()
     f0 = jnp.asarray(rng.integers(0, 2**32, rows, dtype=np.uint32))
-    a_f0, a_cks, _ = _forward_execute_f0(f0, p, slots, tab, mono=False)
-    b_f0, b_cks, _ = _forward_execute_f0(f0, p, slots, tab, mono=True)
+    a_f0, a_cks, _, _ = _forward_execute_f0(f0, p, slots, tab, mono=False)
+    b_f0, b_cks, _, _ = _forward_execute_f0(f0, p, slots, tab, mono=True)
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     # mono never touches the trash slot or a pad row
     np.testing.assert_array_equal(np.asarray(b_f0)[tab:],
                                   np.asarray(f0)[tab:])
     assert int(a_cks) == int(b_cks)
+
+
+# ---- scatter_winner_rows: only the final writers reach the row scatter ---
+
+def _winner_case(pattern: str, n: int, cap: int, chunk: int, edge: int,
+                 rng):
+    """(slots, win) for one epoch of n lanes over `cap` rows; `cap` is
+    the trash slot.  Winners hold distinct slots (one final writer a
+    row) unless the pattern says otherwise.  `edge`: the most chunks the
+    loop takes before the one sorted scatter is cheaper."""
+    slots = rng.choice(cap, size=n, replace=False).astype(np.int32)
+    win = np.zeros(n, bool)
+    count = {"none": 0, "all": n, "two_chunks": 2 * chunk,
+             "two_chunks_and_one": 2 * chunk + 1, "edge": edge * chunk,
+             "edge_and_one": edge * chunk + 1}.get(pattern)
+    if count is not None:
+        win[rng.choice(n, count, replace=False)] = True
+    elif pattern == "behind_losers":
+        win[-5:] = True
+    elif pattern == "trash_and_twins":
+        # `last_writer` lets lanes aimed at the trash slot "win", and
+        # `level_exec` hands over one txn's duplicate lanes of one slot
+        # (identical values); a miss may also carry a negative slot
+        win[rng.random(n) < 0.3] = True
+        slots[::7] = cap
+        slots[1::2] = slots[::2]
+        slots[3] = -1
+    else:
+        raise AssertionError(pattern)
+    return slots, win
+
+
+# (table, pattern, which branch must run).  `small`: a pass over the
+# column is cheap, so past a few chunks the one sorted scatter of all n
+# lanes runs; `large`: the loop at any count, all 64 chunks included
+_WINNER_CASES = [
+    ("small", "none", "loop"), ("small", "all", "whole"),
+    ("small", "edge", "loop"), ("small", "edge_and_one", "whole"),
+    ("small", "behind_losers", "loop"), ("small", "trash_and_twins", "whole"),
+    ("large", "none", "loop"), ("large", "all", "loop"),
+    ("large", "two_chunks", "loop"), ("large", "two_chunks_and_one", "loop"),
+    ("large", "behind_losers", "loop"), ("large", "trash_and_twins", "loop"),
+]
+
+
+@pytest.mark.parametrize("in_slot_order", [True, False],
+                         ids=["slot_order", "any_order"])
+@pytest.mark.parametrize("table,pattern,branch", _WINNER_CASES,
+                         ids=[f"{t}-{p}" for t, p, _ in _WINNER_CASES])
+def test_scatter_winner_rows_matches_the_trash_steered_scatter(
+        table, pattern, branch, in_slot_order):
+    """The winners-only row scatter against the legacy one (every lane
+    issued, losers steered to the trash row): the table below `capacity`
+    bit for bit, the trash row and the padding rows untouched, and the
+    lanes it reports = what its two branches issue — whole chunks
+    covering the winners (no winner: none at all), or all n lanes with
+    the sorted promise."""
+    from deneva_tpu.ops import scatter as sc
+    from deneva_tpu.workloads.ycsb import _field_bytes
+
+    rng = np.random.default_rng(list(f"{pattern}/{table}".encode()))
+    n, width = 1024, 24
+    cap = 2_000 if table == "small" else 150_000
+    rows = cap + 9                          # trash row + 8 padding rows
+    chunk = -(-n // sc._CHUNKS)
+    edge = min((rows + 6 * n - 1) // (chunk * sc._ROWS_PER_LANE),
+               n // chunk)
+    slots, win = _winner_case(pattern, n, cap, chunk, edge, rng)
+    keys = slots.astype(np.int32) * 3 + 1   # a function of the slot
+    order = rng.integers(0, 1000, n).astype(np.int32)
+    if pattern == "trash_and_twins":
+        order[1::2] = order[::2]            # twins carry one value
+    if in_slot_order:
+        o = np.argsort(np.where(slots < 0, cap, slots), kind="stable")
+        slots, win, keys, order = slots[o], win[o], keys[o], order[o]
+    col = jnp.asarray(rng.integers(0, 256, (rows, width), dtype=np.uint8))
+    value = lambda k, o: _field_bytes(k, o, width)      # noqa: E731
+    got, lanes, read = jax.jit(sc.scatter_winner_rows,
+                               static_argnums=(4, 5))(
+        col, jnp.asarray(slots), jnp.asarray(win),
+        (jnp.asarray(keys), jnp.asarray(order)), value, cap,
+        col[cap].sum(dtype=jnp.uint32))
+    assert int(read) == int(np.asarray(col)[cap].sum())  # handed back
+    steer = np.where(win & (slots >= 0), slots, cap)
+    want = col.at[jnp.asarray(steer)].set(value(jnp.asarray(keys),
+                                                jnp.asarray(order)))
+    np.testing.assert_array_equal(np.asarray(got)[:cap],
+                                  np.asarray(want)[:cap])
+    np.testing.assert_array_equal(np.asarray(got)[cap:],
+                                  np.asarray(col)[cap:])
+    cnt = int((win & (slots >= 0) & (slots < cap)).sum())
+    trips = -(-cnt // chunk)
+    assert 0 < edge and (edge < n // chunk) == (table == "small")
+    assert (trips <= edge) == (branch == "loop")
+    assert int(lanes) == (trips * chunk if branch == "loop" else n)
+
+
+@pytest.mark.parametrize("write_frac", [0.5, 0.02, 0.0, 1.0])
+def test_winner_scatter_indices_keep_the_sorted_promise(write_frac):
+    """`indices_are_sorted=True` is a promise (PR 22: a -1 sentinel wraps
+    to the last row, put the largest index first, and the chip lost
+    every write).  What `compact_winners` hands XLA is non-decreasing
+    and within [0, n_rows], as a whole and chunk by chunk, with no
+    winner (every lane out of range) and with every write lane a
+    winner; the winners' slots come first, ascending, each with its own
+    (key, rank)."""
+    from deneva_tpu.ops import compact_winners, forward_plan_flat
+    from deneva_tpu.ops import scatter as sc
+
+    rng = np.random.default_rng(13)
+    n, tab, rows = 2048, 5000, 5016
+    big = np.iinfo(np.int32).max
+    if write_frac == 1.0:
+        keys = rng.permutation(tab)[:n].astype(np.int32)   # all distinct
+    else:
+        keys = rng.integers(0, tab, n).astype(np.int32)
+        keys[rng.random(n) < 0.05] = big
+    rank = np.repeat(np.arange(n // 4, dtype=np.int32), 4)
+    w = (rng.random(n) < write_frac) & (keys != big)
+    p = forward_plan_flat(jnp.asarray(keys), jnp.asarray(rank),
+                          jnp.asarray(w))
+    slots = jnp.where(p.keys != big, p.keys, tab)
+    idx, (ck, cr), cnt = compact_winners(slots, p.win, (p.keys, p.rank),
+                                         tab, rows)
+    idx, ck, cr, cnt = np.asarray(idx), np.asarray(ck), np.asarray(cr), \
+        int(cnt)
+    win = np.asarray(p.win)
+    assert cnt == win.sum()
+    if write_frac == 1.0:
+        assert cnt == n                     # every lane a winner
+    assert (np.diff(idx) >= 0).all()
+    assert idx.min() >= 0 and idx.max() <= rows
+    chunk = -(-n // sc._CHUNKS)
+    for at in range(0, n, chunk):
+        part = idx[at:at + chunk]
+        assert (np.diff(part) >= 0).all() and part.max() <= rows
+    assert (idx[cnt:] == rows).all() and (idx[:cnt] < tab).all()
+    np.testing.assert_array_equal(idx[:cnt], np.asarray(slots)[win])
+    np.testing.assert_array_equal(ck[:cnt], np.asarray(p.keys)[win])
+    np.testing.assert_array_equal(cr[:cnt], np.asarray(p.rank)[win])

@@ -15,6 +15,13 @@ from deneva_tpu.parallel import make_mesh, make_sharded_run, state_shardings
 from deneva_tpu.workloads import get_workload
 
 
+# a count of device work, not of the serial semantics: each chip is
+# handed its own lanes (every lane of a fingerprint column, whole chunks
+# covering its own winners of a full-row one), so the sum over chips is
+# not the single device's
+WORK_ONLY = {"write_scatter_lanes"}
+
+
 def cfg_for(alg):
     return Config(cc_alg=alg, epoch_batch=64, conflict_buckets=1024,
                   max_accesses=4, req_per_query=4, synth_table_size=4096,
@@ -39,7 +46,7 @@ def test_sharded_run_matches_single_device(alg):
     out_stats = {k: np.asarray(v) for k, v in
                  jax.device_get(out.stats).items()}
 
-    for k in ref_stats:
+    for k in set(ref_stats) - WORK_ONLY:
         assert (ref_stats[k] == out_stats[k]).all(), k
 
 
@@ -63,7 +70,7 @@ def test_partition_parallel_forwarding_matches_single_device():
     out = run(place(eng8.init_state(seed=5)), 12)
     out_stats = {k: np.asarray(v) for k, v in
                  jax.device_get(out.stats).items()}
-    for k in ref_stats:
+    for k in set(ref_stats) - WORK_ONLY:
         assert (ref_stats[k] == out_stats[k]).all(), k
 
 
@@ -88,7 +95,7 @@ def test_partition_parallel_full_pool_and_forced_aborts():
     out_stats = {k: np.asarray(v) for k, v in
                  jax.device_get(out.stats).items()}
     assert int(out_stats["total_txn_abort_cnt"]) > 0
-    for k in ref_stats:
+    for k in set(ref_stats) - WORK_ONLY:
         assert (ref_stats[k] == out_stats[k]).all(), k
 
 
@@ -102,7 +109,7 @@ def _mc_bit_identity(cfg, seed=7, epochs=10):
     eng8 = Engine(cfg8, get_workload(cfg8))
     place, run = make_sharded_run(eng8, make_mesh(8))
     out = jax.device_get(run(place(eng8.init_state(seed=seed)), epochs).stats)
-    for k in ref:
+    for k in set(ref) - WORK_ONLY:
         assert (np.asarray(ref[k]) == np.asarray(out[k])).all(), k
     assert int(out["total_txn_commit_cnt"]) > 0
     return out
@@ -188,7 +195,7 @@ def test_sharded_plan_path_bit_identical_to_single_device():
     place, run = make_sharded_run(eng8, make_mesh(8))
     out = jax.device_get(run(place(eng8.init_state(seed=6)), 8).stats)
     assert int(np.asarray(out["defer_cnt"])) == 0   # capacity ample
-    for k in ref:
+    for k in set(ref) - WORK_ONLY:
         assert (np.asarray(ref[k]) == np.asarray(out[k])).all(), k
     assert int(np.asarray(out["total_txn_commit_cnt"])) > 0
 

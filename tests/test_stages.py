@@ -216,6 +216,20 @@ def test_window_stage_seconds_add_up_to_the_window_wall(served):
     assert s["worker_idle_time"] >= 0
 
 
+def test_summary_carries_the_lanes_handed_to_the_write_scatter(served):
+    """`write_scatter_lane_cnt` rides next to `write_cnt` in `[summary]`
+    (`exec.write_lanes_per_epoch` reads it): a window count like its
+    neighbours, so it divides by the WINDOW's epochs (`stage_epoch_cnt`;
+    `epoch_cnt` is the whole run's).  This run stores fingerprints, not
+    rows, and that scatter is handed every lane of every epoch (128
+    txns x 4)."""
+    from deneva_tpu.stats import parse_summary
+    line = [ln for ln in served if ln.startswith("node 0 (server): ")][0]
+    s = parse_summary(line.split(": ", 1)[1])
+    assert s["write_scatter_lane_cnt"] == 128 * 4 * s["stage_epoch_cnt"]
+    assert 0 < s["write_cnt"] < s["write_scatter_lane_cnt"]
+
+
 def test_timeline_and_crit_lines_are_what_they_were(served):
     from deneva_tpu.harness.parse import parse_metrics
     from deneva_tpu.harness.timeline import parse_timeline
@@ -240,7 +254,12 @@ def test_timeline_and_crit_lines_are_what_they_were(served):
 
 # recorded from the parent tree (commit 3393394, before any scope
 # existed) by this same seeded feed: scopes are metadata, so the commit
-# and abort counts, the verdict planes and the table's digest are these
+# and abort counts, the verdict planes and the table's digest are these.
+# OCC's digest is the parent's table with F0's TRASH ROW ZEROED (parent
+# tree at 3d631a6, the same feed; its own digest d2a3765f... held some
+# losing lane's bytes there): since PR 26 only the final writers reach
+# the row scatter and nothing is steered to the trash row any more —
+# every row below `capacity` is the parent's, bit for bit
 PARENT = {
     "TPU_BATCH": dict(
         commits=1392, aborts=0, writes=2827, planes=15064,
@@ -248,8 +267,8 @@ PARENT = {
                "c9209fed"),
     "OCC": dict(
         commits=488, aborts=904, writes=986, planes=15064,
-        digest="d2a3765f0d1c16e53df6d2524ac16a4a004da8355fa9df382f0b9481"
-               "3eeef707"),
+        digest="dc1af2a82dfd3befd804d9bc1c136647bf68c6780d83947b54265173"
+               "ae22f50f"),
 }
 SCOPES = {
     "TPU_BATCH": {"ep.decode", "ep.plan", "ep.read", "ep.write", "ep.stats",
