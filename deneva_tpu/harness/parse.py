@@ -308,9 +308,11 @@ def parse_mesh(lines) -> list[dict[str, Any]]:
     summary path, emitted only when ``device_parts > 1``) -> [{node,
     shards, a2a_bytes, prefetch_overlap, groups}].  The pod-scale
     measured path's health ledger: ``shards`` is the mesh width the
-    epoch program actually ran at, ``a2a_bytes`` the static per-epoch
-    ``all_to_all`` estimate under the owner-exchange plan (0 = the
-    replicated fallback plan), ``prefetch_overlap`` the fraction of
+    epoch program actually ran at, ``a2a_bytes`` the bytes the owner
+    exchange's ``all_to_all`` blocks move BETWEEN chips an epoch
+    (static, from the block shapes the program cuts at the batch's real
+    width; 0 = the replicated fallback plan, or a path that exchanges
+    no lanes), ``prefetch_overlap`` the fraction of
     verdict-plane d2h prefetches already complete when the retire
     worker asked (1.0 = fully overlapped with device execution),
     ``groups`` the retired-group count behind that ratio.  Logs

@@ -94,16 +94,20 @@ def state_shardings(mesh: Mesh, state: Any):
     return jax.tree_util.tree_map_with_path(spec, state)
 
 
-def a2a_bytes_per_epoch(cfg, b: int) -> int:
-    """Static per-epoch estimate of ``all_to_all`` traffic under the
-    sharded owner-exchange plan: each of D shards ships its
-    ``[D, pair_cap]`` key/rank/write lanes (int32+int32+bool = 9 B per
-    lane) to every peer.  0 when capacity planning is off — the generic
-    ``mc_execute`` path exchanges only psum partials, not lanes."""
+def a2a_bytes_per_epoch(cfg, b: int, width: int) -> int:
+    """Bytes the sharded owner exchange of `YCSBWorkload.execute_mc`
+    moves BETWEEN chips in one epoch of ``b`` transactions whose batch is
+    ``width`` accesses wide (the wire's, not ``max_accesses``: the block
+    shapes `execute_mc` really cuts): each of D shards ships one
+    ``pair_cap``-lane block of key/rank/write lanes (int32 + int32 + bool
+    = 9 B a lane) to each of its D - 1 peers; the block a shard keeps for
+    itself crosses no wire.  Static (a function of shapes).  0 when
+    capacity planning is off: the replicated plan exchanges only psum
+    partials, not lanes."""
     from ..ops.forward import mc_pair_cap
     d = cfg.device_parts
-    cap = mc_pair_cap(b, cfg.max_accesses, d, cfg.mc_plan_capacity)
-    return d * d * cap * 9
+    cap = mc_pair_cap(b, width, d, cfg.mc_plan_capacity)
+    return d * (d - 1) * cap * 9
 
 
 def mesh_line(node: int, fields: dict) -> str:

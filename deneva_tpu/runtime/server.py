@@ -710,6 +710,9 @@ class ServerNode:
         if cfg.device_parts > 1:
             from deneva_tpu.parallel import mesh as _mesh
             self._mesh_mod = _mesh
+            # the configuration's mesh: the one a workload's sharded
+            # loader built its table over (`YCSBWorkload._load_mc`), so
+            # the placement below finds those columns where they belong
             self.mesh = _mesh.make_mesh(cfg.device_parts)
             if not self.vote_mode:
                 _inner_group = self.group_step
@@ -3628,8 +3631,10 @@ class ServerNode:
         if self.mesh is not None:
             # mesh counters ([summary] satellite) + the [mesh] line
             # (parsed by harness.parse.parse_mesh): shard count, the
-            # static per-epoch all_to_all estimate of the owner
-            # exchange, and how often the verdict-plane prefetch was
+            # bytes the owner exchange moves between chips an epoch
+            # (static, from the block shapes `execute_mc` cuts; 0 where
+            # the generic `mc_execute` runs, which exchanges no lanes),
+            # and how often the verdict-plane prefetch was
             # already finished at its retirement turn (prefetch_overlap
             # = d2h+unpack genuinely hidden behind device execution).
             # Emitted only when a mesh is armed, so the single-device
@@ -3637,7 +3642,9 @@ class ServerNode:
             from deneva_tpu.parallel.mesh import (a2a_bytes_per_epoch,
                                                   mesh_line)
             ratio = self._prefetch_hits / max(self._prefetch_polls, 1)
-            a2a = a2a_bytes_per_epoch(cfg, self.b_merged)
+            from deneva_tpu.ops import forwarding_applies
+            a2a = a2a_bytes_per_epoch(cfg, self.b_merged, self._width) \
+                if forwarding_applies(self.be, self.wl) else 0
             st.set("mesh_shards", float(cfg.device_parts))
             st.set("mesh_a2a_bytes", float(a2a))
             st.set("mesh_prefetch_overlap", ratio)

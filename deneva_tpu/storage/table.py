@@ -343,6 +343,69 @@ def to_mc_layout(tab: DeviceTable, d_parts: int, anchor_rows: int = 1
                         anchor_rows=R)
 
 
+def mc_column_builder(mesh, capacity: int, row_fn, dtype, extra: tuple = ()):
+    """Jitted ``() -> column`` that BUILDS one column of a ``capacity``-row
+    table directly in the owner-major stacked layout, sharded over
+    ``mesh`` (one block a device, dim 0): device ``d`` computes its own
+    block and nothing else — rows ``j < local_rows`` hold
+    ``row_fn(j * D + d)`` (the single-device slot the row would have
+    had), the block's pad rows and its trash row stay zero, exactly
+    `mc_block_geometry`'s.  ``row_fn`` maps int32 slots to
+    ``dtype[len(slots), *extra]``, or is None for a column of zeros.  The
+    result equals that column of ``to_mc_layout(<single-device table>)``
+    bit for bit, but no array of the whole table's rows ever exists on
+    one device: a table no single device can hold is loaded this way."""
+    from jax.sharding import PartitionSpec as P
+
+    (axis,) = mesh.axis_names
+    d_parts = mesh.size
+    local_rows, lb = mc_block_geometry(capacity, 1, d_parts)
+
+    def block():
+        if row_fn is None:
+            return jnp.zeros((lb, *extra), dtype)
+        j = jnp.arange(lb, dtype=jnp.int32)
+        live = j < local_rows
+        # pad rows compute a (discarded) value of slot 0: in range
+        slot = jnp.where(live, j * d_parts + jax.lax.axis_index(axis), 0)
+        live = live.reshape((lb,) + (1,) * len(extra))
+        return jnp.where(live, row_fn(slot).astype(dtype), 0)
+
+    return jax.jit(jax.shard_map(
+        block, mesh=mesh, in_specs=(),
+        out_specs=P(axis, *([None] * len(extra)))))
+
+
+def create_mc(schema: TableSchema, capacity: int, mesh, row_fns: dict,
+              full_row: bool = False) -> DeviceTable:
+    """A loaded table in the owner-major stacked layout, each block built
+    on the device of ``mesh`` that holds it (`mc_column_builder`), one
+    column at a time.  ``row_fns``: column name -> slots -> values for the
+    columns the loader fills; the others are zeros.  Equals
+    ``to_mc_layout`` of `DeviceTable.create` + those columns written,
+    placed as `parallel.mesh.state_shardings` places a table — a later
+    ``device_put`` over the same mesh moves nothing."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    d_parts = mesh.size
+    local_rows, _ = mc_block_geometry(capacity, 1, d_parts)
+    cols, built = {}, {}
+    for c in schema.columns:
+        dtype, extra = _col_spec(c.ctype, c.size, full_row)
+        fn = row_fns.get(c.name)
+        # one program per distinct (law, shape): ten YCSB fields share one
+        key = (fn, jnp.dtype(dtype).name, extra)
+        if key not in built:
+            built[key] = mc_column_builder(mesh, capacity, fn, dtype, extra)
+        cols[c.name] = built[key]()
+    row_cnt = jax.device_put(
+        jnp.full((d_parts,), local_rows, jnp.int32),
+        NamedSharding(mesh, P(*mesh.axis_names)))
+    return DeviceTable(columns=cols, row_cnt=row_cnt, name=schema.name,
+                       capacity=capacity, full_row=full_row,
+                       mc_parts=d_parts)
+
+
 def fill_columns(tab: DeviceTable, n: int, cols: dict) -> DeviceTable:
     """Loader helper: set the first ``n`` rows of the named columns and
     advance ``row_cnt`` (the parallel loaders of SURVEY §2.5 reduced to

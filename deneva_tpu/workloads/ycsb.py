@@ -34,7 +34,7 @@ from deneva_tpu.ops import (HotSet, Zipfian, forward_plan, last_writer,
                             scatter_winner_rows)
 from deneva_tpu.storage.catalog import parse_schema
 from deneva_tpu.storage.index import DenseIndex, SortedIndex
-from deneva_tpu.storage.table import DeviceTable, VersionRing, to_mc_layout
+from deneva_tpu.storage.table import DeviceTable, VersionRing, create_mc
 
 # benchmarks/YCSB_schema.txt: MAIN_TABLE, 10 x 100-byte string fields
 YCSB_SCHEMA = "TABLE=MAIN_TABLE\n" + "".join(
@@ -253,15 +253,45 @@ class YCSBWorkload:
         (ycsb_wl.cpp:70-74); shared by both index kinds and the loader.
         Elastic mode is full-residency: every key has a local row (the
         ownership mask lives in the slot map, not the layout)."""
-        if self.elastic:
-            return np.arange(self.n_local, dtype=np.int32)
-        base = self.cfg.node_id if self.n_parts > 1 else 0
-        stride = self.n_parts if self.n_parts > 1 else 1
+        base, stride = self._key_law()
         return (base + np.arange(self.n_local, dtype=np.int64)
                 * stride).astype(np.int32)
 
+    def _key_law(self) -> tuple[int, int]:
+        """(base, stride): local slot s holds global key base + s * stride."""
+        if self.elastic or self.n_parts <= 1:
+            return 0, 1
+        return self.cfg.node_id, self.n_parts
+
     # -- loader (ycsb_wl.cpp:125-203) ----------------------------------
-    def load(self):
+    def _load_mc(self) -> DeviceTable:
+        """The table over ``device_parts`` chips, each shard built on the
+        chip that holds it (`storage.table.create_mc`): mesh block d
+        holds exactly this node's slots ≡ d (mod D) — the reference's
+        strided node partition (ycsb_wl.cpp:70-74) across CHIPS — as
+        ``to_mc_layout`` of the single-device load would, without the
+        single-device table (25 M full rows fit no one chip).  The mesh
+        is the configuration's (`parallel.mesh.make_mesh`,
+        deterministic), so every caller of ``load()`` — the server, log
+        replay, the benchmark's verdict replay — gets the same placement
+        and `ServerNode`'s later ``device_put`` moves nothing."""
+        from deneva_tpu.parallel.mesh import make_mesh
+        cfg = self.cfg
+        base, stride = self._key_law()
+
+        def key(slot):
+            return slot * jnp.int32(stride) + jnp.int32(base)
+        if cfg.sim_full_row:
+            def init(slot):
+                return _field_bytes(key(slot), 0, cfg.tup_size)
+            fns = {c.name: init for c in self.catalog.table(TABLE).columns}
+        else:
+            fns = {"F0": lambda slot: _field_fingerprint(key(slot), 0)}
+        return create_mc(self.catalog.table(TABLE), self.n_local,
+                         make_mesh(cfg.device_parts), fns,
+                         full_row=cfg.sim_full_row)
+
+    def _load_one(self) -> DeviceTable:
         full = self.cfg.sim_full_row
         tab = DeviceTable.create(self.catalog.table(TABLE), self.n_local,
                                  full_row=full)
@@ -281,11 +311,13 @@ class YCSBWorkload:
             # it for the chip at the served size)
             tab.columns["F0"] = tab.columns["F0"].at[: self.n_local].set(
                 _field_fingerprint(keys, 0))
-        if self.cfg.device_parts > 1:
-            # multi-chip owner-major stacked layout: mesh block d holds
-            # exactly the keys ≡ d (mod D) — the reference's strided node
-            # partition (ycsb_wl.cpp:70-74) across CHIPS
-            tab = to_mc_layout(tab, self.cfg.device_parts)
+        return tab
+
+    def load(self):
+        # several chips: each owner-major block is built on the chip that
+        # holds it; one chip: the table, eagerly, on the default device
+        tab = self._load_mc() if self.cfg.device_parts > 1 \
+            else self._load_one()
         db = {TABLE: tab}
         if self.elastic:
             # device-resident owner array: ownership changes are a data
@@ -404,11 +436,21 @@ class YCSBWorkload:
         ``mc_plan_capacity=0`` for the round-3 replicated-plan mode
         (zero capacity factors, zero defers, full-batch sort per chip).
 
-        Returns ``(db, defer_mask)``; tables must be in the owner-major
-        layout `load()` produces for ``device_parts > 1``; each local
-        block's last row is its trash.
+        Returns ``(db, defer_mask)``.  The table is in the owner-major
+        stacked layout and lives SHARDED over the mesh, one block a chip:
+        `_load_mc` builds each block on the chip that holds it
+        (`storage.table.create_mc`; equal to ``to_mc_layout`` of the
+        one-chip table, which no one chip could hold at the served
+        size); each local block's last row is its trash.
+
+        Scopes (metadata only, as `runtime/server._make_epoch_body`'s):
+        everything the mesh ADDS to an epoch — the slice cuts, the defer
+        pass, the owner sort, the block cuts, the three ``all_to_all``s,
+        the ``all_gather`` of the defer bits and the three ``psum``s —
+        is `ep.exchange`; the per-shard plan and slot map are `ep.plan`,
+        as on one chip; `ep.read` / `ep.write` are the shared executor's.
         """
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         from deneva_tpu.ops import forward_plan_flat, mc_pair_cap
         from deneva_tpu.parallel import AXIS, current_mesh
@@ -490,15 +532,18 @@ class YCSBWorkload:
                 mine = (bk % d_parts == me) & (bk != big)
                 bk = jnp.where(mine, bk, big)
                 bw = bw & mine
-                p = forward_plan_flat(bk, br, bw)
             else:
                 dfr = jnp.zeros((b,), bool)
-                owned = valid & (keys % d_parts == me)
-                p = forward_plan(keys, rank, is_write, owned)
-            # f0 here is one owner-major block (to_mc_layout): its last
-            # padded row is the block-local trash
-            trash = jnp.int32(f0.shape[0] - 1)
-            slots = jnp.where(p.keys != big, p.keys // d_parts, trash)
+            with jax.named_scope("ep.plan"):
+                if pair_cap:
+                    p = forward_plan_flat(bk, br, bw)
+                else:
+                    owned = valid & (keys % d_parts == me)
+                    p = forward_plan(keys, rank, is_write, owned)
+                # f0 here is one owner-major block (`create_mc`): its
+                # last padded row is the block-local trash
+                trash = jnp.int32(f0.shape[0] - 1)
+                slots = jnp.where(p.keys != big, p.keys // d_parts, trash)
             # mono holds per shard: plan keys are sorted with non-owned
             # lanes already masked to the big sentinel, so slots ascend
             # toward the block-local trash at the top
@@ -507,16 +552,27 @@ class YCSBWorkload:
             return (f0, jax.lax.psum(cks, AXIS), jax.lax.psum(wcnt, AXIS),
                     jax.lax.psum(lanes, AXIS), dfr)
 
-        f0, cks, wcnt, lanes, dfr = jax.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(AXIS), P(), P(), P(), P(), P()),
-            out_specs=(P(AXIS), P(), P(), P(),
-                       P(AXIS) if pair_cap else P()))(
-                tab.columns["F0"], batch.keys, batch.rank, batch.ts,
-                batch.is_write, valid)
-        stats["read_checksum"] = stats["read_checksum"] + cks
-        stats["write_cnt"] = stats["write_cnt"] + wcnt
-        stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + lanes
+        with jax.named_scope("ep.exchange"):
+            f0, cks, wcnt, lanes, dfr = jax.shard_map(
+                body, mesh=mesh,
+                in_specs=(P(AXIS), P(), P(), P(), P(), P()),
+                out_specs=(P(AXIS), P(), P(), P(),
+                           P(AXIS) if pair_cap else P()))(
+                    tab.columns["F0"], batch.keys, batch.rank, batch.ts,
+                    batch.is_write, valid)
+            if pair_cap:
+                # the all_gather: every chip (and the caller's verdict)
+                # needs the whole mask; asked for here so that it
+                # carries this scope
+                dfr = jax.lax.with_sharding_constraint(
+                    dfr, NamedSharding(mesh, P()))
+            # (in the scope: the chip's compiler merges the three psums
+            # into one all-reduce that keeps no op_name; a trace reads
+            # its scope from these consumers)
+            stats["read_checksum"] = stats["read_checksum"] + cks
+            stats["write_cnt"] = stats["write_cnt"] + wcnt
+            stats["write_scatter_lanes"] = \
+                stats["write_scatter_lanes"] + lanes
         db = dict(db)
         db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
         return db, dfr

@@ -204,3 +204,117 @@ def test_mesh_group_compiles_for_four_chips(topo, monkeypatch):
     assert out_db["MAIN_TABLE"].columns["F0"].spec == P(M.AXIS)
     # (TPU_BATCH keeps no cross-epoch watermark state — its cc_state is
     # empty — so the table is this deployment's only sharded leaf)
+
+
+# ---- the four-chip cell (PR 29): ycsb_fullrow_tpubatch_dp4.hot -----------
+
+CELL_DP4 = "ycsb_fullrow_tpubatch_dp4.hot"
+
+
+def _cell_cfg() -> Config:
+    """The `Config` of the cell's timed launch, through the harness's own
+    `load_cell` / `server_fields` (benchmark/run.py never imports JAX)."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "run.py")
+    spec = importlib.util.spec_from_file_location("bench_run_for_compile",
+                                                  path)
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    fields = run.server_fields(run.load_cell(CELL_DP4), 3_000_000_019, {})
+    return Config.from_args([f"--{k}={v}" for k, v in fields.items()]
+                            ).replace(node_id=0, part_cnt=1)
+
+
+@pytest.fixture(scope="module")
+def dp4_cell(topo):
+    """(cfg, mesh, state, compiled group, seconds) of the cell's group
+    program — 25,165,824 full rows over the four described chips, epochs
+    of 16,384, C=32 — compiled ONCE for the tests below."""
+    from deneva_tpu.parallel import mesh as M
+    cfg = _cell_cfg()
+    mesh = Mesh(np.array(topo.devices[:4]), (M.AXIS,))
+    with pytest.MonkeyPatch.context() as mp:
+        group, state, feed = _group_program(cfg, mp)
+        state = _with_sharding(state, M.state_shardings(mesh, state))
+        feed = _with_sharding(feed, NamedSharding(mesh, P()))
+        with M.use_mesh(mesh):
+            compiled, secs = _compile(group, state["db"], state["cc_state"],
+                                      state["stats"], *feed)
+    return cfg, mesh, state, compiled, secs
+
+
+def test_sharded_loader_compiles_for_four_chips_at_the_cells_size(topo):
+    """One column of the cell's table, built block by block on the chip
+    that holds it (`storage.table.mc_column_builder`): each device is
+    asked for its 6,291,520 rows x 100 B and nothing beside them — the
+    ten columns of a shard then fit one chip with room for serving."""
+    from deneva_tpu.parallel import mesh as M
+    from deneva_tpu.storage.table import mc_block_geometry, mc_column_builder
+    from deneva_tpu.workloads.ycsb import _field_bytes
+    cfg = _cell_cfg()
+    assert (cfg.synth_table_size, cfg.device_parts) == (25_165_824, 4)
+    mesh = Mesh(np.array(topo.devices[:4]), (M.AXIS,))
+    build = mc_column_builder(
+        mesh, cfg.synth_table_size,
+        lambda slot: _field_bytes(slot, 0, cfg.tup_size), np.uint8,
+        (cfg.tup_size,))
+    compiled, secs = _compile(build)
+    need = _report("dp4_loader_column (per device)", compiled, secs)
+    _, lb = mc_block_geometry(cfg.synth_table_size, 1, 4)
+    block = lb * cfg.tup_size
+    m = compiled.memory_analysis()
+    assert m.output_size_in_bytes >= block
+    # the block and at most a few percent of scratch: no uint32[rows,100]
+    # temporary, no second copy, nothing of another device's rows
+    assert need < 1.1 * block
+    (out,) = jax.tree.leaves(compiled.output_shardings)
+    assert out.shard_shape((4 * lb, cfg.tup_size)) == (lb, cfg.tup_size)
+    assert cfg.field_per_tuple * need < HBM_BYTES // 2
+
+
+def test_dp4_cell_group_fits_each_of_the_four_chips(dp4_cell):
+    cfg, mesh, state, compiled, secs = dp4_cell
+    need = _report("dp4_cell_group (per device)", compiled, secs)
+    m = compiled.memory_analysis()
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) < HBM_BYTES
+    shard = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"])) // 4
+    # a quarter of the table a chip, donated and written in place
+    assert m.alias_size_in_bytes >= shard
+    assert shard + cfg.pipeline_groups * (need - shard) < HBM_BYTES
+    f0 = state["db"]["MAIN_TABLE"].columns["F0"]
+    assert f0.sharding.shard_shape(f0.shape) == (f0.shape[0] // 4, 100)
+    out_db = compiled.output_shardings[0]
+    assert out_db["MAIN_TABLE"].columns["F0"].is_equivalent_to(
+        f0.sharding, 2)
+
+
+def test_dp4_cell_group_names_what_the_mesh_adds(dp4_cell):
+    """On the chip's own HLO: the collectives of `execute_mc` sit under
+    `ep.exchange` (an all-reduce the compiler merged may keep no op_name
+    — `phase_reduce.hlo_scopes` then reads its consumers'), the exchange
+    blocks are cut at the batch's real width (10 accesses: 20,480 lanes
+    a block), and the per-shard plan sort is `ep.plan`'s."""
+    import re
+    hlo = dp4_cell[3].as_text()
+    seen = {}
+    for ln in hlo.splitlines():
+        m = re.search(r" (all-to-all|all-gather|all-reduce)[a-z\-]*\(", ln)
+        if not m:
+            continue
+        name = re.search(r'op_name="([^"]*)"', ln)
+        seen.setdefault(m.group(1), []).append(ln)
+        assert name is None or "ep.exchange" in name.group(1).split("/"), ln
+        assert name is not None or m.group(1) == "all-reduce", ln
+    assert len(seen["all-to-all"]) == 3 and len(seen["all-gather"]) == 1
+    assert "all-reduce" in seen
+    assert any("s32[4,1,20480]" in ln for ln in seen["all-to-all"])
+    sorts = [re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+             for ln in hlo.splitlines() if re.search(r" sort\(", ln)]
+    assert all("ep.exchange" in s for s in sorts)
+    inner = [next(p for p in reversed(s) if p.startswith("ep."))
+             for s in sorts]
+    assert sorted(inner) == ["ep.exchange"] * 3 + ["ep.plan", "ep.write"]
