@@ -36,8 +36,9 @@ import numpy as np
 #          8 = round-17 (isolation audit plane: audit_edge_cnt/
 #              audit_drop_cnt device counters, and with audit armed the
 #              db pytree gains the __audit__ version-stamp tables);
-#          9 = PR 26 (write_scatter_lanes device counter).
-SCHEMA_VERSION = 9
+#          9 = PR 26 (write_scatter_lanes device counter);
+#         10 = PR 30 (read_gather_lanes device counter).
+SCHEMA_VERSION = 10
 
 
 def save_state(path: str, state) -> None:

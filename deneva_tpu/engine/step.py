@@ -89,6 +89,10 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1) -> dict:
         # scatter_winner_rows): against write_cnt and the epoch's lane
         # count it says how far the winner compaction engages
         "write_scatter_lanes": z(),
+        # lanes handed to YCSB's F0 gather: under a forwarding plan with
+        # full rows the unforwarded reads, in whole sixteenths of the
+        # plan (ops/gather.checksum_needed_rows), else every lane
+        "read_gather_lanes": z(),
         # commit latency in epochs, PER TXN TYPE (round-4: the
         # reference's per-txn StatsArr families, stats_array.cpp);
         # the driver calibrates buckets to wall seconds per chunk

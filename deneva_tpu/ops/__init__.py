@@ -16,6 +16,7 @@ from deneva_tpu.ops.forward import (ForwardPlan,  # noqa: F401
                                     forwarding_applies,
                                     last_earlier_writer, mc_defer_verdict,
                                     mc_pair_cap, mc_plan_defer)
+from deneva_tpu.ops.gather import checksum_needed_rows  # noqa: F401
 from deneva_tpu.ops.conflict import (  # noqa: F401
     access_incidence,
     overlap,

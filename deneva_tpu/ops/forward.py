@@ -23,12 +23,15 @@ commits in ONE pass: no conflict matrix, no levels, no aborts.
 The plan stays in **sorted coordinates**: executors (`ycsb.execute`)
 gather/scatter the table through the sorted arrays directly, because on
 TPU the expensive resource is random-access passes while sorts and scans
-are cheap (a 3-operand sort of 163,840 lanes: 0.19 ms on v5e; the
-gather of as many 100 B rows 1.95 ms; a row written one by one 71 ns a
-lane, or the whole 6M-row column passed over in 4 ms whatever the lanes
-— PERF.md sections 5 and 6).  Keeping sorted coordinates deletes the
-unsort scatter and the whole `last_writer` scatter-max tournament from
-the hot path.
+are cheap (a 3-operand sort of 163,840 lanes: 0.19 ms on v5e; a 100 B
+row gathered costs 6 to 10 ns a lane, whatever the row; a row written
+one by one 71 ns a lane, or the whole 6M-row column passed over in 4 ms
+whatever the lanes — PERF.md sections 5 and 6).  Keeping sorted
+coordinates deletes the unsort scatter and the whole `last_writer`
+scatter-max tournament from the hot path; of an epoch's lanes only the
+final writers (``win``) and the reads that nothing forwards to
+(``is_read & (fwd < 0)``) touch the table, each kind compacted first
+(`ops.scatter.scatter_winner_rows`, `ops.gather.checksum_needed_rows`).
 
 Contract: ``rank`` must be unique per txn and >= 0; accesses must be
 read-xor-write (an RMW access would be handed its own rank).  Collisions

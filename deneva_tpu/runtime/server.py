@@ -3450,9 +3450,9 @@ class ServerNode:
         for k in ("total_txn_commit_cnt", "total_txn_abort_cnt",
                   "defer_cnt", "write_cnt"):
             st.set(k, float(final[k] - measured[k]))
-        st.set("write_scatter_lane_cnt",
-               float(final["write_scatter_lanes"]
-                     - measured["write_scatter_lanes"]))
+        for k, dev in (("write_scatter_lane_cnt", "write_scatter_lanes"),
+                       ("read_gather_lane_cnt", "read_gather_lanes")):
+            st.set(k, float(final[dev] - measured[dev]))
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):
                 key = f"{fam}_by_type"

@@ -159,7 +159,8 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
         dbv = {n: (McTableView(t, me) if t.mc_parts > 1 else t)
                for n, t in db.items()}
         st = {k: jnp.zeros((), jnp.uint32) for k in
-              ("read_checksum", "write_cnt", "write_scatter_lanes")}
+              ("read_checksum", "write_cnt", "write_scatter_lanes",
+               "read_gather_lanes")}
         if chained:
             for lvl in range(n_levels if n_levels is not None
                              else cfg.exec_subrounds):
@@ -170,17 +171,20 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
             dbv = wl.execute(dbv, queries, commit, order, st)
         out = {n: (v.assemble() if isinstance(v, McTableView) else v)
                for n, v in dbv.items()}
-        # lanes issued differ per chip (each compacts its own winners)
+        # lanes issued differ per chip (each compacts its own winners,
+        # and every chip gathers all of its lanes)
         return (out, jax.lax.psum(st["read_checksum"], AXIS),
                 st["write_cnt"],
-                jax.lax.psum(st["write_scatter_lanes"], AXIS))
+                jax.lax.psum(st["write_scatter_lanes"], AXIS),
+                jax.lax.psum(st["read_gather_lanes"], AXIS))
 
-    out_db, cks, wcnt, lanes = jax.shard_map(
+    out_db, cks, wcnt, lanes, rlanes = jax.shard_map(
         body, mesh=mesh,
         in_specs=(db_spec, P(), P(), P(), P()),
-        out_specs=(db_spec, P(), P(), P()))(db, queries, commit, order,
-                                            level)
+        out_specs=(db_spec, P(), P(), P(), P()))(db, queries, commit, order,
+                                                 level)
     stats["read_checksum"] = stats["read_checksum"] + cks
     stats["write_cnt"] = stats["write_cnt"] + wcnt
     stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + lanes
+    stats["read_gather_lanes"] = stats["read_gather_lanes"] + rlanes
     return out_db

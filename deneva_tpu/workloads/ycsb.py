@@ -13,7 +13,9 @@ primary index is the identity `DenseIndex` (YCSB keys are dense,
 execute is one gather (reads, checksummed into stats so XLA cannot
 dead-code them) plus one last-writer scatter (writes); with full rows
 (`sim_full_row`) only the final writers' lanes reach that scatter,
-compacted first (`ops.scatter.scatter_winner_rows`).
+compacted first (`ops.scatter.scatter_winner_rows`), and of the
+forwarding executor's lanes only the reads that nothing forwards to
+reach the gather, compacted likewise (`ops.gather.checksum_needed_rows`).
 
 Multi-partition control (`FIRST_PART_LOCAL`, `PART_PER_TXN`, MPR
 `ycsb_query.cpp:303-376`) maps to the mesh build: keys are striped
@@ -30,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deneva_tpu.config import CCAlg, Config
-from deneva_tpu.ops import (HotSet, Zipfian, forward_plan, last_writer,
-                            scatter_winner_rows)
+from deneva_tpu.ops import (HotSet, Zipfian, checksum_needed_rows,
+                            forward_plan, last_writer, scatter_winner_rows)
 from deneva_tpu.storage.catalog import parse_schema
 from deneva_tpu.storage.index import DenseIndex, SortedIndex
 from deneva_tpu.storage.table import DeviceTable, VersionRing, create_mc
@@ -110,14 +112,25 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
                         mono: bool = False):
     """THE forwarding-executor data path, shared verbatim by the
     single-chip `execute` and each shard of `execute_mc` so their
-    semantics cannot diverge: reads gather F0 (forwarded lanes take
+    semantics cannot diverge: reads take F0 (forwarded lanes take
     f(key, writer rank) instead), the checksum folds over reads, and
     only final writers scatter.  Returns (f0', checksum, write_cnt,
-    scatter lanes) — the caller decides whether the scalars need a psum.
+    scatter lanes, gather lanes) — the caller decides whether the
+    scalars need a psum.
 
     ``f0`` is uint32[N] in fingerprint mode or uint8[N, S] under
     SIM_FULL_ROW — the full-row branch moves the real payload bytes, so
     benchmark numbers measure reference-width HBM traffic.
+
+    The read half.  Full rows: only the reads that nothing forwards to
+    (``is_read & (fwd < 0)``) reach the row gather, compacted first —
+    write lanes, forwarded reads and a shard's padding lanes never do,
+    and a lane costs the gather the same whatever it reads
+    (`ops.gather.checksum_needed_rows`); the forwarded reads add the
+    bytes of f(key, writer rank).  The checksum is the per-lane
+    gather's, to the bit.  Fingerprints (4.9 ns a uint32 lane): every
+    lane gathers.  The lanes handed to the gather come back as the
+    fifth value (`stats["read_gather_lanes"]`).
 
     The write half has three forms.  Full rows under ``mono`` (callers
     with key-monotone slot maps, i.e. every current caller: slot order
@@ -142,14 +155,17 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     The two halves carry the epoch's `ep.read` / `ep.write` scopes
     (metadata: `runtime/server._make_epoch_body`)."""
     with jax.named_scope("ep.read"):
-        vals = jnp.take(f0, jnp.where(p.is_read, slots, trash), axis=0)
         if f0.ndim == 2:
             nbytes = f0.shape[1]
-            vals = jnp.where((p.fwd >= 0)[:, None],
-                             _field_bytes(p.keys, p.fwd, nbytes), vals)
-            cks = jnp.sum(jnp.where(p.is_read[:, None], vals, 0),
-                          dtype=jnp.uint32)
+            tbl, rlanes = checksum_needed_rows(
+                f0, slots, p.is_read & (p.fwd < 0))
+            cks = tbl + jnp.sum(
+                jnp.where((p.is_read & (p.fwd >= 0))[:, None],
+                          _field_bytes(p.keys, p.fwd, nbytes), 0),
+                dtype=jnp.uint32)
         else:
+            rlanes = jnp.uint32(slots.shape[0])
+            vals = jnp.take(f0, jnp.where(p.is_read, slots, trash), axis=0)
             vals = jnp.where(p.fwd >= 0,
                              _field_fingerprint(p.keys, p.fwd), vals)
             cks = jnp.sum(jnp.where(p.is_read, vals, 0), dtype=jnp.uint32)
@@ -171,7 +187,15 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
                 else _field_fingerprint(p.keys, p.rank).astype(f0.dtype)
             f0 = f0.at[jnp.where(p.win, slots, trash)].set(wvals)
         wcnt = p.is_write.sum(dtype=jnp.uint32)
-    return f0, cks, wcnt, lanes
+    return f0, cks, wcnt, lanes, rlanes
+
+
+def _count(stats: dict, **add) -> None:
+    """One epoch's execution into the device counters
+    (`engine/step.init_device_stats`); a caller's hand-built dict may
+    hold only the counters it reads."""
+    for k, v in add.items():
+        stats[k] = stats.get(k, 0) + v
 
 
 class YCSBWorkload:
@@ -547,16 +571,17 @@ class YCSBWorkload:
             # mono holds per shard: plan keys are sorted with non-owned
             # lanes already masked to the big sentinel, so slots ascend
             # toward the block-local trash at the top
-            f0, cks, wcnt, lanes = _forward_execute_f0(
+            f0, cks, wcnt, lanes, rlanes = _forward_execute_f0(
                 f0, p, slots, trash, mono=True)
             return (f0, jax.lax.psum(cks, AXIS), jax.lax.psum(wcnt, AXIS),
-                    jax.lax.psum(lanes, AXIS), dfr)
+                    jax.lax.psum(lanes, AXIS), jax.lax.psum(rlanes, AXIS),
+                    dfr)
 
         with jax.named_scope("ep.exchange"):
-            f0, cks, wcnt, lanes, dfr = jax.shard_map(
+            f0, cks, wcnt, lanes, rlanes, dfr = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(AXIS), P(), P(), P(), P(), P()),
-                out_specs=(P(AXIS), P(), P(), P(),
+                out_specs=(P(AXIS), P(), P(), P(), P(),
                            P(AXIS) if pair_cap else P()))(
                     tab.columns["F0"], batch.keys, batch.rank, batch.ts,
                     batch.is_write, valid)
@@ -566,13 +591,11 @@ class YCSBWorkload:
                 # carries this scope
                 dfr = jax.lax.with_sharding_constraint(
                     dfr, NamedSharding(mesh, P()))
-            # (in the scope: the chip's compiler merges the three psums
+            # (in the scope: the chip's compiler merges the four psums
             # into one all-reduce that keeps no op_name; a trace reads
             # its scope from these consumers)
-            stats["read_checksum"] = stats["read_checksum"] + cks
-            stats["write_cnt"] = stats["write_cnt"] + wcnt
-            stats["write_scatter_lanes"] = \
-                stats["write_scatter_lanes"] + lanes
+            _count(stats, read_checksum=cks, write_cnt=wcnt,
+                   write_scatter_lanes=lanes, read_gather_lanes=rlanes)
         db = dict(db)
         db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
         return db, dfr
@@ -637,13 +660,11 @@ class YCSBWorkload:
             # under part_cnt striping (or an elastic mask at n_parts>1)
             # non-owned keys hit miss_slot INTERLEAVED between owned
             # slots — not monotone
-            f0, cks, wcnt, lanes = _forward_execute_f0(
+            f0, cks, wcnt, lanes, rlanes = _forward_execute_f0(
                 tab.columns["F0"], p, slots, tab.capacity,
                 mono=self.n_parts == 1)
-            stats["read_checksum"] = stats["read_checksum"] + cks
-            stats["write_cnt"] = stats["write_cnt"] + wcnt
-            stats["write_scatter_lanes"] = \
-                stats["write_scatter_lanes"] + lanes
+            _count(stats, read_checksum=cks, write_cnt=wcnt,
+                   write_scatter_lanes=lanes, read_gather_lanes=rlanes)
             db = dict(db)
             db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
             return db
@@ -725,7 +746,8 @@ class YCSBWorkload:
                     wslots, {"F0": _field_fingerprint(wkeys, worder)},
                     mask=win)
                 lanes = jnp.uint32(wslots.shape[0])
-            stats["write_cnt"] = stats["write_cnt"] + wmask.sum(dtype=jnp.uint32)
-            stats["write_scatter_lanes"] = \
-                stats["write_scatter_lanes"] + lanes
+            # (the gather above was handed every lane)
+            _count(stats, write_cnt=wmask.sum(dtype=jnp.uint32),
+                   write_scatter_lanes=lanes,
+                   read_gather_lanes=jnp.uint32(rslots.size))
         return db

@@ -129,6 +129,29 @@ def test_served_group_compiles_for_v5e(name, over, one_chip, monkeypatch):
     assert table + cfg.pipeline_groups * per_group < HBM_BYTES
 
 
+def _column_copies(hlo: str, shape: str) -> list[str]:
+    """Where the compiled program copies an array of ``shape``: "entry"
+    for the entry computation, "inner" for any other (a loop body, a
+    conditional's branch: once an EPOCH or more)."""
+    import re
+    where = None
+    copies = []
+    for ln in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%[\w.\-]+ \(", ln)
+        if head:
+            where = "entry" if head.group(1) else "inner"
+        if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln):
+            copies.append(where)
+    return copies
+
+
+def _row_gathers(hlo: str, width: int) -> set[int]:
+    """Lanes of every row gather of ``width``-byte rows in the program."""
+    import re
+    return {int(m.group(1)) for m in re.finditer(
+        r"= u8\[(\d+)," + str(width) + r"\]\S* gather\(", hlo)}
+
+
 @pytest.mark.parametrize("over", [
     dict(), dict(cc_alg="OCC", epoch_batch=1024, max_txn_in_flight=1 << 17,
                  client_batch_size=1024)], ids=["tpu_batch", "occ"])
@@ -140,24 +163,26 @@ def test_full_row_column_is_written_in_place_inside_the_epoch_scan(
     chip's compiler copied the whole column twice an EPOCH (my chip run,
     PR 26: 5 ms of copies around 2.3 ms of scatter; its `after` argument
     is the cure).  The only copies of the column are the entry
-    computation's two relayouts, once a GROUP, as at the parent."""
-    import re
+    computation's two relayouts, once a GROUP, as at the parent.
+
+    Since PR 30 the forwarding executor's reads come out of a loop too
+    (`ops.gather.checksum_needed_rows`) ahead of that write are
+    gathers of one to sixteen sixteenths of the plan's lanes inside the
+    branches of a conditional: the same pin holds (OCC's masked path
+    has no plan and gathers its 10,240 lanes in one call, as it did)."""
     cfg = served_cfg(sim_full_row="true", synth_table_size=1 << 21, **over)
     group, state, feed = _group_program(cfg, monkeypatch)
     state, feed = _with_sharding((state, feed), one_chip)
     compiled, _ = _compile(group, state["db"], state["cc_state"],
                            state["stats"], *feed)
     f0 = state["db"]["MAIN_TABLE"].columns["F0"]
-    shape = f"u8[{f0.shape[0]},{f0.shape[1]}]"
-    where = None
-    copies = []
-    for ln in compiled.as_text().splitlines():
-        head = re.match(r"^(ENTRY )?%[\w.\-]+ \(", ln)
-        if head:
-            where = "entry" if head.group(1) else "inner"
-        if re.search(r"= " + re.escape(shape) + r"\S* copy\(", ln):
-            copies.append(where)
+    hlo = compiled.as_text()
+    copies = _column_copies(hlo, f"u8[{f0.shape[0]},{f0.shape[1]}]")
     assert copies and set(copies) == {"entry"}, copies
+    if cfg.cc_alg == "TPU_BATCH":
+        lanes = cfg.epoch_batch * cfg.req_per_query
+        assert _row_gathers(hlo, f0.shape[1]) == {
+            k * lanes // 16 for k in range(1, 17)}
 
 
 def test_ycsb_loader_compiles_for_v5e(one_chip):
@@ -317,4 +342,26 @@ def test_dp4_cell_group_names_what_the_mesh_adds(dp4_cell):
     assert all("ep.exchange" in s for s in sorts)
     inner = [next(p for p in reversed(s) if p.startswith("ep."))
              for s in sorts]
-    assert sorted(inner) == ["ep.exchange"] * 3 + ["ep.plan", "ep.write"]
+    # (`ep.read`: the read heads' compaction, PR 30)
+    assert sorted(inner) == ["ep.exchange"] * 3 + ["ep.plan", "ep.read",
+                                                   "ep.write"]
+
+
+def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
+    """The one-chip pin (`test_full_row_column_is_written_in_place_
+    inside_the_epoch_scan`) on the four-chip cell: no chip copies its
+    629 MB shard of the column inside an epoch — the two relayouts in
+    the entry computation stay, once a group — and of a shard's 81,920
+    plan lanes a whole number of sixteenths reaches the row gather."""
+    cfg, _, state, compiled, _ = dp4_cell
+    f0 = state["db"]["MAIN_TABLE"].columns["F0"]
+    rows, width = f0.sharding.shard_shape(f0.shape)
+    hlo = compiled.as_text()
+    copies = _column_copies(hlo, f"u8[{rows},{width}]")
+    assert copies and set(copies) == {"entry"}, copies
+    from deneva_tpu.ops import mc_pair_cap
+    lanes = 4 * mc_pair_cap(cfg.epoch_batch, cfg.req_per_query, 4,
+                            cfg.mc_plan_capacity)
+    assert lanes == 81_920
+    assert _row_gathers(hlo, width) == {k * lanes // 16
+                                        for k in range(1, 17)}

@@ -293,16 +293,17 @@ def test_forward_execute_mono_scatter_matches_legacy():
     big = jnp.int32(np.iinfo(np.int32).max)
     slots = jnp.where(p.keys != big, p.keys, tab)     # identity index
     f0 = jnp.asarray(rng.integers(0, 2**32, tab + 1, dtype=np.uint32))
-    a_f0, a_cks, a_w, a_l = _forward_execute_f0(f0, p, slots, tab,
-                                                mono=False)
-    b_f0, b_cks, b_w, b_l = _forward_execute_f0(f0, p, slots, tab,
-                                                mono=True)
+    a_f0, a_cks, a_w, a_l, a_r = _forward_execute_f0(f0, p, slots, tab,
+                                                     mono=False)
+    b_f0, b_cks, b_w, b_l, b_r = _forward_execute_f0(f0, p, slots, tab,
+                                                     mono=True)
     # trash slot may differ (legacy parks losers there); data rows must not
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     assert int(a_cks) == int(b_cks) and int(a_w) == int(b_w)
-    # fingerprint mode hands the scatter every lane, in both forms
-    assert int(a_l) == int(b_l) == n
+    # fingerprint mode hands the scatter and the gather every lane, in
+    # both forms
+    assert int(a_l) == int(b_l) == int(a_r) == int(b_r) == n
 
 
 def test_forward_execute_mono_scatter_matches_legacy_full_row():
@@ -318,8 +319,10 @@ def test_forward_execute_mono_scatter_matches_legacy_full_row():
                           jnp.asarray(w))
     slots = p.keys
     f0 = jnp.asarray(rng.integers(0, 256, (tab + 1, width), dtype=np.uint8))
-    a_f0, a_cks, _, a_l = _forward_execute_f0(f0, p, slots, tab, mono=False)
-    b_f0, b_cks, _, b_l = _forward_execute_f0(f0, p, slots, tab, mono=True)
+    a_f0, a_cks, _, a_l, _ = _forward_execute_f0(f0, p, slots, tab,
+                                                 mono=False)
+    b_f0, b_cks, _, b_l, _ = _forward_execute_f0(f0, p, slots, tab,
+                                                 mono=True)
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     assert int(a_cks) == int(b_cks)
@@ -368,8 +371,8 @@ def test_mono_scatter_indices_keep_the_sorted_promise(write_frac):
     else:
         assert (wslot == rows).all()
     f0 = jnp.asarray(rng.integers(0, 2**32, rows, dtype=np.uint32))
-    a_f0, a_cks, _, _ = _forward_execute_f0(f0, p, slots, tab, mono=False)
-    b_f0, b_cks, _, _ = _forward_execute_f0(f0, p, slots, tab, mono=True)
+    a_f0, a_cks, *_ = _forward_execute_f0(f0, p, slots, tab, mono=False)
+    b_f0, b_cks, *_ = _forward_execute_f0(f0, p, slots, tab, mono=True)
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     # mono never touches the trash slot or a pad row

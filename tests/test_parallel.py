@@ -15,11 +15,11 @@ from deneva_tpu.parallel import make_mesh, make_sharded_run, state_shardings
 from deneva_tpu.workloads import get_workload
 
 
-# a count of device work, not of the serial semantics: each chip is
+# counts of device work, not of the serial semantics: each chip is
 # handed its own lanes (every lane of a fingerprint column, whole chunks
-# covering its own winners of a full-row one), so the sum over chips is
-# not the single device's
-WORK_ONLY = {"write_scatter_lanes"}
+# covering its own winners or read heads of a full-row one), so the sum
+# over chips is not the single device's
+WORK_ONLY = {"write_scatter_lanes", "read_gather_lanes"}
 
 
 def cfg_for(alg):

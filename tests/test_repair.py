@@ -317,9 +317,10 @@ def test_repair_noop_when_no_losers_bit_identical(alg):
     for a, b in zip(jax.tree.leaves(s_on.pool),
                     jax.tree.leaves(s_off.pool)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    # (`write_scatter_lanes` counts device work: the armed sub-rounds
-    # hand their scatters every lane again, all of them masked)
-    for k in set(s_on.stats) - {"write_scatter_lanes"}:
+    # (`write_scatter_lanes` and `read_gather_lanes` count device work:
+    # the armed sub-rounds hand their scatters and gathers every lane
+    # again, all of them masked)
+    for k in set(s_on.stats) - {"write_scatter_lanes", "read_gather_lanes"}:
         np.testing.assert_array_equal(np.asarray(s_on.stats[k]),
                                       np.asarray(s_off.stats[k]), k)
     assert int(s_on.stats["rep_salvaged_cnt"]) == 0
