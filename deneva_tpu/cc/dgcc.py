@@ -11,7 +11,7 @@ audit plane's edge-derivation kernel (`cc/depgraph.py` — one exact-key
 lane sort + segmented scans, zero bucket-collision false conflicts)
 over the PLANNED access sets of all active txns and assigns each txn an
 execution wave, the chained-level machinery CALVIN/TPU_BATCH already
-execute through (`engine/step._run_levels`, the repair engine's
+execute through (`engine/epoch.run_levels`, the repair engine's
 re-execution waves generalized): wave k re-reads only rows written by
 waves < k.  Near-zero aborts by construction: the only non-commit
 outcome is a DEFER of over-deep dependency closures to the next epoch's

@@ -84,7 +84,7 @@ def must_precede(cfg, inc: Incidence, b: int):
     """P[i, j] = i must precede j (i read a key j writes; snapshot read),
     minus the RMW self-overlap diagonal.  The ONE edge derivation shared
     by validate_maat and the distributed verify round
-    (runtime/server.make_vote_steps.check): the verify round must check
+    (engine/epoch.make_vote_steps.check): the verify round must check
     exactly the edge set the positions were negotiated for.
 
     Escrow (``order_free``) exemption, gated by ``escrow_order_free``

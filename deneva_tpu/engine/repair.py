@@ -8,8 +8,8 @@ admission, re-plans, re-reads everything and pays an exponential backoff
 winners.  But every sweep backend already materializes the conflict
 incidence the repair literature needs (`cc.base.build_incidence`), so
 the invalidated-read frontier of each loser is one matvec away, and the
-Calvin chained sub-round machinery (`cc/calvin.py`, `engine/step.
-_run_levels`) is the template for executing a second dependent wave
+Calvin chained sub-round machinery (`cc/calvin.py`, `engine/epoch.
+run_levels`) is the template for executing a second dependent wave
 inside the same epoch.  Repair turns the losers of a sweep round into
 that second wave:
 

@@ -128,6 +128,7 @@ import sys
 import time
 
 from deneva_tpu.config import CCAlg, Config, WorkloadKind
+from deneva_tpu.engine.epoch import make_dist_step
 from deneva_tpu.stats import parse_summary
 
 
@@ -802,7 +803,6 @@ def _check_partition(name: str, cfg: Config, out: dict, run_id: str,
     from deneva_tpu.runtime.logger import (iter_record_spans, replay_into,
                                            state_digest)
     from deneva_tpu.runtime.membership import MEMBER_KEY, initial_map
-    from deneva_tpu.runtime.server import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     n_srv = cfg.node_cnt
@@ -1204,7 +1204,6 @@ def _check_recovery(cfg: Config, out: dict, run_id: str,
     is a byte prefix of its primary's."""
     from deneva_tpu.runtime.logger import (
         iter_record_spans, replay_into, state_digest)
-    from deneva_tpu.runtime.server import make_dist_step
 
     kill_node, _ = cfg.fault_kill_spec()
     log_dir = os.path.join(cfg.log_dir, run_id)

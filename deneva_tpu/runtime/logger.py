@@ -31,6 +31,8 @@ import queue as _queue
 
 import numpy as np
 
+from deneva_tpu.engine.epoch import make_dist_step
+
 _FRAME = struct.Struct("<IqII")
 _MAGIC = 0xDE7E7A10
 
@@ -251,7 +253,6 @@ def replay_log(path: str, cfg) -> dict:
     """
     from deneva_tpu.cc import get_backend
     from deneva_tpu.engine.step import init_device_stats
-    from deneva_tpu.runtime.server import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     wl = get_workload(cfg)

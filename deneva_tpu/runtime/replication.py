@@ -75,6 +75,7 @@ import time
 import numpy as np
 
 from deneva_tpu.config import Config
+from deneva_tpu.engine.epoch import make_dist_step
 
 # ---- region assignment -------------------------------------------------
 
@@ -299,7 +300,6 @@ def follower_boot(cfg: Config, primary: int):
     from deneva_tpu.cc import get_backend
     from deneva_tpu.engine.step import init_device_stats
     from deneva_tpu.runtime.membership import MEMBER_KEY, initial_map
-    from deneva_tpu.runtime.server import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     fcfg = cfg.replace(node_id=primary, recover=False, fault_kill="")

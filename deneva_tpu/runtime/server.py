@@ -42,6 +42,11 @@ from collections import deque
 import numpy as np
 
 from deneva_tpu.config import CCAlg, Config
+# make_dist_step: ServerNode's replay paths use it, and it stays
+# importable from here because benchmark/verdicts_child.py (an accepted
+# benchmark file) imports it from this module (ROADMAP D0 repoints it)
+from deneva_tpu.engine.epoch import (make_dist_group, make_dist_step,
+                                     make_vote_steps)
 from deneva_tpu.runtime import replication as georepl
 from deneva_tpu.runtime import wire
 from deneva_tpu.runtime.telemetry import (ST_ADMIT, ST_BATCH, ST_HOLD,
@@ -51,515 +56,9 @@ from deneva_tpu.runtime.telemetry import (ST_ADMIT, ST_BATCH, ST_HOLD,
 from deneva_tpu.runtime.native import NativeTransport
 from deneva_tpu.runtime.stages import StageClock, span as stage_span
 from deneva_tpu.stats import Stats
+from deneva_tpu.workloads.base import EXEC_COUNTERS
 
 _TAG_MASK = np.int64((1 << 40) - 1)
-
-
-def _make_epoch_body(cfg: Config, wl, be):
-    """Pure per-epoch validation+execution body shared by the per-epoch
-    jit (replay path) and the pipelined multi-epoch dispatch group.
-
-    Deterministic: every server runs this exact function on the identical
-    merged batch, so verdicts agree without any vote exchange.
-    Returns (body, b_merged) where body maps
-    (db, cc_state, stats, active, ts, query, epoch=None) ->
-    (db, cc_state, stats, done, restart_abort, defer, rep, dens, aud).
-    ``rep`` marks txns that committed via transaction repair
-    (engine/repair.py — a subset of ``done``; all-false when
-    ``cfg.repair`` is off, and the group jit only packs its plane when
-    armed, so the off-wire stays bit-identical).  ``dens`` is the
-    per-partition observed-conflict density (int32[P], the metrics
-    bus's per-epoch contention signal) when ``cfg.metrics`` is armed,
-    else None — with metrics off the body computes nothing extra and
-    the group jit's outputs are exactly the pre-bus ones.  ``aud`` is
-    the isolation audit plane's per-epoch observation tuple
-    (cc/base.audit_observe: packed edges, edge buckets, counts,
-    digests) when ``cfg.audit`` is armed, else None; armed bodies take
-    ``epoch`` — an observation LABEL (and the audit_mutate window key),
-    never an input to any verdict, and the log replay path feeds the
-    recorded epoch numbers back so replay reproduces the observations
-    bit for bit.
-
-    The phases of an epoch carry `jax.named_scope`s — metadata only, the
-    compiled program is the same — so a device trace can say which phase
-    an operation belongs to whatever the compiler numbers it: `ep.plan`
-    (the workload's plan, the access batch, and on the forwarding path
-    the plan sort of `forward_verdict`, which validates nothing),
-    `ep.validate` (incidence + the backend's sweep: sweep backends
-    only), `ep.read` / `ep.write` (inside the workload's executor, where
-    the gather and the scatter are), `ep.levels`, `ep.repair`,
-    `ep.stats` (counters); the group program adds `ep.decode` and
-    `grp.pack`.  Nested scopes read innermost-first: a gather under
-    `ep.levels/ep.read` is a read.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    import dataclasses as _dc
-
-    from deneva_tpu.cc import (AccessBatch, build_conflict_incidence,
-                               conflict_density, gate_order_free)
-    from deneva_tpu.engine.step import forced_sentinel_mask
-    from deneva_tpu.ops import (forward_verdict, forwarding_applies,
-                                mc_defer_verdict)
-
-    # merged batch = equal slices per server; epoch_batch is the budget
-    b = max(1, cfg.epoch_batch // cfg.node_cnt) * cfg.node_cnt
-    forwarding = forwarding_applies(be, wl)
-
-    def step(db, cc_state, stats, active, ts, query, epoch=None):
-        rep = None
-        srounds = None
-        dens = None
-        aud_out = None
-        with jax.named_scope("ep.plan"):
-            rank = jnp.arange(b, dtype=jnp.int32)
-            planned = wl.plan(db, query)
-            batch = AccessBatch(
-                table_ids=planned["table_ids"], keys=planned["keys"],
-                is_read=planned["is_read"], is_write=planned["is_write"],
-                valid=planned["valid"], ts=ts, rank=rank, active=active,
-                order_free=gate_order_free(cfg, be,
-                                           planned.get("order_free")))
-            forced = forced_sentinel_mask(batch) \
-                if cfg.ycsb_abort_mode else None
-        inc = None
-        if forwarding:
-            fbatch = batch if forced is None else _dc.replace(
-                batch, active=batch.active & ~forced)
-            if cfg.device_parts > 1:
-                # mesh-sharded measured path: per-shard plans and the
-                # capacity-overflow defers are decided inside
-                # wl.execute_mc (shard-local O(N/D) + one all_gather),
-                # so the verdict is built AFTER execution from the
-                # replicated defer mask — identical structure to the
-                # in-process engine's multi-chip branch (engine/step.py)
-                db, mc_dfr = wl.execute_mc(db, fbatch, stats)
-                verdict = mc_defer_verdict(fbatch, mc_dfr)
-                if forced is not None:
-                    forced = forced & ~(verdict.abort | verdict.defer)
-                exec_commit = verdict.commit
-            else:
-                with jax.named_scope("ep.plan"):
-                    verdict, fwd = forward_verdict(fbatch)
-                # forward_verdict never aborts/defers, so the CC-retry
-                # filter below is a no-op here — applied anyway to keep
-                # the forced semantics identical to Engine.step (and
-                # future-proof against forwarding backends that defer)
-                if forced is not None:
-                    forced = forced & ~(verdict.abort | verdict.defer)
-                exec_commit = verdict.commit
-                # commit set baked into the plan (fbatch.active);
-                # mask=None is asserted by the executor so the two
-                # cannot diverge
-                db = wl.execute(db, query, None, verdict.order, stats,
-                                fwd_rank=fwd)
-        else:
-            if be.alg == CCAlg.DGCC:
-                # DGCC: exact-key lane graph (cc/depgraph), no hashed
-                # incidence; the stats dict carries the [dgcc] counters
-                # (the repair-engine stats contract).  The verdict is a
-                # pure replicated function of the merged batch, so the
-                # three verdict planes stay bit-identical across nodes
-                # and dp shardings — exactly CALVIN's cluster shape.
-                with jax.named_scope("ep.validate"):
-                    verdict, cc_state = be.validate(cfg, cc_state, batch,
-                                                    None, stats=stats)
-            else:
-                with jax.named_scope("ep.validate"):
-                    inc = build_conflict_incidence(cfg, be, batch,
-                                                   batch.order_free)
-                    verdict, cc_state = be.validate(cfg, cc_state, batch,
-                                                    inc)
-            if cfg.audit_mutate:
-                # seeded edge-derivation fault (the audit plane's
-                # anti-inert knob): flipped losers execute and ack like
-                # any commit — a real isolation violation every server
-                # computes identically (config-keyed) and replay
-                # reproduces (the epoch label rides the log)
-                from deneva_tpu.cc import audit_mutate_verdict
-                verdict = audit_mutate_verdict(cfg, batch, inc, verdict,
-                                               epoch)
-            if forced is not None:
-                forced = forced & ~(verdict.abort | verdict.defer)
-            exec_commit = verdict.commit if forced is None \
-                else verdict.commit & ~forced
-            if cfg.device_parts > 1:
-                # generic partition-parallel execution (workloads/mc):
-                # replicated verdict, owner-major sharded tables, the
-                # workload's own execute body per chip under shard_map
-                from deneva_tpu.workloads.mc import mc_execute
-                db = mc_execute(cfg, wl, db, query, exec_commit,
-                                verdict.order, verdict.level, stats,
-                                chained=be.chained,
-                                level_exec=be.alg != CCAlg.DGCC,
-                                n_levels=cfg.dgcc_levels
-                                if be.alg == CCAlg.DGCC else None)
-            elif be.chained:
-                from deneva_tpu.engine.step import _run_levels
-                with jax.named_scope("ep.levels"):
-                    db, stats = _run_levels(
-                        cfg, wl, db, query, exec_commit, verdict, stats,
-                        level_exec=be.alg != CCAlg.DGCC)
-            else:
-                db = wl.execute(db, query, exec_commit, verdict.order,
-                                stats)
-            # transaction repair (engine/repair.py, default off): fused
-            # sub-rounds re-executing the losers against post-winner
-            # state — part of the replicated deterministic verdict
-            # (config pins merged mode), so every server computes the
-            # identical salvaged set and replay reproduces it
-            if cfg.repair and be.repair_rule is not None \
-                    and not be.chained:
-                from deneva_tpu.engine.repair import run_repair
-                with jax.named_scope("ep.repair"):
-                    db, cc_state, verdict, rep, srounds = run_repair(
-                        cfg, wl, be, db, query, batch, inc, verdict,
-                        cc_state, stats, exec_commit, forced)
-                exec_commit = exec_commit | rep
-        if cfg.metrics:
-            # metrics bus: per-partition observed-conflict density off
-            # the incidence views the sweep already materialized (the
-            # forwarding path pays two bucket scatter-adds instead) —
-            # an OBSERVATION of the batch, never an input to any
-            # verdict, so replay determinism is untouched
-            dens = conflict_density(cfg, batch, planned["owner"], inc)
-        # forced txns complete (acked + released by the caller via the
-        # commit mask) but count as aborts, exactly like the engine
-        with jax.named_scope("ep.stats"):
-            commit = exec_commit & active
-            done = commit if forced is None \
-                else (commit | (forced & active))
-            abort = verdict.abort & active
-            if forced is not None:
-                abort = abort | (forced & active)
-            defer = verdict.defer & active
-            stats = dict(stats)
-            stats["total_txn_commit_cnt"] += commit.sum(dtype=jnp.uint32)
-            stats["total_txn_abort_cnt"] += abort.sum(dtype=jnp.uint32)
-            stats["defer_cnt"] += defer.sum(dtype=jnp.uint32)
-            from deneva_tpu.engine.step import count_by_type
-            count_by_type(stats, wl, query, commit, abort)
-            rep = jnp.zeros_like(done) if rep is None else rep & active
-        if cfg.audit:
-            # isolation audit (cc/base.audit_observe): dependency
-            # observations of the FINAL committed set — pure
-            # observation, never an input to a verdict or a table
-            # write, so armed-vs-off verdicts/logs stay bit-identical.
-            # Visibility: forwarding = serial-in-order; chained =
-            # levels; repair salvage waves = their sub-round; level-0
-            # sweeps = epoch-start snapshot.
-            from deneva_tpu.cc import AUDIT_KEY, audit_observe
-            order_vis = forwarding
-            if forwarding:
-                lvl = jnp.zeros_like(verdict.level)
-            elif be.chained:
-                lvl = verdict.level
-            else:
-                lvl = srounds if srounds is not None \
-                    else jnp.zeros_like(verdict.level)
-            aud2, edges, ebkt, cnt, drop, vdig, rdig = audit_observe(
-                cfg, batch, commit, verdict.order, lvl, order_vis,
-                db[AUDIT_KEY], epoch)
-            db = dict(db)
-            db[AUDIT_KEY] = aud2
-            stats["audit_edge_cnt"] += cnt.astype(jnp.uint32)
-            stats["audit_drop_cnt"] += drop.astype(jnp.uint32)
-            if not forwarding and not be.chained:
-                # witness density: CLAIM-VIOLATING edges (both
-                # endpoints at level 0 of a zero-edge-claim backend;
-                # repair-salvaged endpoints sit at lvl >= 1).  Chained/
-                # forwarding backends legitimately emit edges, so the
-                # counter stays zero for them by the same rule the
-                # in-process engine applies (engine/step.py 5c).
-                from deneva_tpu.cc.depgraph import witness_count
-                stats["audit_wit_cnt"] += witness_count(
-                    edges, lvl).astype(jnp.uint32)
-            aud_out = (edges, ebkt, cnt, drop, vdig, rdig)
-        return (db, cc_state, stats, done, abort & ~done, defer, rep,
-                dens, aud_out)
-
-    return step, b
-
-
-def make_dist_step(cfg: Config, wl, be):
-    """Jitted single-epoch step (kept for the log-replay path, which
-    re-executes the command stream one recorded epoch at a time)."""
-    import jax
-
-    body, _ = _make_epoch_body(cfg, wl, be)
-
-    @jax.jit
-    def step(db, cc_state, stats, epoch, active, ts, query):
-        # determinism: verdicts depend only on the feed.  The audit
-        # plane consumes the epoch as an observation LABEL (stamp-table
-        # entries + the audit_mutate window key); replay feeds the
-        # recorded epoch numbers back, so replayed observations are
-        # bit-identical too.
-        ep = epoch if cfg.audit else None
-        return body(db, cc_state, stats, active, ts, query, epoch=ep)
-
-    return step
-
-
-def make_dist_group(cfg: Config, wl, be, width: int, n_scalars: int):
-    """Jitted C-epoch dispatch group for the pipelined cluster loop.
-
-    ``lax.scan`` threads (db, cc_state, stats) through ``pipeline_epochs``
-    consecutive merged epochs in ONE device dispatch: the host pays its
-    2-3 host<->device transfers (and their dispatch latency) per GROUP
-    instead of per epoch.  Commit masks come back only for this
-    node's slice of the merged batch (all a node ever consumes: CL_RSP +
-    retry routing), cutting the down-transfer by node_cnt.  State buffers
-    are donated so K in-flight groups do not multiply table memory.
-
-    The feed is the RAW WIRE COLUMNS (keys/types/scalars), shipped as
-    FLAT 1-D buffers and decoded on device by ``wl.from_wire_dev``: a
-    [C, b, W] leaf with a small minor dimension (W ~ 10) gets its minor
-    dim padded to the 128-lane tile in the device layout, so
-    transferring it shaped costs ~13x the bytes.  Flat transfers
-    relayout on chip at HBM speeds instead.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    body, b = _make_epoch_body(cfg, wl, be)
-    C = max(1, cfg.pipeline_epochs)
-    b_loc = b // cfg.node_cnt
-    lo = cfg.node_id * b_loc
-    # elastic + faults: verdict planes cover the FULL merged batch, not
-    # just this node's slice — a survivor needs every slice's committed
-    # tags for re-ack takeover after a dead peer's slots are reassigned
-    # (the committed set must outlive its admitting server).  Off this
-    # mode the shapes (and the d2h volume) are exactly the pre-elastic
-    # ones.
-    full_planes = cfg.elastic and cfg.faults_enabled
-    mask_n = b if full_planes else b_loc
-    sl = slice(0, b) if full_planes else slice(lo, lo + b_loc)
-    pb = (mask_n + 7) // 8 * 8          # bit-pack padding
-
-    # a 4th "repaired" verdict plane rides the d2h stack ONLY when the
-    # repair subsystem is armed (rep_* accounting + the repair timeline
-    # span at retirement); off, the stack shape and bytes are exactly
-    # the pre-repair three planes
-    n_planes = 4 if cfg.repair else 3
-
-    def scan_body(carry, xs):
-        db, cc_state, stats = carry
-        if cfg.audit:
-            # the audit plane labels each epoch's observations with its
-            # number (stamp tables + the audit_mutate window key): the
-            # host feeds the group's epoch indices as one extra int32[C]
-            # scan input when — and only when — audit is armed
-            active, ts, keys, types, scal, ep = xs
-        else:
-            active, ts, keys, types, scal = xs
-            ep = None
-        with jax.named_scope("ep.decode"):
-            query = wl.from_wire_dev(keys, types, scal)
-        db, cc_state, stats, done, abort, defer, rep, dens, aud = body(
-            db, cc_state, stats, active, ts, query, epoch=ep)
-        outs = (done[sl], abort[sl], defer[sl], rep[sl])
-        if cfg.metrics:
-            # per-epoch density plane rides the scan outputs ONLY when
-            # the bus is armed — off, the d2h volume is exactly the
-            # pre-bus verdict planes
-            outs = outs + (dens,)
-        if cfg.audit:
-            # audit observation planes (edges/buckets/counts/digests)
-            # ride the d2h stack only when armed — same off-contract as
-            # the density plane
-            outs = outs + aud
-        return (db, cc_state, stats), outs
-
-    def pack(m):
-        # bool[C, b_loc] -> uint8[C, pb/8], little-endian bit order (the
-        # host unpacks with np.unpackbits(bitorder="little")).  The
-        # verdict planes cross d2h once per group and gate every ack:
-        # as bits they are 8x fewer bytes than bools.
-        w = jnp.pad(m, ((0, 0), (0, pb - mask_n))).reshape(m.shape[0], -1, 8)
-        weights = jnp.left_shift(jnp.ones((8,), jnp.uint8),
-                                 jnp.arange(8, dtype=jnp.uint8))
-        return (w.astype(jnp.uint8) * weights).sum(-1).astype(jnp.uint8)
-
-    # donation is claimed off the CPU backend only (the tests' backend
-    # keeps every argument readable after the call).  Consequence for
-    # host code on the chip: a donated array is DELETED at dispatch —
-    # the caller adopts the returned state and never reads a feed
-    # buffer again.  Besides the persistent state pytrees
-    # (db/cc_state/stats), the per-group FEED buffers are donated too:
-    # each is a fresh device_put the host never rereads, so XLA can
-    # reuse their pages for the scan carries instead of allocating a
-    # second copy per in-flight group — the "persistent donated epoch
-    # buffers" half of the pod-scale path (the host side already
-    # recycles the pinned staging buffers via _feed_acquire).
-    donate = (0, 1, 2, 3, 4, 5, 6, 7) if jax.default_backend() != "cpu" \
-        else ()
-
-    @functools.partial(jax.jit, donate_argnums=donate)
-    def group(db, cc_state, stats, active_f, ts_f, keys_f, types_f,
-              scal_f, epochs_f=None):
-        active = active_f.reshape(C, b)
-        ts = ts_f.reshape(C, b)
-        keys = keys_f.reshape(C, b, width)
-        types = types_f.reshape(C, b, width)
-        scal = scal_f.reshape(C, b, n_scalars)
-        xs = (active, ts, keys, types, scal)
-        if cfg.audit:
-            xs = xs + (epochs_f,)
-        (db, cc_state, stats), masks = jax.lax.scan(
-            scan_body, (db, cc_state, stats), xs)
-        with jax.named_scope("grp.pack"):
-            planes = jnp.stack([pack(masks[i]) for i in range(n_planes)])
-        out = (db, cc_state, stats, planes)
-        if cfg.metrics:
-            # int32[C, P] per-epoch density beside the packed planes
-            # (the scan outputs carry the four mask planes at 0..3
-            # whether or not repair packs its plane, so density sits at
-            # the FIXED index 4)
-            out = out + (masks[4],)
-        if cfg.audit:
-            # audit observation stack: ([C, E] edges, [C, E] buckets,
-            # [C] cnt, [C] dropped, [C] vdig, [C] rdig)
-            out = out + (masks[-6:],)
-        return out
-
-    return group
-
-
-def make_vote_steps(cfg: Config, wl, be):
-    """Batched 2PC (VOTE protocol) jits for non-deterministic backends.
-
-    The reference coordinates a multi-partition txn with per-txn
-    prepare/ack round trips (`system/txn.cpp:498-606`); here the whole
-    epoch prepares at once:
-
-    * ``vote(db, cc_state, query, active, ts)`` — each server validates
-      ONLY the accesses it owns (the workload plan's ``owner`` map masks
-      the rest invalid) against its LOCAL cross-epoch state, yielding its
-      per-txn prepare votes.  Soundness: every conflicting access pair
-      shares a key, the key's single owner sees both sides, and every
-      backend's serialization order in vote mode is a *globally shared*
-      total order (rank for locks/OCC, birth-ts for T/O) — so the union
-      of locally-conflict-free commit sets is serializable in that order.
-      (MAAT's locally-derived order is not shared — it negotiates
-      positions through the vote payloads instead, below.)
-    * ``apply(...)`` — after the vote exchange decides (commit = every
-      owner voted yes, abort = any owner voted abort, else wait), execute
-      the decided set locally and advance cross-epoch CC state for
-      GLOBAL commits only (`CCBackend.commit_state` — the reference
-      updates row ts-state on the 2PC commit path, not at prepare).
-
-    MAAT (round-4): its dynamic serialization order is locally derived,
-    so the vote additionally negotiates POSITIONS, the batch analogue of
-    the reference's timestamp-range negotiation
-    (`concurrency_control/maat.cpp:176-190` intersects `[lower,upper)`
-    bounds shipped on RACK_PREP, `transport/message.cpp:1057-1137`):
-
-    1. prepare: each owner's local validate yields per-txn lower-bound
-       positions (``verdict.order // b`` — its local ancestor count),
-       piggybacked on the VOTE message;
-    2. intersect: every node takes the elementwise MAX of all bounds —
-       the least position satisfying every owner's local constraints
-       (the reference's range intersection, commit point = lower end);
-    3. verify (``check``): each owner re-checks its local must-precede
-       edges against the final positions; a violated edge — exactly the
-       signature of a CROSS-NODE cycle such as distributed write skew,
-       which no single owner can see — aborts its later-positioned
-       endpoint, announced in a second VOTE round.  Survivors' edges all
-       agree with one shared total order, so the union is serializable.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from deneva_tpu.cc import (AccessBatch, build_conflict_incidence,
-                               gate_order_free)
-
-    b = max(1, cfg.epoch_batch // cfg.node_cnt) * cfg.node_cnt
-    me = cfg.node_id
-
-    def local_batch(db, query, active, ts):
-        rank = jnp.arange(b, dtype=jnp.int32)
-        planned = wl.plan(db, query)
-        owned = planned["valid"] & (planned["owner"] == jnp.int32(me))
-        # ro_hint: GLOBAL read-only classification from the unmasked plan
-        # — without it a cross-partition rw-txn would look read-only to
-        # the node owning only its reads and skip MVCC read validation
-        ro = ~(planned["valid"] & planned["is_write"]).any(axis=1)
-        batch = AccessBatch(
-            table_ids=planned["table_ids"], keys=planned["keys"],
-            is_read=planned["is_read"], is_write=planned["is_write"],
-            valid=owned, ts=ts, rank=rank, active=active, ro_hint=ro,
-            # per-access flags, so the owner mask composes: each owner
-            # exempts exactly its owned escrow accesses (and advances
-            # its LOCAL watermarks with the same rules at commit)
-            order_free=gate_order_free(cfg, be,
-                                       planned.get("order_free")))
-        return batch, planned
-
-    def global_order(batch):
-        # must be identical on every node: locks/OCC serialize in merged
-        # rank order; the T/O family in birth-ts order, with GLOBALLY
-        # read-only MVCC txns at the snapshot point (batch.ro_hint comes
-        # from the unmasked plan so every node agrees)
-        if cfg.cc_alg == CCAlg.TIMESTAMP:
-            return batch.ts
-        if cfg.cc_alg == CCAlg.MVCC:
-            return jnp.where(batch.ro_hint, 0, batch.ts)
-        return batch.rank
-
-    maat = cfg.cc_alg == CCAlg.MAAT
-
-    @jax.jit
-    def vote(db, cc_state, query, active, ts):
-        batch, planned = local_batch(db, query, active, ts)
-        inc = build_conflict_incidence(cfg, be, batch, batch.order_free)
-        verdict, _ = be.validate(cfg, cc_state, batch, inc)
-        # MAAT lower bound = local serialization position (order packs
-        # position * b + lane; undo the lane)
-        lo = verdict.order // jnp.int32(b)
-        return verdict.commit, verdict.abort, verdict.defer, lo
-
-    @jax.jit
-    def check(db, query, cand, ts, order):
-        """MAAT verify round: my local must-precede edges AMONG THE
-        GLOBAL COMMIT CANDIDATES (the AND of round-1 votes) vs the
-        intersected positions; a violated edge aborts its
-        later-positioned endpoint (the range that closed).  Candidates
-        only: at node_cnt=1 each candidate's position is this node's own
-        locally-consistent order, so no edge can violate and vote mode
-        decides exactly like merged mode."""
-        from deneva_tpu.cc.maat import must_precede
-        batch, planned = local_batch(db, query, cand, ts)
-        inc = build_conflict_incidence(cfg, be, batch, batch.order_free)
-        p = must_precede(cfg, inc, b)
-        p = p & cand[:, None] & cand[None, :]
-        # order values are distinct (lane tiebreak), so >= means >
-        viol = p & (order[:, None] >= order[None, :])
-        return viol.any(axis=1)
-
-    @jax.jit
-    def apply(db, cc_state, stats, query, active, ts, commit, abort,
-              defer, order):
-        batch, planned = local_batch(db, query, active, ts)
-        commit = commit & active
-        abort = abort & active
-        defer = defer & active
-        if be.commit_state is not None:
-            # watermark buckets are self-hashed from the batch (see
-            # cc/timestamp._wm_bucket) — no incidence rebuild needed here
-            cc_state = be.commit_state(cfg, cc_state, batch, None, commit)
-        db = wl.execute(db, query, commit,
-                        order if maat else global_order(batch), stats)
-        stats = dict(stats)
-        stats["total_txn_commit_cnt"] += commit.sum(dtype=jnp.uint32)
-        stats["total_txn_abort_cnt"] += abort.sum(dtype=jnp.uint32)
-        stats["defer_cnt"] += defer.sum(dtype=jnp.uint32)
-        from deneva_tpu.engine.step import count_by_type
-        count_by_type(stats, wl, query, commit, abort)
-        return db, cc_state, stats
-
-    return vote, check, apply
 
 
 class _RetryQueue:
@@ -3450,9 +2949,10 @@ class ServerNode:
         for k in ("total_txn_commit_cnt", "total_txn_abort_cnt",
                   "defer_cnt", "write_cnt"):
             st.set(k, float(final[k] - measured[k]))
-        for k, dev in (("write_scatter_lane_cnt", "write_scatter_lanes"),
-                       ("read_gather_lane_cnt", "read_gather_lanes")):
-            st.set(k, float(final[dev] - measured[dev]))
+        # the executors' lane counters: `<x>_lanes` -> `<x>_lane_cnt`
+        for dev in EXEC_COUNTERS:
+            if dev.endswith("_lanes"):
+                st.set(dev[:-1] + "_cnt", float(final[dev] - measured[dev]))
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):
                 key = f"{fam}_by_type"

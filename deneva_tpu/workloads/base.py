@@ -35,6 +35,25 @@ import jax.numpy as jnp
 
 DB = dict  # table name -> DeviceTable; a pytree
 
+# The executors' device counters: what an ``execute`` body adds to the
+# ``stats`` dict it is handed.  ONE list: `engine/step.init_device_stats`
+# makes its zeros from it, `workloads/mc.mc_execute` its per-chip dict,
+# its reductions and its out-specs (in this order), and the server's
+# `[summary]` reads each ``*_lanes`` counter as ``*_lane_cnt``.  A new
+# counter is a line here plus `engine/checkpoint.SCHEMA_VERSION`.
+EXEC_COUNTERS = (
+    "read_checksum",
+    "write_cnt",
+    # lanes handed to YCSB's F0 scatter (ops/scatter.
+    # scatter_winner_rows): against write_cnt and the epoch's lane
+    # count it says how far the winner compaction engages
+    "write_scatter_lanes",
+    # lanes handed to YCSB's F0 gather: under a forwarding plan with
+    # full rows the unforwarded reads, in whole sixteenths of the
+    # plan (ops/gather.checksum_needed_rows), else every lane
+    "read_gather_lanes",
+)
+
 
 def partition_owned(key: jax.Array, n_parts: int, me: int) -> jax.Array:
     """bool mask: does this node own ``key`` under modulo striping

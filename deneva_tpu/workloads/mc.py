@@ -43,6 +43,7 @@ from jax.sharding import PartitionSpec as P
 
 from deneva_tpu.parallel.mesh import AXIS, current_mesh
 from deneva_tpu.storage.table import DeviceTable, mc_block_geometry
+from deneva_tpu.workloads.base import EXEC_COUNTERS
 
 
 class McTableView:
@@ -140,7 +141,7 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
     ``commit``/``order``/``level`` come from the replicated verdict; for
     chained backends each wavefront level executes as a sub-round against
     the chip-local table state, exactly like the single-chip engine loop
-    (`engine/step.py`).  ``level_exec`` follows `engine/step._run_levels`:
+    (`engine/epoch.py`).  ``level_exec`` follows `engine/epoch.run_levels`:
     True claims each sub-round's committed set is write-conflict-free
     (CALVIN/TPU_BATCH); False (DGCC) keeps the per-wave ``last_writer``
     order tournament, so same-wave duplicate writers resolve identically
@@ -158,9 +159,7 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
         me = jax.lax.axis_index(AXIS)
         dbv = {n: (McTableView(t, me) if t.mc_parts > 1 else t)
                for n, t in db.items()}
-        st = {k: jnp.zeros((), jnp.uint32) for k in
-              ("read_checksum", "write_cnt", "write_scatter_lanes",
-               "read_gather_lanes")}
+        st = {k: jnp.zeros((), jnp.uint32) for k in EXEC_COUNTERS}
         if chained:
             for lvl in range(n_levels if n_levels is not None
                              else cfg.exec_subrounds):
@@ -171,20 +170,19 @@ def mc_execute(cfg, wl, db: dict, queries, commit: jax.Array,
             dbv = wl.execute(dbv, queries, commit, order, st)
         out = {n: (v.assemble() if isinstance(v, McTableView) else v)
                for n, v in dbv.items()}
-        # lanes issued differ per chip (each compacts its own winners,
-        # and every chip gathers all of its lanes)
-        return (out, jax.lax.psum(st["read_checksum"], AXIS),
-                st["write_cnt"],
-                jax.lax.psum(st["write_scatter_lanes"], AXIS),
-                jax.lax.psum(st["read_gather_lanes"], AXIS))
+        # what a chip gathers and scatters is its own (each compacts its
+        # own winners, and every chip gathers all of its lanes): summed
+        # over the mesh; write_cnt is counted from the replicated commit
+        # mask and is every chip's already
+        return (out,) + tuple(
+            st[k] if k == "write_cnt" else jax.lax.psum(st[k], AXIS)
+            for k in EXEC_COUNTERS)
 
-    out_db, cks, wcnt, lanes, rlanes = jax.shard_map(
+    out_db, *counts = jax.shard_map(
         body, mesh=mesh,
         in_specs=(db_spec, P(), P(), P(), P()),
-        out_specs=(db_spec, P(), P(), P(), P()))(db, queries, commit, order,
-                                                 level)
-    stats["read_checksum"] = stats["read_checksum"] + cks
-    stats["write_cnt"] = stats["write_cnt"] + wcnt
-    stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + lanes
-    stats["read_gather_lanes"] = stats["read_gather_lanes"] + rlanes
+        out_specs=(db_spec,) + (P(),) * len(EXEC_COUNTERS))(
+            db, queries, commit, order, level)
+    for k, v in zip(EXEC_COUNTERS, counts):
+        stats[k] = stats[k] + v
     return out_db

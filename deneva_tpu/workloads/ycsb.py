@@ -153,7 +153,7 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     value (`stats["write_scatter_lanes"]`).
 
     The two halves carry the epoch's `ep.read` / `ep.write` scopes
-    (metadata: `runtime/server._make_epoch_body`)."""
+    (metadata: `engine/epoch.make_epoch_body`)."""
     with jax.named_scope("ep.read"):
         if f0.ndim == 2:
             nbytes = f0.shape[1]
@@ -467,7 +467,7 @@ class YCSBWorkload:
         one-chip table, which no one chip could hold at the served
         size); each local block's last row is its trash.
 
-        Scopes (metadata only, as `runtime/server._make_epoch_body`'s):
+        Scopes (metadata only, as `engine/epoch.make_epoch_body`'s):
         everything the mesh ADDS to an epoch — the slice cuts, the defer
         pass, the owner sort, the block cuts, the three ``all_to_all``s,
         the ``all_gather`` of the defer bits and the three ``psum``s —
@@ -670,7 +670,7 @@ class YCSBWorkload:
             return db
         full = self.cfg.sim_full_row
         # the epoch's `ep.read` / `ep.write` scopes (metadata:
-        # `runtime/server._make_epoch_body`) live here, where the row
+        # `engine/epoch.make_epoch_body`) live here, where the row
         # gather and the row scatter are
         with jax.named_scope("ep.read"):
             slots = self._local_slots(db, q.keys)                  # [n, R]

@@ -521,7 +521,7 @@ def test_mutation_caught_and_clean_run_certifies(tmp_path):
     least one seed of the sweep must be rejected."""
     from deneva_tpu.cc import get_backend
     from deneva_tpu.engine.step import init_device_stats
-    from deneva_tpu.runtime.server import make_dist_step
+    from deneva_tpu.engine.epoch import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     def run(mutate, d, seed):

@@ -61,7 +61,7 @@ def _group_program(cfg: Config, monkeypatch):
     builds them, state and feed described instead of allocated."""
     from deneva_tpu.cc import get_backend
     from deneva_tpu.engine.step import init_device_stats
-    from deneva_tpu.runtime.server import make_dist_group
+    from deneva_tpu.engine.epoch import make_dist_group
     from deneva_tpu.workloads import get_workload
 
     wl, be = get_workload(cfg), get_backend(cfg.cc_alg)

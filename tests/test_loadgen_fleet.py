@@ -138,12 +138,22 @@ def test_fleet_worker_processes_match_inline_oracle():
     cfg = _fleet_cfg(loadgen_procs=2, tenant_cnt=4)
     fl = LoadFleet(cfg, node_id=1, ring=TAG_RING, chunk=256)
     fl.go()
+    # What the comparison needs is the first `need` blocks of EACH lane,
+    # however far apart the two spawned processes come up: on a loaded
+    # box one lane streams for seconds before the other has imported
+    # numpy, so a count over both lanes says nothing about the second.
+    # Later blocks of a lane that has its share are drained and dropped
+    # (the queue's backpressure must not park the lane that is ahead).
+    need = 8
     got = {0: [], 1: []}
     ten = {0: [], 1: []}
-    total = 0
     t0 = _time.monotonic()
     try:
-        while total < 2048 and _time.monotonic() - t0 < 60:
+        while min(len(got[0]), len(got[1])) < need:
+            # a guard against a lane that died, not a pace: a worker
+            # gives up on its own go signal after 300 s
+            assert _time.monotonic() - t0 < 280, \
+                f"blocks after 280 s: {len(got[0])} | {len(got[1])}"
             b = fl.take(256)
             if b is None:
                 _time.sleep(0.005)
@@ -152,13 +162,11 @@ def test_fleet_worker_processes_match_inline_oracle():
             g = int(fleet_gen_of(TAG_RING, tags[:1])[0])
             assert (fleet_gen_of(TAG_RING, tags) == g).all(), \
                 "a streamed block never mixes lanes"
-            got[g].append(tags)
-            ten[g].append(tc)
-            total += len(tags)
+            if len(got[g]) < need:
+                got[g].append(tags)
+                ten[g].append(tc)
     finally:
         fl.close()
-    assert total >= 2048
-    assert got[0] and got[1], "both lanes must produce"
     for g in (0, 1):
         ref = FleetGen(cfg, 1, g, TAG_RING)
         n = sum(map(len, got[g]))

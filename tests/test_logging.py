@@ -45,7 +45,7 @@ def test_recovery_invariant_replay_equals_straight_run(tmp_path):
     from deneva_tpu.runtime import wire
     from deneva_tpu.runtime.logger import (EpochLogger, replay_into,
                                            replay_log, state_digest)
-    from deneva_tpu.runtime.server import make_dist_step
+    from deneva_tpu.engine.epoch import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     cfg = Config(

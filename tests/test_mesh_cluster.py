@@ -148,7 +148,7 @@ def _replay_digest(run: dict) -> str:
     from deneva_tpu.parallel.mesh import (make_mesh, state_shardings,
                                           use_mesh)
     from deneva_tpu.runtime.logger import replay_into, state_digest
-    from deneva_tpu.runtime.server import make_dist_step
+    from deneva_tpu.engine.epoch import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     cfg = run["cfg"]

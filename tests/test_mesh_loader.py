@@ -83,7 +83,7 @@ def _group_hlo(cfg) -> str:
     builds it, on the CPU devices (metadata is the same on any backend)."""
     from deneva_tpu.cc import get_backend
     from deneva_tpu.engine.step import init_device_stats
-    from deneva_tpu.runtime.server import make_dist_group
+    from deneva_tpu.engine.epoch import make_dist_group
     wl, be = get_workload(cfg), get_backend(cfg.cc_alg)
     k, _t, s = wl.to_wire(wl.generate(jax.random.PRNGKey(0), 1))
     width, n_scal = k.shape[1], s.shape[1]

@@ -350,7 +350,7 @@ def test_maat_vote_steps_single_node_equals_merged():
     import jax.numpy as jnp
     from deneva_tpu.cc import AccessBatch, build_conflict_incidence, \
         get_backend
-    from deneva_tpu.runtime.server import make_vote_steps
+    from deneva_tpu.engine.epoch import make_vote_steps
     from deneva_tpu.workloads import get_workload
 
     cfg = small_cfg(node_cnt=1, cc_alg=CCAlg.MAAT, dist_protocol="vote",
@@ -389,7 +389,7 @@ def test_maat_vote_detects_cross_node_write_skew():
     both nodes' edges — one txn's range closes (maat.cpp:176-190)."""
     import jax.numpy as jnp
     from deneva_tpu.cc import get_backend
-    from deneva_tpu.runtime.server import make_vote_steps
+    from deneva_tpu.engine.epoch import make_vote_steps
     from deneva_tpu.workloads import get_workload
     from deneva_tpu.workloads.ycsb import YCSBQuery
 
