@@ -48,20 +48,8 @@ from dataclasses import dataclass
 
 import jax
 
-from deneva_tpu.ops import access_incidence, bucket_hash, combine_key
-
-
-def get_overlap(cfg):
-    """Per-config overlap op.  A hand-written Pallas epilogue-fusion
-    kernel lived behind this dispatch in rounds 3-4; round-5 measured it
-    0.58-0.96x the XLA path at every sweep operating point (B in
-    {512,1024,2048} x K=8192, dual hash on/off, v5e — XLA already keeps
-    the compare+AND epilogue fused) and deleted it (BASELINE.md round-5
-    notes; kernel retrievable from git history at tag-of-commit 6fba114).
-    The dispatch point stays so a future winning kernel has one seam."""
-    from deneva_tpu.ops import overlap
-
-    return overlap
+from deneva_tpu.ops import (access_incidence, bucket_hash, combine_key,
+                            key_overlap)
 
 
 @dataclass
@@ -120,44 +108,54 @@ jax.tree_util.register_dataclass(
 
 @dataclass
 class Incidence:
-    """Bucket-space incidence matrices of one epoch, both hash families.
+    """One epoch's accesses as the conflict readers want them.
 
-    ``r/w/u/pr`` are bfloat16[B, K] (reads / writes / union / pure reads —
-    accesses that read without writing; RMW-read incidence is ``r - pr``);
-    family-2 copies are None unless ``Config.conflict_exact`` dual hashing
-    is on.
+    In ACCESS space, for the pairwise conflict matrices (`overlap`): the
+    combined identity of every padded slot, the read and write slots of
+    the active txns, and the slots that need ordering.  In BUCKET space,
+    bfloat16[B, K] counts for the readers that want per-bucket column
+    sums and never pairs (`committed_write_frontier`, `conflict_density`,
+    `audit_mutate_verdict`, `cc.router.cross_group_defer`): the write
+    and the union incidence of hash family 0, and the write incidence of
+    family 1 where ``Config.conflict_exact`` asks for it (None
+    otherwise).  XLA drops the scatter-add of an incidence nobody reads,
+    so a program with those gates off builds none of them.
     """
 
-    r1: jax.Array
+    ident: jax.Array       # uint32[B, A]
+    rmask: jax.Array       # bool[B, A]
+    wmask: jax.Array       # bool[B, A]
+    # accesses NOT marked order_free (None: all of them).  The backends
+    # that honor the escrow exemption draw their edges from the ORDERED
+    # views — overlap("uo", "w"): a pair conflicts iff it overlaps AND
+    # at least one side needs ordering, so escrow add-add pairs carry no
+    # edge while reads of the same accumulators still order against
+    # every write; T/O reader-wait edges from ("ro", "w"), the relaxed-
+    # isolation WW lock edges from ("wo", "w"), READ_COMMITTED's
+    # residual read locks from ("pro", "w").
+    ordered: jax.Array | None
     w1: jax.Array
     u1: jax.Array
-    pr1: jax.Array
-    r2: jax.Array | None
     w2: jax.Array | None
-    u2: jax.Array | None
-    pr2: jax.Array | None
     # per-access bucket ids in family 0 (for ts-table gathers/scatters)
     bucket1: jax.Array     # int32[B, A]
-    # ordered-union incidence: accesses NOT marked order_free.  The
-    # backends that honor the escrow exemption draw conflict edges from
-    # overlap(uo, w) — a pair conflicts iff it overlaps AND at least one
-    # side needs ordering — so escrow add-add pairs carry no edge while
-    # reads of the same accumulators still order against every write.
-    # Equals u1/u2 when no exemption applies.
-    uo1: jax.Array | None = None
-    uo2: jax.Array | None = None
-    # ordered read / write / pure-read incidence (r/w/pr minus the
-    # order_free accesses): the sweep backends' escrow-aware edge inputs
-    # — T/O reader-wait edges come from overlap(ro, w), the relaxed-
-    # isolation WW lock edges from overlap(wo, w), READ_COMMITTED's
-    # residual read locks from overlap(pro, w).  ALIASES of r/w/pr when
-    # no exemption applies (zero extra memory or matmuls).
-    ro1: jax.Array | None = None
-    ro2: jax.Array | None = None
-    wo1: jax.Array | None = None
-    wo2: jax.Array | None = None
-    pro1: jax.Array | None = None
-    pro2: jax.Array | None = None
+
+    def mask(self, view: str) -> jax.Array:
+        """bool[B, A]: the access slots of a named view — ``r`` reads,
+        ``w`` writes, ``u`` their union, ``pr`` pure reads (RMW reads
+        are ``r & ~pr``); with a trailing ``o`` the ordered ones."""
+        r, w = self.rmask, self.wmask
+        m = {"r": r, "w": w, "u": r | w, "pr": r & ~w}[view.removesuffix("o")]
+        if view.endswith("o") and self.ordered is not None:
+            m = m & self.ordered
+        return m
+
+    def overlap(self, a: str, b: str) -> jax.Array:
+        """bool[B, B]: txn i's ``a`` view holds a key of txn j's ``b``
+        view — the one place every sweep backend draws its conflict
+        matrix from, a pairwise compare of the exact combined keys
+        (`ops.conflict.key_overlap`)."""
+        return key_overlap(self.ident, self.mask(a), self.mask(b))
 
 
 def gate_order_free(cfg, be, order_free: jax.Array | None
@@ -587,33 +585,22 @@ def audit_mutate_verdict(cfg, batch: AccessBatch, inc: Incidence,
 def build_incidence(batch: AccessBatch, n_buckets: int, exact: bool,
                     order_free: jax.Array | None = None) -> Incidence:
     # `shard_buckets` is a no-op single-device; under a parallel.use_mesh
-    # context it shards the bucket dim so the conflict matmul contracts
+    # context it shards the bucket dim so the readers' matvecs contract
     # over partitions and XLA inserts the cross-device reduction.
     from deneva_tpu.parallel.mesh import shard_buckets
     ident = combine_key(batch.table_ids, batch.keys)
     v = batch.valid & batch.active[:, None]
     rmask = v & batch.is_read
     wmask = v & batch.is_write
-    prmask = rmask & ~wmask
     b1 = bucket_hash(ident, n_buckets, family=0)
 
-    def family(b):
-        inc = lambda m: shard_buckets(access_incidence(b, m, n_buckets))  # noqa: E731
-        r, w = inc(rmask), inc(wmask)
-        u, pr = inc(rmask | wmask), inc(prmask)
-        if order_free is None:
-            # aliases: escrow off (or nothing declared) costs nothing and
-            # the ordered views are bitwise the plain ones
-            return r, w, u, pr, u, r, w, pr
-        of = ~order_free
-        return (r, w, u, pr, inc((rmask | wmask) & of), inc(rmask & of),
-                inc(wmask & of), inc(prmask & of))
+    def inc(b, m):
+        return shard_buckets(access_incidence(b, m, n_buckets))
 
-    r1, w1, u1, pr1, uo1, ro1, wo1, pro1 = family(b1)
-    r2 = w2 = u2 = pr2 = uo2 = ro2 = wo2 = pro2 = None
-    if exact:
-        b2 = bucket_hash(ident, n_buckets, family=1)
-        r2, w2, u2, pr2, uo2, ro2, wo2, pro2 = family(b2)
-    return Incidence(r1=r1, w1=w1, u1=u1, pr1=pr1, r2=r2, w2=w2, u2=u2,
-                     pr2=pr2, bucket1=b1, uo1=uo1, uo2=uo2, ro1=ro1,
-                     ro2=ro2, wo1=wo1, wo2=wo2, pro1=pro1, pro2=pro2)
+    return Incidence(
+        ident=ident, rmask=rmask, wmask=wmask,
+        ordered=None if order_free is None else ~order_free,
+        w1=inc(b1, wmask), u1=inc(b1, rmask | wmask),
+        w2=(inc(bucket_hash(ident, n_buckets, family=1), wmask)
+            if exact else None),
+        bucket1=b1)

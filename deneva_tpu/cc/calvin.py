@@ -46,19 +46,16 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from deneva_tpu.cc.base import AccessBatch, Incidence, Verdict, get_overlap
+from deneva_tpu.cc.base import AccessBatch, Incidence, Verdict
 from deneva_tpu.ops import earlier_edges, wavefront_levels
 
 
 def validate_calvin(cfg, state, batch: AccessBatch, inc: Incidence):
-    ov = get_overlap(cfg)
     # conflict iff the pair overlaps AND at least one side is an
     # ORDERED access: escrow/commutative (order_free) add-add pairs
     # carry no edge, while reads of the same accumulators still order
     # against every write (uo == u when nothing is exempt)
-    uo1 = inc.u1 if inc.uo1 is None else inc.uo1
-    uo2 = inc.u2 if inc.uo2 is None else inc.uo2
-    uw = ov(uo1, inc.w1, uo2, inc.w2)
+    uw = inc.overlap("uo", "w")
     c = uw | uw.T
     e = earlier_edges(c, batch.rank, batch.active)
     lv, overflow = wavefront_levels(e, max_level=cfg.exec_subrounds - 1)

@@ -75,7 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from deneva_tpu.cc.base import (AccessBatch, Incidence, Verdict,
-                                committed_write_frontier, get_overlap)
+                                committed_write_frontier)
 from deneva_tpu.ops import (earlier_edges, greedy_first_fit,
                             precedence_levels)
 
@@ -97,10 +97,7 @@ def must_precede(cfg, inc: Incidence, b: int):
     clique that used to close m*(m-1)/2 ranges per epoch vanishes,
     while an ordered read of the accumulator still precedes every
     uncommitted delta writer exactly as before."""
-    ov = get_overlap(cfg)
-    ro1 = inc.r1 if inc.ro1 is None else inc.ro1
-    ro2 = inc.r2 if inc.ro1 is None else inc.ro2
-    p = ov(ro1, inc.w1, ro2, inc.w2)
+    p = inc.overlap("ro", "w")
     return p & ~jnp.eye(b, dtype=bool)
 
 

@@ -17,8 +17,10 @@ serial-equivalence test with j's writes "after" i's snapshot reads.  That
 is the lex-first MIS sweep over the *directed* U-vs-W overlap.
 
 Like the reference's central validation, the whole epoch validates in one
-place — except "one place" is the MXU, and the critical section is a
-matmul instead of a semaphore.
+place — except "one place" is the chip, and the critical section is one
+[B, B] conflict matrix instead of a semaphore: a pairwise compare of the
+epoch's exact keys (`cc.base.Incidence.overlap`), then the sweep's matvec
+fixpoint on the MXU.
 
 Escrow (``order_free``) exemption, gated by ``escrow_order_free`` AND
 ``escrow_sweep``: a txn's escrow accesses leave its validated set —
@@ -36,7 +38,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from deneva_tpu.cc.base import (AccessBatch, Incidence, Verdict,
-                                committed_write_frontier, get_overlap)
+                                committed_write_frontier)
 from deneva_tpu.ops import earlier_edges, greedy_first_fit
 
 
@@ -62,10 +64,7 @@ def validate_occ(cfg, state, batch: AccessBatch, inc: Incidence):
     # invalidate me; my escrow deltas commute with their writes' deltas
     # and an ordered write of theirs on the same key appears in their uo
     # for the mirrored pair, which earlier_edges then directs)
-    ov = get_overlap(cfg)
-    uo1 = inc.u1 if inc.uo1 is None else inc.uo1
-    uo2 = inc.u2 if inc.uo1 is None else inc.uo2
-    uw = ov(uo1, inc.w1, uo2, inc.w2)
+    uw = inc.overlap("uo", "w")
     e = earlier_edges(uw, batch.rank, batch.active)
     win, lose, und = greedy_first_fit(e, batch.active, rounds=cfg.sweep_rounds)
     v = Verdict(commit=win, abort=lose, defer=und,

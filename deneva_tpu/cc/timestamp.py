@@ -104,7 +104,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from deneva_tpu.cc.base import AccessBatch, Incidence, Verdict, get_overlap
+from deneva_tpu.cc.base import AccessBatch, Incidence, Verdict
 from deneva_tpu.ops import (bucket_hash, combine_key, earlier_edges,
                             greedy_first_fit)
 
@@ -262,9 +262,7 @@ def _rw_later_reader_edges(cfg, batch: AccessBatch, inc: Incidence):
     """E[i,j]: ORDERED reader i (by ts) after writer j on a common key
     (ro aliases r when no escrow exemption applies: declared-immutable
     column reads never wait behind the row's delta writers)."""
-    ro1 = inc.r1 if inc.ro1 is None else inc.ro1
-    ro2 = inc.r2 if inc.ro1 is None else inc.ro2
-    rw = get_overlap(cfg)(ro1, inc.w1, ro2, inc.w2)    # i reads ∩ j writes
+    rw = inc.overlap("ro", "w")    # i reads ∩ j writes
     return earlier_edges(rw, batch.ts, batch.active)   # j earlier by ts
 
 

@@ -60,7 +60,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from deneva_tpu.cc.base import (AccessBatch, Incidence, Verdict,
-                                committed_write_frontier, get_overlap)
+                                committed_write_frontier)
 from deneva_tpu.cc.nocc import validate_nocc
 from deneva_tpu.ops import earlier_edges, greedy_first_fit
 
@@ -90,28 +90,21 @@ def _lock_edges(cfg, batch: AccessBatch, inc: Incidence):
     Ordered incidence views (uo/wo/pro — alias u/w/pr when no escrow
     exemption applies) keep escrow add-add pairs edge-free."""
     iso = cfg.isolation_level
-    ov = get_overlap(cfg)
+    ov = inc.overlap
     if iso == "NOLOCK":
         return None
-    uo1 = inc.u1 if inc.uo1 is None else inc.uo1
-    uo2 = inc.u2 if inc.uo1 is None else inc.uo2
     if iso == "SERIALIZABLE":
         # symmetrized ordered-vs-write overlap: a pair conflicts iff at
         # least one side's ORDERED access meets the other's write
-        uw = ov(uo1, inc.w1, uo2, inc.w2)
+        uw = ov("uo", "w")
         return earlier_edges(uw | uw.T, batch.rank, batch.active)
-    wo1 = inc.w1 if inc.wo1 is None else inc.wo1
-    wo2 = inc.w2 if inc.wo1 is None else inc.wo2
-    ww = ov(wo1, inc.w1, wo2, inc.w2)
+    ww = ov("wo", "w")
     e = earlier_edges(ww | ww.T, batch.rank, batch.active)
     if iso == "READ_COMMITTED":
         # i's ordered pure read contends with an earlier writer j of the
         # same key; the reverse direction (writer behind reader) is gone —
         # the read lock is already released by the time the writer asks.
-        pro1 = inc.pr1 if inc.pro1 is None else inc.pro1
-        pro2 = inc.pr2 if inc.pro1 is None else inc.pro2
-        prw = ov(pro1, inc.w1, pro2, inc.w2)
-        e = e | earlier_edges(prw, batch.rank, batch.active)
+        e = e | earlier_edges(ov("pro", "w"), batch.rank, batch.active)
     return e
 
 

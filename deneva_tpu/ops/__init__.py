@@ -19,7 +19,7 @@ from deneva_tpu.ops.forward import (ForwardPlan,  # noqa: F401
 from deneva_tpu.ops.gather import checksum_needed_rows  # noqa: F401
 from deneva_tpu.ops.conflict import (  # noqa: F401
     access_incidence,
-    overlap,
+    key_overlap,
     earlier_edges,
     greedy_first_fit,
     wavefront_levels,

@@ -6,14 +6,23 @@ per-algorithm manager with latched owner/waiter lists
 `row_t::get_row` (`storage/row.cpp:197-310`).  The TPU-native replacement
 detects *all* conflicts of an epoch at once:
 
-1. Each transaction's padded RW-set is hashed into a bucket space of width
-   K (`deneva_tpu.ops.hashing`) and expanded into incidence matrices
-   ``R, W ∈ {0,1,...}^{B×K}`` (`access_incidence`).
-2. Pairwise overlap is one batched matmul on the MXU:
-   ``(A @ B.T) > 0`` says which transaction pairs touch a common bucket
-   (`overlap`).  Read-write / write-write decompositions are just different
-   choices of A and B.  With dual hashing, two independent bucket spaces
-   are ANDed so false conflicts need a double collision.
+1. Pairwise overlap — "does txn i's A-set meet txn j's B-set", a boolean
+   [B, B] matrix; read-write / write-write decompositions are just
+   different choices of A and B — is a compare of the exact keys
+   (`key_overlap`): the combined identities of the two padded RW-sets
+   compared pair by pair on the VPU, A x A compares ORed into the
+   [B, B] matrix in one fusion.  No false conflict, no memory beside the
+   result.  The CC backends reach it through `cc.base.Incidence.overlap`.
+2. The readers that want per-bucket column sums and never pairs
+   (`cc.base.committed_write_frontier`, `conflict_density`, the router's
+   cross-group defers) hash each RW-set into a bucket space of width K
+   (`deneva_tpu.ops.hashing`) and scatter it into incidence matrices
+   ``W, U ∈ {0,1,...}^{B×K}`` (`access_incidence`).  The pairwise
+   overlap is NOT drawn from these: ``(A @ B.T) > 0`` on the MXU with
+   two hash families ANDed is linear in A where the compare is
+   quadratic, but slower than it up to A ~55 at B 1024, K 8192 (PERF.md
+   section 6, PR 32); the cells plan 10 accesses a txn, the default
+   `max_accesses` is 16, TPC-C's NewOrder needs 18.
 3. A *serialization sweep* turns the boolean conflict matrix plus a
    priority order into per-transaction verdicts:
 
@@ -42,6 +51,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def access_incidence(bucket_ids: jax.Array, valid: jax.Array,
@@ -60,18 +70,39 @@ def access_incidence(bucket_ids: jax.Array, valid: jax.Array,
     return inc.at[rows, cols].add(vals)
 
 
-def overlap(inc_a: jax.Array, inc_b: jax.Array,
-            inc_a2: jax.Array | None = None,
-            inc_b2: jax.Array | None = None) -> jax.Array:
-    """bool[B, B]: does txn i's A-set share a bucket with txn j's B-set?
+# `key_overlap`'s padding: what a masked-out lane of either side compares
+# as.  Two DISTINCT values, so padding never meets padding; both are
+# `combine_key` images of table 0's keys -1 and -2, which no non-negative
+# key of table 0 maps to (the multiplier is odd, so `combine_key` is a
+# bijection of the key within a table).  In another table some key's
+# identity may equal one of them (one in 2^32): that key then reads as
+# conflicting with padded lanes — an added conflict, never a hidden one.
+# (numpy scalars: a `jnp` constant here would start a backend at import)
+_PAD_A = np.uint32((-2654435761) % (1 << 32))
+_PAD_B = np.uint32((-2 * 2654435761) % (1 << 32))
 
-    One MXU matmul (f32 accumulate); the optional second hash family is
-    ANDed in to suppress false conflicts (Config.conflict_exact).
+
+def key_overlap(ident: jax.Array, mask_a: jax.Array,
+                mask_b: jax.Array) -> jax.Array:
+    """bool[B, B]: does txn i's A-set hold a key of txn j's B-set?
+
+    ident: uint32[B, A] combined identities (`combine_key`) of the padded
+    access slots; mask_a / mask_b: bool[B, A], the slots of each side.
+    One compare and one OR per key pair on the VPU, A x A of them
+    unrolled over a [B, B] accumulator — the chip's compiler makes ONE
+    loop fusion of it with the caller's `earlier_edges` and cast folded
+    in; no arena, no scatter, no matmul.  0.65-0.75 ps a key pair and
+    matrix element on one v5e: 0.069 ms at B 1024 x A 10, 0.175 at A 16,
+    0.81 at A 32, 0.27 at B 2048 x A 10 (my chip run, PR 32).
     """
-    m = jnp.matmul(inc_a, inc_b.T, preferred_element_type=jnp.float32) > 0
-    if inc_a2 is not None:
-        m &= jnp.matmul(inc_a2, inc_b2.T,
-                        preferred_element_type=jnp.float32) > 0
+    ka = jnp.where(mask_a, ident, _PAD_A)
+    kb = jnp.where(mask_b, ident, _PAD_B).T        # [A, B]: rows of lanes
+    b, a = ident.shape
+    m = jnp.zeros((b, b), bool)
+    for i in range(a):
+        col = ka[:, i, None]
+        for j in range(a):
+            m |= col == kb[j]
     return m
 
 

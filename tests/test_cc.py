@@ -152,6 +152,33 @@ def test_occ_blind_ww_conflicts():
     assert c[0] and a[1]
 
 
+def test_occ_verdict_invariants_on_hot_random_epochs():
+    """Disjoint, covering, serializable (`check_verdict`) on epochs of
+    14 txns over eight keys; OCC decides every txn within the epoch."""
+    rng = np.random.default_rng(5)
+    for trial in range(4):
+        txns = [[(int(rng.integers(0, 8)), str(rng.choice(["r", "w", "rw"])))
+                 for _ in range(rng.integers(1, 5))] for _ in range(14)]
+        v, _, b = run("OCC", txns)
+        c, a, d = check_verdict(v, b, txns)
+        assert c.sum() >= 1 and not d.any()
+
+
+@pytest.mark.parametrize("alg", ["OCC", "NO_WAIT", "CALVIN", "MAAT",
+                                 "TIMESTAMP"])
+def test_sweep_backends_conflict_on_keys_not_on_buckets(alg):
+    """ONE bucket in each hash family: every access of the epoch shares
+    it, and still txns with keys of their own do not conflict — the
+    sweep backends' conflict matrix is `Incidence.overlap`'s compare of
+    the exact keys, whatever `conflict_buckets` says."""
+    cfg = CFG.replace(conflict_buckets=1, conflict_exact=True)
+    txns = [[(k, "rw")] for k in range(6)] + [[(0, "r")]]
+    v, _, b = run(alg, txns, cfg=cfg)
+    c, a, d = check_verdict(v, b, txns, chained=alg == "CALVIN")
+    assert c[:6].all()
+    assert c[6] == (alg in ("CALVIN", "MAAT"))
+
+
 # ---- TIMESTAMP ---------------------------------------------------------
 
 def test_to_reader_after_writer_waits():

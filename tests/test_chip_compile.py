@@ -236,8 +236,8 @@ def test_mesh_group_compiles_for_four_chips(topo, monkeypatch):
 CELL_DP4 = "ycsb_fullrow_tpubatch_dp4.hot"
 
 
-def _cell_cfg() -> Config:
-    """The `Config` of the cell's timed launch, through the harness's own
+def _cell_cfg(cell: str = CELL_DP4) -> Config:
+    """The `Config` of a cell's timed launch, through the harness's own
     `load_cell` / `server_fields` (benchmark/run.py never imports JAX)."""
     import importlib.util
     import os
@@ -247,7 +247,7 @@ def _cell_cfg() -> Config:
                                                   path)
     run = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(run)
-    fields = run.server_fields(run.load_cell(CELL_DP4), 3_000_000_019, {})
+    fields = run.server_fields(run.load_cell(cell), 3_000_000_019, {})
     return Config.from_args([f"--{k}={v}" for k, v in fields.items()]
                             ).replace(node_id=0, part_cnt=1)
 
@@ -365,3 +365,44 @@ def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
     assert lanes == 81_920
     assert _row_gathers(hlo, width) == {k * lanes // 16
                                         for k in range(1, 17)}
+
+
+# ---- the OCC cell (PR 32): ycsb_fullrow_occ.medium ------------------------
+
+def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
+                                                            monkeypatch):
+    """The OCC cell's group program on the chip's own HLO: no scatter
+    but the row scatter (the four `access_incidence` scatter-adds of
+    10,240 lanes into `bf16[1024 x 8192]` arenas went, 0.45 ms an epoch
+    at PR 31), no convolution under `ep.validate` (the two bucket
+    matmuls, 0.18 ms) — the U-vs-W conflict matrix is `ops.conflict.
+    key_overlap`'s compare — nothing of an arena's size anywhere in the
+    program, and it still fits the chip."""
+    import re
+    cfg = _cell_cfg("ycsb_fullrow_occ.medium")
+    b, k = cfg.epoch_batch, cfg.conflict_buckets
+    assert (cfg.cc_alg, b, k, cfg.conflict_exact) == ("OCC", 1024, 8192,
+                                                      True)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    state, feed = _with_sharding((state, feed), one_chip)
+    compiled, secs = _compile(group, state["db"], state["cc_state"],
+                              state["stats"], *feed)
+    need = _report("occ_cell_group", compiled, secs)
+    table = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"]))
+    assert table + cfg.pipeline_groups * (need - table) < HBM_BYTES
+    hlo = compiled.as_text()
+    assert f"[{b},{k}]" not in hlo and f"[{b * k}]" not in hlo
+    validate = [ln for ln in hlo.splitlines() if re.search(
+        r'op_name="[^"]*/ep\.validate/', ln)]
+    assert len(validate) > 100
+    assert any(" compare(" in ln for ln in validate)
+    assert not [ln for ln in validate if " convolution(" in ln]
+    # (a scatter inside a fusion keeps no op_name: by what it writes —
+    # the program's only scatters are `ep.write`'s, into the row column)
+    f0 = state["db"]["MAIN_TABLE"].columns["F0"]
+    scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
+    assert scatters and all(
+        f"= u8[{f0.shape[0]},{f0.shape[1]}]" in ln for ln in scatters)
+    # the matrix itself: one [B, B] result of A x A fused compares
+    assert re.search(rf"f32\[{b},{b}\]\S* fusion\(", hlo)

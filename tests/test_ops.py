@@ -7,8 +7,8 @@ import pytest
 
 from deneva_tpu.ops import (
     bucket_hash, combine_key, Zipfian, last_writer,
-    access_incidence, overlap, earlier_edges, greedy_first_fit,
-    wavefront_levels, precedence_levels,
+    access_incidence, earlier_edges, greedy_first_fit,
+    wavefront_levels, precedence_levels, key_overlap,
 )
 
 
@@ -57,6 +57,50 @@ def test_hotset_two_tier_split():
     assert np.bincount(hot, minlength=100).min() > 0  # ...uniform over DATA_PERC keys
 
 
+def _overlap_case(case: str, a: int, b: int = 48, seed: int = 7):
+    """(tables, keys, U mask, W mask) of a random epoch: padded slots,
+    inactive txns, pure reads, blind writes and RMW lanes everywhere;
+    ``case`` adds what its name says."""
+    rng = np.random.default_rng([seed, a, len(case)])
+    keys = rng.integers(0, 60, (b, a))
+    tables = np.zeros((b, a), np.int64)
+    is_w = rng.random((b, a)) < 0.4
+    is_r = ~is_w | (rng.random((b, a)) < 0.3)
+    if case == "rmw":                 # every lane reads AND writes
+        is_r[:], is_w[:] = True, True
+    if case == "repeat":              # one key in every slot of a txn
+        keys[::2] = keys[::2, :1]
+    if case == "tables":              # the same keys in three tables
+        keys = rng.integers(0, 12, (b, a))
+        tables = rng.integers(0, 3, (b, a))
+    valid = rng.random((b, a)) < 0.8
+    active = rng.random(b) < 0.85
+    v = valid & active[:, None]
+    return tables, keys, v & (is_r | is_w), v & is_w
+
+
+@pytest.mark.parametrize("a", [1, 10, 16])
+@pytest.mark.parametrize("case", ["padded", "rmw", "repeat", "tables"])
+def test_key_overlap_is_the_set_intersection(case, a):
+    """`key_overlap` against Python sets of (table, key): txn i's masked
+    U slots meet txn j's masked W slots."""
+    tables, keys, um, wm = _overlap_case(case, a)
+    ident = combine_key(jnp.asarray(tables, jnp.int32),
+                        jnp.asarray(keys, jnp.int32))
+    got = np.asarray(jax.jit(key_overlap)(ident, jnp.asarray(um),
+                                          jnp.asarray(wm)))
+    b = keys.shape[0]
+    sets = lambda m: [{(int(t), int(k)) for t, k, on  # noqa: E731
+                       in zip(tables[i], keys[i], m[i]) if on}
+                      for i in range(b)]
+    us, ws = sets(um), sets(wm)
+    want = np.array([[bool(us[i] & ws[j]) for j in range(b)]
+                     for i in range(b)])
+    assert (got == want).all()
+    assert want.any() and not want.all()
+    assert got.dtype == np.bool_ and got.shape == (b, b)
+
+
 def test_last_writer_oracle():
     rng = np.random.default_rng(0)
     n, cap = 256, 32
@@ -89,18 +133,29 @@ def _bruteforce_conflict(keysets_a, keysets_b):
             c[i, j] = bool(keysets_a[i] & keysets_b[j])
     return c
 
-def test_overlap_exact_with_dual_hash():
+def test_key_overlap_of_a_set_with_itself():
     rng = np.random.default_rng(2)
-    b, a, k = 32, 6, 4096
+    b, a = 32, 6
     keys = rng.integers(0, 500, (b, a)).astype(np.int32)
-    valid = rng.random((b, a)) < 0.9
+    valid = jnp.asarray(rng.random((b, a)) < 0.9)
     ident = combine_key(0, jnp.asarray(keys))
-    inc1 = access_incidence(bucket_hash(ident, k, 0), jnp.asarray(valid), k)
-    inc2 = access_incidence(bucket_hash(ident, k, 1), jnp.asarray(valid), k)
-    got = np.asarray(overlap(inc1, inc1, inc2, inc2))
-    sets = [set(keys[i][valid[i]].tolist()) for i in range(b)]
+    got = np.asarray(key_overlap(ident, valid, valid))
+    sets = [set(keys[i][np.asarray(valid[i])].tolist()) for i in range(b)]
     want = _bruteforce_conflict(sets, sets)
     assert (got == want).all()
+
+
+def test_access_incidence_counts_the_lanes_of_each_bucket():
+    rng = np.random.default_rng(4)
+    b, a, k = 32, 6, 64
+    buckets = rng.integers(0, k, (b, a)).astype(np.int32)
+    valid = rng.random((b, a)) < 0.8
+    got = np.asarray(access_incidence(jnp.asarray(buckets),
+                                      jnp.asarray(valid), k), np.float32)
+    want = np.zeros((b, k), np.float32)
+    for i in range(b):
+        np.add.at(want[i], buckets[i][valid[i]], 1)
+    assert got.shape == (b, k) and (got == want).all()
 
 
 def _greedy_oracle(conflict, rank, active):

@@ -264,16 +264,20 @@ def test_timeline_and_crit_lines_are_what_they_were(served):
 # tree at 3d631a6, the same feed; its own digest d2a3765f... held some
 # losing lane's bytes there): since PR 26 only the final writers reach
 # the row scatter and nothing is steered to the trash row any more —
-# every row below `capacity` is the parent's, bit for bit
+# every row below `capacity` is the parent's, bit for bit.  Since PR 32
+# OCC's conflict matrix comes from the exact keys: the same feed commits
+# the FOUR transactions that only a shared bucket (of 512, one family)
+# had aborted (488 -> 492 of the same 1,392 decisions; with the hashed
+# matmul put back the program gave dc1af2a8... again, bit for bit)
 PARENT = {
     "TPU_BATCH": dict(
         commits=1392, aborts=0, writes=2827, planes=15064,
         digest="5ed0474b85d6e6053fe5bec7509574bb3eac139bde94c3712cd83aa7"
                "c9209fed"),
     "OCC": dict(
-        commits=488, aborts=904, writes=986, planes=15064,
-        digest="dc1af2a82dfd3befd804d9bc1c136647bf68c6780d83947b54265173"
-               "ae22f50f"),
+        commits=492, aborts=900, writes=995, planes=15064,
+        digest="9b7233d6de29f2b518493f4b44439c55432297f5c27484480e266b422d13e2"
+               "c5"),
 }
 SCOPES = {
     "TPU_BATCH": {"ep.decode", "ep.plan", "ep.read", "ep.write", "ep.stats",
