@@ -7,8 +7,7 @@ Its floor is the offset between the two planes' clocks: in the chip
 traces of PR 25 a program starts on the device plane 0.8-2.5 ms BEFORE
 its `DoEnqueueProgram` on the host plane, so the device's stamps are
 that early and every lag reads that much long: a host that retired the
-instant the device finished would still read 0.8-2.5 ms.  The Python
-tracer, on in the traced window, lengthens the host side besides."""
+instant the device finished would still read 0.8-2.5 ms."""
 
 import os
 import sys

@@ -9,7 +9,7 @@ Reads what the program writes into the profiler's own trace and nothing
 else:
 
 * the program's `jax.named_scope`s (`ep.plan`, `ep.validate`, `ep.read`,
-  `ep.write`, ... : `deneva_tpu/runtime/server._make_epoch_body`).  An
+  `ep.write`, ... : `deneva_tpu/engine/epoch.make_epoch_body`).  An
   "XLA Ops" event carries only its HLO instruction's text, so the scope
   of an op comes from the HLO module the profiler stores in the trace's
   `/host:metadata` plane ("Hlo Proto"): {instruction name -> op_name},
@@ -53,7 +53,6 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import statistics
 import subprocess
 import sys
@@ -65,7 +64,6 @@ PHASES = {"ep.plan": "plan", "ep.validate": "validate", "ep.read": "read",
           "ep.write": "write"}
 SCOPE_PREFIXES = ("ep.", "grp.")
 BARRIERS = ("while", "call", "conditional")
-_INSTR = re.compile(r"%?([\w.\-]+)")
 
 
 # ---- protobuf wire format: just enough for XSpace and HloProto ----------
@@ -299,7 +297,7 @@ def reduce(prof, scopes: dict[str, str], epochs_per_group: int) -> dict:
         n_groups += len(mods)
         group_s += sum(b - a for a, b, _ in mods) * 1e-9
         for name, s in tr.self_times(ops).items():
-            ins = _INSTR.match(name).group(1)
+            ins = tr.INSTR.match(name).group(1)
             sc = scopes.get(ins, "unscoped")
             scope_s[sc] = scope_s.get(sc, 0.0) + s
             if sc not in PHASES:

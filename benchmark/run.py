@@ -30,9 +30,10 @@ CPU:
    from the server's closing lines and the reduced trace, each through a
    reader of its own, `benchmark/metrics/<metric name>.py`.
 
-Last stdout line: {"correct", "attempted", "failed", "metrics", "device"
-[, "breakdown"], "checks"}; the checks are stderr's last lines too.  No chip, or fewer than the cell asks for: non-zero
-exit, no result line — there is no option that lets a CPU run pass.
+Last stdout line: {"correct", "attempted", "failed", "metrics", "device",
+"timing" [, "breakdown"], "checks"}; the checks are stderr's last lines
+too.  No chip, or fewer than the cell asks for: non-zero exit, no result
+line — there is no option that lets a CPU run pass.
 """
 
 from __future__ import annotations
@@ -61,7 +62,11 @@ SERVE_PAST_WINDOW_S = 1.0       # the server outlasts the clients' window
 # phase has them (the reference must fit every epoch they hold); the
 # clients' own window closes inside them
 VERIFY_WINDOWS = dict(warmup_secs=0.25, done_secs=0.5, client_window=0.25)
-TRACE_START_S, TRACE_LEN_S = 1.0, 2.5   # after the window opens
+TRACE_START_S = 1.0                     # after the window opens
+# seconds traced, by the cell's chips: what `stop_trace` has to store
+# grows with chips x epochs x ops an epoch (0.11 ms an op event: 140 s
+# for 2.5 s of the four-chip cell, 58 s for 1.0 s = 384 epochs a chip)
+TRACE_LEN_S = {1: 2.5, 4: 1.0}
 
 
 class RunFailed(Exception):
@@ -187,7 +192,7 @@ def launch(what: str, cell: dict, fields: dict, seed: int, seconds: float,
                        recv_threads=cfg.rem_thread_cnt),
         trace=dict(dir=os.path.join(d, "trace"),
                    start_s=warmup + TRACE_START_S,
-                   len_s=TRACE_LEN_S) if trace else None)
+                   len_s=TRACE_LEN_S[cell["chips"]]) if trace else None)
     spec_path = os.path.join(d, "spec.json")
     with open(spec_path, "w") as f:
         json.dump(spec, f)
@@ -409,12 +414,11 @@ def compute_metrics(cell: dict, ctx: dict, traced: bool) -> dict:
     return out
 
 
-def reduce_trace(res: dict) -> dict | None:
+def reduce_trace(res: dict) -> dict:
     """The traced launch's `.xplane.pb`, reduced in a child pinned to the
-    CPU backend (reading it needs JAX's own reader)."""
-    tr = res["server"].get("trace") or {}
-    if "window_s" not in tr:
-        return None
+    CPU backend (reading it needs JAX's own reader).  The server child
+    prints `[trace]` with its window or fails the launch by name."""
+    tr = res["server"]["trace"]
     out = run_child("trace_reduce", [
         sys.executable, os.path.join(HERE, "trace_reduce.py"),
         os.path.join(res["dir"], "trace"), str(tr["window_s"]),
@@ -465,10 +469,15 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
                       attempted=int(sent),
                       failed=int(max(0, sent - acked - cap)),
                       metrics=metrics, device=device)
+        # how near the run is to its limits (360 s a run; the children
+        # `CHILD_TIMEOUT_S`): the check, the timed launch, and what
+        # `stop_trace` took past the serve loop
+        result["timing"] = dict(check_s=check_s, timed_wall_s=res["wall_s"])
         if reduced is not None:
             device["busy_s"] = reduced["busy_s"]
             device["window_s"] = reduced["window_s"]
             result["breakdown"] = reduced["breakdown"]
+            result["timing"]["stop_cost_s"] = srv["trace"]["stop_cost_s"]
         # every number compared beside its limit: the result's last key
         result["checks"] = {n: {"value": v, "limit": lim}
                             for n, v, lim in checks}

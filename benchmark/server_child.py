@@ -14,6 +14,14 @@ chip-holding process can take and the launcher does not print:
   measured window: a timer thread calls `jax.profiler.start_trace` /
   `stop_trace` (there is no profiler hook inside the program).  The
   thread takes the start barrier's time from the file client 0 writes.
+  The Python tracer is off: no metric reads its events, and while it
+  ran a host-bound server fed its device half as often; the program's
+  `srv.<stage>` spans are `TraceAnnotation`s, which the HOST tracer
+  records.  `stop_trace` has the rest of the run's own limit
+  (``spec["setup_wait_s"]``, the parent's kill time), not a budget of
+  its own: it takes ~0.11 ms a device op event, so a faster program
+  needs longer, and a traced run that cannot return its window fails
+  by name ("stop_trace: no end after N s"), never silently.
 
 Prints the launcher CLI's own closing lines — ``[device] node=0 {json}``
 and ``node 0 (server): [summary] ...`` — plus ``[memory] {json}`` and,
@@ -29,6 +37,9 @@ import threading
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the child gives up this long before the parent would kill it, so that
+# the failure it reports is its own, named one
+STOP_TRACE_MARGIN_S = 10.0
 
 
 def trace_window(tr: dict, barrier_file: str, out: dict,
@@ -43,7 +54,9 @@ def trace_window(tr: dict, barrier_file: str, out: dict,
         t0 = int(f.read()) * 1e-9           # CLOCK_MONOTONIC, shared
     if stop.wait(max(0.0, t0 + tr["start_s"] - time.monotonic())):
         return
-    jax.profiler.start_trace(tr["dir"])
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(tr["dir"], profiler_options=opts)
     t_a = time.monotonic()
     stop.wait(tr["len_s"])
     t_b = time.monotonic()
@@ -52,7 +65,22 @@ def trace_window(tr: dict, barrier_file: str, out: dict,
                 stop_cost_s=time.monotonic() - t_b)
 
 
+def join_trace(th: threading.Thread, traced: dict, limit_s: float) -> None:
+    """Wait for the trace thread for what is left of the run's own
+    limit; a thread that has not handed back its window by then fails
+    the run by name."""
+    limit_s = max(0.0, limit_s)
+    th.join(timeout=limit_s)
+    if th.is_alive():
+        raise RuntimeError(f"stop_trace: no end after {limit_s:.0f} s past "
+                           "the serve loop")
+    if "window_s" not in traced:
+        raise RuntimeError("the serve loop ended before the traced window "
+                           "opened: nothing was traced")
+
+
 def main(argv: list[str]) -> int:
+    t_start = time.monotonic()
     with open(argv[0]) as f:
         spec = json.load(f)
     sys.path.insert(0, ROOT)
@@ -79,7 +107,8 @@ def main(argv: list[str]) -> int:
         st = node.run()
         stop.set()
         if th is not None:
-            th.join(timeout=120)
+            join_trace(th, traced, t_start + spec["setup_wait_s"]
+                       - STOP_TRACE_MARGIN_S - time.monotonic())
         import jax
         peak = in_use = limit = None
         for d in jax.local_devices():

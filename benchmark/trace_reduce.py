@@ -27,9 +27,16 @@ Prints one JSON object on the last line:
   left out; ``group_busy_s`` is the device time of those whole
   executions and ``epochs`` = groups x the epochs one group scans (the
   configuration's `pipeline_epochs`);
-* ``device_ops``: SELF time per op name (an op that encloses others, a
+* ``device_ops``: SELF time per op (an op that encloses others, a
   `while` around its body, is charged only what its children leave), the
-  ten largest; ``idle_gaps``: the ten longest gaps between program
+  ten largest, each keyed ``<innermost scope>:<HLO op> <result shape>``
+  (`op_key`: ``ep.write:fusion u8[6291520,100]``) and not by the
+  compiler's instruction number, which every change to the program
+  renumbers — two PRs' lines name one op alike, and ops that differ
+  only in number or layout (the table's two relayout copies) are one
+  entry; the scopes are `phase_reduce.hlo_scopes`'s, read from the HLO
+  the profiler stores in the trace;
+* ``idle_gaps``: the ten longest gaps between program
   executions ("XLA Modules"; between ops where that line is missing),
   each named by the runtime's host-thread event that covers most of it
   (the Python tracer's ``$...`` events belong to the thread that holds
@@ -41,11 +48,14 @@ from __future__ import annotations
 import glob
 import json
 import os
+import re
 import sys
 
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 DEVICE_PREFIX = "/device:TPU:"
 GROUP_MARK = "group"
+INSTR = re.compile(r"%?([\w.\-]+)")        # an HLO instruction's name
+_SHAPE = re.compile(r" = (\(?)([a-z]+\d*\[[\d,]*\])")
 
 
 def find_xplane(path: str) -> str:
@@ -107,6 +117,19 @@ def self_times(ev) -> dict[str, float]:
     return out
 
 
+def op_key(name: str, scopes: dict[str, str]) -> str:
+    """``<scope>:<op> <result shape>`` of an "XLA Ops" event, whose name
+    is its HLO instruction's text (``%fusion.62 = u8[6291520,100]{1,0:T(8,
+    128)(4,1)} fusion(...)``): the instruction's name up to its first
+    dot, the first array of its result without the layout (``(f32[64],..)``
+    for a tuple), under the scope ``scopes`` gives the instruction."""
+    ins = INSTR.match(name).group(1)
+    m = _SHAPE.search(name)
+    shape = "" if m is None else \
+        " " + (m.group(2) if not m.group(1) else f"({m.group(2)},..)")
+    return f"{scopes.get(ins, 'unscoped')}:{ins.split('.')[0]}{shape}"
+
+
 def name_gaps(gaps, host_ev, top: int = 10) -> list[list]:
     """The longest gaps, each with the host event covering most of it."""
     out = []
@@ -122,7 +145,9 @@ def name_gaps(gaps, host_ev, top: int = 10) -> list[list]:
     return out
 
 
-def reduce(prof, window_s: float, epochs_per_group: int) -> dict:
+def reduce(prof, window_s: float, epochs_per_group: int,
+           scopes: dict[str, str] | None = None) -> dict:
+    scopes = scopes or {}
     devs = [p for p in prof.planes if p.name.startswith(DEVICE_PREFIX)]
     if not devs:
         raise ValueError("the trace holds no device plane "
@@ -140,7 +165,8 @@ def reduce(prof, window_s: float, epochs_per_group: int) -> dict:
             extent_s = max(extent_s, (max(e[1] for e in ev) - ev[0][0])
                            * 1e-9)
         for n, s in self_times(ev).items():
-            ops[n] = ops.get(n, 0.0) + s
+            k = op_key(n, scopes)
+            ops[k] = ops.get(k, 0.0) + s
         mods = events_of(lines[MODULES_LINE]) if MODULES_LINE in lines \
             else []
         gaps_all += union_s(mods)[1] if mods else gaps
@@ -188,8 +214,13 @@ def main(argv: list[str]) -> int:
     if argv[0] == "--describe":
         describe(load(argv[1]))
         return 0
-    out = reduce(load(argv[0]), float(argv[1]),
-                 int(argv[2]) if len(argv) > 2 else 1)
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from phase_reduce import hlo_scopes     # it imports this module
+    path = find_xplane(argv[0])
+    with open(path, "rb") as f:
+        scopes = hlo_scopes(f.read())
+    out = reduce(load(path), float(argv[1]),
+                 int(argv[2]) if len(argv) > 2 else 1, scopes)
     print(json.dumps(out), flush=True)
     return 0
 

@@ -34,9 +34,9 @@ def main(argv: list[str]) -> int:
 
     from deneva_tpu.cc import get_backend
     from deneva_tpu.config import Config
+    from deneva_tpu.engine.epoch import make_dist_step
     from deneva_tpu.engine.step import init_device_stats
     from deneva_tpu.runtime.logger import replay_into
-    from deneva_tpu.runtime.server import make_dist_step
     from deneva_tpu.workloads import get_workload
 
     cfg = Config.from_args([f"--{k}={v}" for k, v in spec["fields"].items()]

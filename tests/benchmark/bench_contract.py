@@ -20,9 +20,16 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 YCSB_ROW = dict(tup_size=100, field_per_tuple=10, req_per_query=10,
                 sim_full_row="true")
 ACCEPTED_SHAPES = {"ycsb-fullrow-tpubatch": YCSB_ROW,
-                   "ycsb-fullrow-occ": YCSB_ROW}
+                   "ycsb-fullrow-occ": YCSB_ROW,
+                   "ycsb-fullrow-tpubatch-dp4": YCSB_ROW}
 ACCEPTED_MIX = {"hot": (0.5, 0.5, "closed"), "medium": (0.5, 0.5, "closed")}
-# the per-layer metrics of PRs 24, 25 and 28, in their order
+# the accepted cells: (configuration, traffic, chips)
+HOT, OCC, DP4 = ("ycsb_fullrow_tpubatch.hot", "ycsb_fullrow_occ.medium",
+                 "ycsb_fullrow_tpubatch_dp4.hot")
+ACCEPTED_CELLS = {HOT: ("ycsb-fullrow-tpubatch", "hot", 1),
+                  OCC: ("ycsb-fullrow-occ", "medium", 1),
+                  DP4: ("ycsb-fullrow-tpubatch-dp4", "hot", 4)}
+# the per-layer metrics of PRs 24, 25, 28, 29 and 35, in their order
 ACCEPTED_PER_LAYER = [
     "client.sent_txn_per_s", "transport.bytes_per_txn", "host.idle_share",
     "group.txn_per_epoch", "group.device_ms_per_epoch",
@@ -33,7 +40,29 @@ ACCEPTED_PER_LAYER = [
     "group.verdict_lag_ms", "phase.plan_ms_per_epoch",
     "phase.read_ms_per_epoch", "phase.write_ms_per_epoch",
     "phase.other_ms_per_epoch", "phase.validate_ms_per_epoch",
-    "exec.write_lanes_per_epoch"]
+    "exec.write_lanes_per_epoch",
+    "phase.exchange_ms_per_epoch", "mesh.a2a_bytes_per_epoch",
+    "cc.defers_per_txn", "exchange_ici_roofline",
+    "exec.read_lanes_per_epoch"]
+# what an accepted entry is held to beyond its place: the cells it
+# lists (None: it lists none and every cell reports it) and, where a PR
+# pinned them, what it moves and its layer
+KERNELS = "CC and executor kernels"
+_DP4_ONLY = dict(workloads=[DP4], moves="served_txn_per_s", layer=KERNELS)
+ACCEPTED_ENTRIES = {
+    "phase.validate_ms_per_epoch": dict(workloads=[OCC]),
+    "exec.write_lanes_per_epoch": dict(workloads=None),
+    "phase.exchange_ms_per_epoch": _DP4_ONLY,
+    "mesh.a2a_bytes_per_epoch": _DP4_ONLY,
+    "cc.defers_per_txn": _DP4_ONLY,
+    "exchange_ici_roofline": _DP4_ONLY,
+    "exec.read_lanes_per_epoch": dict(
+        workloads=[HOT, DP4], moves="served_txn_per_s", layer=KERNELS,
+        unit="lanes/epoch", better="lower", source="program_counter"),
+}
+# `epoch_group_hbm_roofline` counts YCSB's bytes (`peaks.ycsb_epoch_bytes`
+# of these `fields`): it lists its cells, and each runs such a schema
+ROOFLINE_FIELDS = ("req_per_query", "tup_size")
 
 
 def _line(s):
@@ -85,15 +114,11 @@ def check_benchmark(root):
         assert shapes, c["name"]
         assert {k: f.get(k) for k in shapes} == shapes, c["name"]
         assert not set(shapes) & set(c["reduced"]), c["name"]
-        if c["name"] in ACCEPTED_SHAPES:
-            assert shapes == ACCEPTED_SHAPES[c["name"]]
         assert "aborts" in conf["guarantees"]
         assert os.path.exists(os.path.join(
             bench_dir, "references", conf["reference"] + ".py"))
         assert os.path.exists(os.path.join(
             bench_dir, "generators", f["workload"].lower() + ".py"))
-    for name in ACCEPTED_SHAPES:
-        assert name in cfgs, name
     cells = [w["name"] for w in b["workloads"]]
     assert len(set(cells)) == len(cells) <= 24
     assert len({(w["config"], w["traffic"]) for w in b["workloads"]}) \
@@ -106,15 +131,12 @@ def check_benchmark(root):
         assert w["config"] in cfgs and w["chips"] in (1, 4) and _line(w["why"])
         tr = load_json(bench_dir, "traffic", w["traffic"] + ".json")
         # the mix is the traffic file's; its generator says whether it
-        # can draw it; the accepted files keep the source's (ycsb_skew)
+        # can draw it
         gen = load_module(os.path.join(
             bench_dir, "generators",
             confs[w["config"]]["fields"]["workload"].lower() + ".py"))
         gen.check(tr)
         assert int(tr["clients"]) >= 1 and float(tr["warmup_secs"]) >= 0
-        if w["traffic"] in ACCEPTED_MIX:
-            assert (tr["read_share"], tr["txn_write_share"], tr["arrival"]) \
-                == ACCEPTED_MIX[w["traffic"]]
     assert {c["config"] for c in b["workloads"]} == cfgs
     e2e = {m["name"]: m for m in b["end_to_end"]}
     assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.25
@@ -142,26 +164,54 @@ def check_benchmark(root):
 
 def check_per_layer(root):
     """Order and presence, not equality: the accepted per-layer metrics
-    are a prefix of the list in their order; what a later PR declares
-    comes after them, with a reader, a layer that an accepted entry has
-    or that PERF.md section 3 names, and only cells that exist."""
+    are all there, in their order among themselves; what a later PR
+    declares has a reader, a layer that an accepted entry has or that
+    PERF.md section 3 names, and only cells that exist."""
     bench = load_json(root, "BENCHMARK.json")
-    per_layer = bench["per_layer"]
-    n = len(ACCEPTED_PER_LAYER)
-    assert [m["name"] for m in per_layer[:n]] == ACCEPTED_PER_LAYER
-    by_name = {m["name"]: m for m in per_layer}
-    assert "ycsb_fullrow_occ.medium" in \
-        by_name["phase.validate_ms_per_epoch"]["workloads"]
-    assert "workloads" not in by_name["exec.write_lanes_per_epoch"]
-    layers = {m["layer"] for m in per_layer[:n]}
+    accepted = [m for m in bench["per_layer"]
+                if m["name"] in ACCEPTED_PER_LAYER]
+    assert [m["name"] for m in accepted] == ACCEPTED_PER_LAYER
+    layers = {m["layer"] for m in accepted}
     with open(os.path.join(root, "PERF.md")) as f:
         perf = f.read()
     section3 = perf[perf.index("\n## 3."):perf.index("\n## 4.")]
     cells = {w["name"] for w in bench["workloads"]}
     e2e = {m["name"] for m in bench["end_to_end"]}
-    for m in per_layer[n:]:
+    for m in bench["per_layer"]:
+        if m["name"] in ACCEPTED_PER_LAYER:
+            continue
         assert os.path.exists(os.path.join(root, "benchmark", "metrics",
                                            m["name"] + ".py")), m["name"]
         assert m["layer"] in layers or m["layer"] in section3, m["name"]
         assert m["moves"] in e2e, m["name"]
         assert set(m.get("workloads", ())) <= cells, m["name"]
+
+
+def check_accepted(root):
+    """Every by-name pin of an accepted cell, configuration and
+    per-layer entry, on any tree: the real one, and the pretended later
+    PR's, which must pass it with its own deployment added."""
+    bench = load_json(root, "BENCHMARK.json")
+    cells = {w["name"]: w for w in bench["workloads"]}
+    confs = {c["name"]: load_json(root, c["file"])
+             for c in bench["configs"]}
+    for name, pin in ACCEPTED_CELLS.items():
+        w = cells.get(name, {})
+        assert (w.get("config"), w.get("traffic"), w.get("chips")) == pin, \
+            name
+    for name, shapes in ACCEPTED_SHAPES.items():
+        assert name in confs and confs[name]["shapes"] == shapes, name
+    # the accepted traffic files keep the source's mix (ycsb_skew)
+    for name, mix in ACCEPTED_MIX.items():
+        tr = load_json(root, "benchmark", "traffic", name + ".json")
+        assert (tr["read_share"], tr["txn_write_share"], tr["arrival"]) \
+            == mix, name
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name, pins in ACCEPTED_ENTRIES.items():
+        for key, want in pins.items():
+            assert by_name[name].get(key) == want, (name, key)
+    listed = by_name["epoch_group_hbm_roofline"]["workloads"]
+    assert set(listed) >= {HOT, OCC, DP4}
+    for name in listed:
+        fields = confs[cells[name]["config"]]["fields"]
+        assert all(k in fields for k in ROOFLINE_FIELDS), name

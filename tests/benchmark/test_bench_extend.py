@@ -1,11 +1,16 @@
 """A deployment is new files: a pretended later PR adds, to a copy of
-`BENCHMARK.json` and `benchmark/`, a configuration with another row
-shape, a generator with another message layout, a traffic file with
-another mix, a reference, a cell and two per-layer metrics — new files
-and new entries only — and the contract's body, `load_cell`,
-`server_fields` and `compute_metrics` take them as they are.  The same
-copy with a shape cut, an accepted metric dropped or an accepted mix
-changed is refused."""
+`BENCHMARK.json` and `benchmark/`, a configuration of ANOTHER SCHEMA
+(its `fields` have none of YCSB's `req_per_query`, `tup_size`,
+`field_per_tuple`), a generator with another message layout, a traffic
+file with another mix, a reference, a cell and two per-layer metrics,
+one of them its own `*_roofline` with its own bytes function — new
+files and new entries only — and the contract's three functions,
+`load_cell`, `server_fields` and `compute_metrics` take them as they
+are: the traced metrics of the new cell raise nothing and every metric
+that lists no cells has a value there (a check refuses a new cell in
+which one reads nothing).  The same copy with a shape cut, an accepted
+metric dropped, an accepted mix changed or an accepted entry edited is
+refused."""
 
 import hashlib
 import json
@@ -15,22 +20,23 @@ import shutil
 import numpy as np
 import pytest
 
-from bench_contract import check_benchmark, check_per_layer, load_module
+from bench_contract import (check_accepted, check_benchmark,
+                            check_per_layer, load_module)
 from conftest import BENCH, ROOT
-from test_bench_run import _res
+from test_bench_phases import PHASE
+from test_bench_phases import SUMMARY as STAGE_KEYS
+from test_bench_run import TRACE, _res
 
 CELL = "toy_narrow_calvin.allwrite"
 CONFIG = dict(
     name="toy-narrow-calvin",
-    source="a made-up benchmark, section 2: 64 B rows of 4 fields, 4 requests",
+    source="a made-up benchmark, section 2: 64 B records, 4 keys a transaction",
     deployment="one partition on one chip, 3 clients",
     fields=dict(workload="TOY", cc_alg="CALVIN", node_cnt=1,
-                sim_full_row="true", synth_table_size=1 << 20, tup_size=64,
-                field_per_tuple=4, req_per_query=4, epoch_batch=4096,
-                pipeline_epochs=8, max_txn_in_flight=1 << 16,
-                client_batch_size=512),
-    shapes=dict(tup_size=64, field_per_tuple=4, req_per_query=4,
-                sim_full_row="true"),
+                synth_table_size=1 << 20, toy_record_bytes=64,
+                toy_keys_per_txn=4, epoch_batch=4096, pipeline_epochs=8,
+                max_txn_in_flight=1 << 16, client_batch_size=512),
+    shapes=dict(toy_record_bytes=64, toy_keys_per_txn=4),
     reduced=dict(synth_table_size="16M rows in the source -> 1M"),
     assumed=["epoch_batch: this repo's default"],
     guarantees=dict(isolation="serializable", aborts="none (Calvin)",
@@ -62,7 +68,7 @@ def make_ring(spec, client):
     tr, f = spec["traffic"], spec["fields"]
     rng = np.random.default_rng([int(spec["seed"]), int(client)])
     n, b, w = int(tr["ring_txns"]), int(f["client_batch_size"]), \\
-        int(f["req_per_query"])
+        int(f["toy_keys_per_txn"])
     keys = rng.integers(0, int(f["synth_table_size"]), (n, w), np.int64)
     writes = rng.random((n, w)) < tr["write_share"]
     amount = rng.integers(1, 100, n).astype(np.int16)
@@ -83,31 +89,50 @@ def verify(log, fields, info, verdicts=None):
     return [("digest_mismatch", 0.0, 0.0)], dict(epochs=0)
 '''
 READERS = {
-    "client.keys_per_txn": '''
-"""Requests a transaction carries (the configuration's shape)."""
+    "client.acks_per_client": '''
+"""Transactions acked to a client, whole run, the clients' mean: it reads
+what every cell's clients report, so it lists no cells."""
 
 
 def read(ctx):
-    return float(ctx["fields"]["req_per_query"])
+    cl = ctx["clients"]
+    return sum(c["acked"] for c in cl) / len(cl) if cl else None
 ''',
-    "mesh.hidden_share": '''
-"""Share of the exchange hidden behind compute; nothing without a trace."""
+    "toy_group_hbm_roofline": '''
+"""The toy schema's epoch against the chip's memory roofline, with the
+toy schema's own bytes; nothing without a trace."""
+
+
+def toy_epoch_bytes(committed_txns, keys_per_txn, record_bytes):
+    """Every committed key reads or writes one whole record once."""
+    return committed_txns * keys_per_txn * record_bytes
 
 
 def read(ctx):
-    t = ctx["trace"]
-    return 100.0 * t["hidden_s"] / t["exchange_s"] if t and t.get(
-        "exchange_s") else None
+    t, s, info = ctx["trace"], ctx["server"]["summary"], ctx["server"]["info"]
+    if not t or not t.get("epochs") or not s.get("epoch_cnt"):
+        return None
+    f = ctx["fields"]
+    need = toy_epoch_bytes(info["run_commit_cnt"] / s["epoch_cnt"],
+                           int(f["toy_keys_per_txn"]),
+                           int(f["toy_record_bytes"]))
+    return 100.0 * need / (
+        t["group_busy_s"] / t["epochs"]
+        * ctx["peaks"].peak_for(info["kind"])["hbm_bytes_per_s"])
 ''',
 }
 METRICS = [
-    dict(name="client.keys_per_txn", unit="req/txn", better="lower",
+    dict(name="client.acks_per_client", unit="txn", better="higher",
          source="program_counter", layer="client",
          moves="served_txn_per_s"),
-    dict(name="mesh.hidden_share", unit="%", better="higher",
-         source="device_trace", layer="mesh collectives",
+    dict(name="toy_group_hbm_roofline", unit="%", better="higher",
+         source="device_trace", layer="toy kernels",
          moves="served_txn_per_s", workloads=[CELL]),
 ]
+# what a new deployment's PROGRAM prints in its `[summary]` so that every
+# per-layer metric that lists no cells has a value in its cell (PERF.md
+# section 4): the stage clock's keys and the row scatter's lane counter
+PRINTED = dict(STAGE_KEYS, write_scatter_lane_cnt=150_000_000.0)
 
 
 def _tree_digest(root):
@@ -141,8 +166,9 @@ def _later_pr(tmp_path, break_it):
     before = _tree_digest(root)
     config, entry_reduced = dict(CONFIG), ["synth_table_size"]
     if break_it == "shape_cut":
-        config["reduced"] = dict(CONFIG["reduced"], tup_size="100 -> 64")
-        entry_reduced = ["synth_table_size", "tup_size"]
+        config["reduced"] = dict(CONFIG["reduced"],
+                                 toy_record_bytes="100 -> 64")
+        entry_reduced = ["synth_table_size", "toy_record_bytes"]
     _write(root, "benchmark/configs/toy-narrow-calvin.json", config)
     _write(root, "benchmark/traffic/allwrite.json", TRAFFIC)
     _write(root, "benchmark/generators/toy.py", GENERATOR)
@@ -157,8 +183,12 @@ def _later_pr(tmp_path, break_it):
         reduced=entry_reduced, why="another schema, another wire layout"))
     bench["workloads"].append(dict(
         name=CELL, config=CONFIG["name"], traffic="allwrite", chips=1,
-        why="1M x 64 B rows, closed loop, 3 clients, every request writes"))
+        why="1M x 64 B records, closed loop, 3 clients, every key written"))
     bench["per_layer"] += METRICS
+    if break_it == "accepted_entry_edited":
+        for m in bench["per_layer"]:
+            if m["name"] == "exchange_ici_roofline":
+                m["workloads"] = m["workloads"] + [CELL]
     if break_it == "metric_dropped":
         bench["per_layer"] = [m for m in bench["per_layer"]
                               if m["name"] != "host.idle_share"]
@@ -168,8 +198,8 @@ def _later_pr(tmp_path, break_it):
     with open(os.path.join(root, "PERF.md")) as f:
         perf = f.read()
     with open(os.path.join(root, "PERF.md"), "w") as f:
-        f.write(perf.replace("\n## 4.", "\n| mesh collectives | `ops/` | "
-                             "`mesh.hidden_share` |\n\n## 4.", 1))
+        f.write(perf.replace("\n## 4.", "\n| toy kernels | `workloads/` | "
+                             "`toy_group_hbm_roofline` |\n\n## 4.", 1))
     assert _tree_digest(root).items() >= before.items()     # none edited
     if break_it == "hot_mix_changed":
         path = os.path.join(root, "benchmark", "traffic", "hot.json")
@@ -189,8 +219,10 @@ def _drive(root, tmp_path):
     assert list(fields)[-6:] == ["write_perc", "read_perc", "client_node_cnt",
                                  "device_parts", "seed", "logging"]
     assert (fields["client_node_cnt"], fields["write_perc"],
-            fields["tup_size"], fields["seed"]) == (3, 1.0, 64, 5)
-    assert "txn_write_perc" not in fields       # YCSB's, not every launch's
+            fields["toy_record_bytes"], fields["seed"]) == (3, 1.0, 64, 5)
+    # YCSB's, not every launch's
+    assert not {"txn_write_perc", "req_per_query", "tup_size",
+                "field_per_tuple"} & set(fields)
     gen = run.generator(fields)
     spec = dict(seed=9, fields=fields, traffic=cell["traffic_file"])
     ring = gen.make_ring(spec, 1)
@@ -199,27 +231,42 @@ def _drive(root, tmp_path):
     # the client's own expression (`loadgen.run_client`), 200 of a block
     parts = gen.block_parts(np.arange(200), *(a[:200] for a in ring[3]))
     assert len(parts[0]) == 16 and parts[3].shape == (200, 4)
+    # a traced run's context of the new cell: the clients' reports, the
+    # closing lines of a program that prints `PRINTED`, the reduced
+    # trace, and the phase reduction beside it (`phase_reduce.cached`)
     res = _res(run, tmp_path)
-    res["fields"] = dict(fields, pipeline_epochs=8)
-    trace = dict(busy_s=2.0, window_s=2.5, epochs=960.0, groups=30.0,
-                 group_busy_s=1.92, hidden_s=0.3, exchange_s=0.4,
-                 breakdown={})
-    lay = run.compute_metrics(cell, run.metric_context(cell, res, trace),
+    res["fields"] = dict(fields, pipeline_epochs=8,
+                         log_dir=str(tmp_path / "tlog"))
+    res["server"]["summary"].update(PRINTED)
+    os.makedirs(tmp_path / "timed")
+    with open(tmp_path / "timed" / "phase_reduce.json", "w") as f:
+        json.dump(PHASE, f)
+    lay = run.compute_metrics(cell, run.metric_context(cell, res, TRACE),
                               traced=True)
-    assert lay["client.keys_per_txn"] == {"value": 4.0, "unit": "req/txn"}
-    assert lay["mesh.hidden_share"]["value"] == pytest.approx(75.0)
-    assert "cc.abort_rate" not in lay and "exec.write_lanes_per_epoch" \
-        not in lay                              # nothing to read: left out
-    assert "epoch_group_hbm_roofline" in lay and "host.idle_share" in lay
+    assert lay["client.acks_per_client"] == {"value": 7_500_000.0,
+                                             "unit": "txn"}
+    # 1875 txns x 4 keys x 64 B needed in 2 ms at 819 GB/s: the toy's
+    # own bytes, and YCSB's roofline is not asked (it lists its cells)
+    assert lay["toy_group_hbm_roofline"]["value"] == pytest.approx(
+        100 * 1875 * 4 * 64 / (2e-3 * 819e9))
+    assert "epoch_group_hbm_roofline" not in lay
+    listless = [m["name"] for m in cell["bench"]["per_layer"]
+                if "workloads" not in m]
+    assert len(listless) > 15 and not set(listless) - set(lay)
+    assert "cc.abort_rate" not in lay and "exec.read_lanes_per_epoch" \
+        not in lay                              # not this cell's: left out
     e2e = run.compute_metrics(cell, run.metric_context(cell, res, None),
                               traced=False)
     assert set(e2e) == {m["name"] for m in cell["bench"]["end_to_end"]}
     # an accepted cell of the copy reports the appended metric that lists
     # no cells, and not the one that lists the new cell
     hot = run.load_cell("ycsb_fullrow_tpubatch.hot")
-    lay = run.compute_metrics(hot, run.metric_context(hot, res, trace),
+    res["fields"].update(req_per_query=10, tup_size=100)
+    lay = run.compute_metrics(hot, run.metric_context(hot, res, TRACE),
                               traced=True)
-    assert "client.keys_per_txn" in lay and "mesh.hidden_share" not in lay
+    assert "client.acks_per_client" in lay
+    assert "toy_group_hbm_roofline" not in lay
+    assert "epoch_group_hbm_roofline" in lay
     # the stated guarantee, not the backend's name, arms the abort check
     res["server"]["info"]["run_abort_cnt"] = 1
     assert "t.deterministic_aborts" in [
@@ -227,23 +274,29 @@ def _drive(root, tmp_path):
         if v > lim]
 
 
+CHECKS = (check_benchmark, check_per_layer, check_accepted)
+
+
 @pytest.mark.parametrize("break_it,refused_by", [
     (None, None),
     ("shape_cut", check_benchmark),
     ("metric_dropped", check_per_layer),
-    ("hot_mix_changed", check_benchmark),
-], ids=["sound", "shape_cut", "metric_dropped", "hot_mix_changed"])
+    ("hot_mix_changed", check_accepted),
+    ("accepted_entry_edited", check_accepted),
+], ids=["sound", "shape_cut", "metric_dropped", "hot_mix_changed",
+        "accepted_entry_edited"])
 def test_a_later_pr_adds_a_deployment_as_new_files_only(
         tmp_path, break_it, refused_by):
     (tmp_path / "tree").mkdir()
     root = _later_pr(tmp_path / "tree", break_it)
     if refused_by is None:
-        check_benchmark(root)
-        check_per_layer(root)
+        for check in CHECKS:
+            check(root)
         _drive(root, tmp_path)
         return
     with pytest.raises(AssertionError):
         refused_by(root)
-    # and only by that: the other half of the contract still holds
-    (check_per_layer if refused_by is check_benchmark else check_benchmark)(
-        root)
+    # and only by that: the rest of the contract still holds
+    for check in CHECKS:
+        if check is not refused_by:
+            check(root)

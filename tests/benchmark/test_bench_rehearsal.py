@@ -6,7 +6,9 @@ must pass — both launches' answer checks, and the plain reference's
 digest, commit count and (OCC) rule check on the logged stream — and
 exactly one must fail: the chip gate.  `run_cell` then refuses to print
 a result.  With the executor broken underneath (a step that returns its
-state unchanged), the reference's digest check fails too."""
+state unchanged), the reference's digest check fails too; with a
+profiler whose `stop_trace` never returns, a traced launch fails by
+name."""
 
 import os
 
@@ -136,6 +138,24 @@ def test_the_control_fails_the_comparison_on_the_toy_occ_cell(
                       run=bench_run, cell=_toy_cell(bench_run, CELLS[1]))
     out = json.loads([ln for ln in capfd.readouterr().out.splitlines()
                       if ln.startswith("{")][-1])
-    assert rc == 0 and out["control_ok"] and out["sound_failed"] == []
+    assert rc == 0 and out["control_ok"] and out["sound_failed"] == [], out
     assert "digest_mismatch" in out["lost_write_failed"]
     assert out["illegal_verdict_failed"]
+
+
+def test_a_traced_launch_whose_stop_trace_never_returns_fails_by_name(
+        bench_run, monkeypatch, tmp_path):
+    """The server child waits for `stop_trace` until shortly before its
+    own limit (`CHILD_TIMEOUT_S`; `hung_trace_server.py` makes that 5 s
+    after its start), then fails: the launch raises `RunFailed` with the
+    reason, it does not come back without its `[trace]` window."""
+    monkeypatch.setattr(bench_run, "SERVER_CHILD", os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "hung_trace_server.py"))
+    monkeypatch.setattr(bench_run, "CHILD_TIMEOUT_S", 90)
+    cell = _toy_cell(bench_run, CELLS[0])
+    fields = bench_run.server_fields(cell, 7, dict(
+        logging="false", warmup_secs=0.5, done_secs=2.0))
+    with pytest.raises(bench_run.RunFailed,
+                       match="stop_trace: no end after [0-5] s"):
+        bench_run.launch("timed", cell, fields, 7, 1.0, 0.5, str(tmp_path),
+                         True)

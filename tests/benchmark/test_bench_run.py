@@ -13,7 +13,7 @@ import sys
 import numpy as np
 import pytest
 
-from bench_contract import check_benchmark
+from bench_contract import check_accepted, check_benchmark
 from conftest import BENCH, ROOT
 
 DEVICE = ('[device] node=0 {"platform": "tpu", "kind": "TPU v5 lite", '
@@ -72,9 +72,7 @@ def test_metric_arithmetic_keeps_window_and_whole_run_apart(
     assert e2e["setup_s"] == 41.0
     assert e2e["ack_latency_p50_ms"] == pytest.approx(100.5)
     assert e2e["ack_latency_p99_ms"] == pytest.approx(198.01, abs=0.01)
-    trace = dict(busy_s=2.0, window_s=2.5, epochs=960.0, groups=30.0,
-                 group_busy_s=1.92, breakdown={})
-    ctx = bench_run.metric_context(cell, res, trace)
+    ctx = bench_run.metric_context(cell, res, TRACE)
     lay = {k: v["value"] for k, v in
            bench_run.compute_metrics(cell, ctx, traced=True).items()}
     assert lay["client.sent_txn_per_s"] == 700_000
@@ -85,9 +83,7 @@ def test_metric_arithmetic_keeps_window_and_whole_run_apart(
     assert lay["group.device_ms_per_epoch"] == 2.0
     assert lay["device.idle_share"] == pytest.approx(20.0)
     assert lay["cc.retries_per_txn"] == 0.4 and lay["cc.abort_rate"] == 25.0
-    # 1875 txn x 10 accesses x 100 B = 1.875 MB needed in 2 ms at 819 GB/s
-    assert lay["epoch_group_hbm_roofline"] == pytest.approx(
-        100 * 1.875e6 / (2e-3 * 819e9))
+    assert lay["epoch_group_hbm_roofline"] == pytest.approx(ONE_CHIP_SHARE)
     # an OCC-only metric is left out of a TPU_BATCH cell, and a reader
     # with nothing to read (no trace) returns nothing
     hot = bench_run.load_cell("ycsb_fullrow_tpubatch.hot")
@@ -95,6 +91,50 @@ def test_metric_arithmetic_keeps_window_and_whole_run_apart(
     lay = bench_run.compute_metrics(hot, ctx, traced=True)
     assert "cc.abort_rate" not in lay and "device.idle_share" not in lay
     assert "host.idle_share" in lay
+
+
+TRACE = dict(busy_s=2.0, window_s=2.5, epochs=960.0, groups=30.0,
+             group_busy_s=1.92, breakdown={})
+# 1875 txn x 10 accesses x 100 B = 1.875 MB an epoch, 2 ms of device time
+# an epoch (a chip's mean), 819 GB/s a chip
+ONE_CHIP_SHARE = 100 * 1.875e6 / (2e-3 * 819e9)
+
+
+@pytest.mark.parametrize("shards,want", [
+    (None, ONE_CHIP_SHARE),             # a server that prints no mesh
+    (1.0, ONE_CHIP_SHARE),
+    (4.0, ONE_CHIP_SHARE / 4),          # the cluster's bytes, four chips
+], ids=["no_mesh", "one_shard", "four_shards"])
+def test_the_roofline_is_a_chips_bytes_over_a_chips_time_and_peak(
+        bench_run, tmp_path, shards, want):
+    """`run_commit_cnt` is the cluster's, `group_busy_s` a chip's mean:
+    the needed bytes are divided by the chips that moved them."""
+    cell = bench_run.load_cell("ycsb_fullrow_tpubatch_dp4.hot")
+    res = _res(bench_run, tmp_path)
+    if shards is not None:
+        res["server"]["summary"]["mesh_shards"] = shards
+    read = bench_run.load_by_name("metrics", "epoch_group_hbm_roofline").read
+    assert read(bench_run.metric_context(cell, res, TRACE)) \
+        == pytest.approx(want)
+    assert read(bench_run.metric_context(cell, res, None)) is None
+
+
+def test_the_roofline_is_not_asked_in_a_cell_it_does_not_list(
+        bench_run, tmp_path):
+    """Its bytes are YCSB's: a cell of another schema (its `fields` have
+    no `req_per_query`, no `tup_size`) is not in its list, the reader is
+    never called there, and nothing raises."""
+    hot = bench_run.load_cell("ycsb_fullrow_tpubatch.hot")
+    other = dict(hot, name="tpcc_payment_neworder.mixed")
+    res = _res(bench_run, tmp_path)
+    res["fields"] = dict(pipeline_epochs=32, num_wh=64)
+    lay = bench_run.compute_metrics(
+        other, bench_run.metric_context(other, res, TRACE), traced=True)
+    assert "epoch_group_hbm_roofline" not in lay
+    assert "group.device_ms_per_epoch" in lay       # list-less: asked
+    with pytest.raises(KeyError):                   # what the list keeps out
+        bench_run.compute_metrics(
+            hot, bench_run.metric_context(hot, res, TRACE), traced=True)
 
 
 def test_check_served_catches_each_wrong_answer(bench_run, tmp_path):
@@ -317,6 +357,7 @@ def test_a_launchs_config_arguments_are_the_parents(bench_run, name):
 
 def test_benchmark_json_keeps_to_the_contract():
     check_benchmark(ROOT)
+    check_accepted(ROOT)
 
 
 def test_every_file_of_the_benchmark_has_a_contract_name():
@@ -328,6 +369,37 @@ def test_every_file_of_the_benchmark_has_a_contract_name():
             for f in fs:
                 rel = os.path.relpath(os.path.join(d, f), ROOT)
                 assert ok.match(rel) and len(rel) <= 200, rel
+
+
+def test_no_test_pins_an_entry_by_its_position_or_a_lists_length():
+    """A later PR appends cells and per-layer entries: a test here that
+    subscripts `per_layer` or `workloads` by a number or a slice, or
+    holds their length to a value, fails that PR for adding them (PR 29
+    put such a pin in; PR 28 had taken four out).  What is accepted is
+    looked up by name: `bench_contract.check_accepted`."""
+    lists = r"(?:per_layer|workloads)"
+    by_position = re.compile(
+        lists + r"""["']?\]?\[\s*[-+\d:a-z]|"""         # a subscript
+        r"len\([^()]*" + lists + r"[^()]*\)\s*[=!]=")    # a length held
+    here = os.path.join(ROOT, "tests", "benchmark")
+    hits = []
+    for name in sorted(os.listdir(here)):
+        if name.endswith(".py"):
+            with open(os.path.join(here, name)) as f:
+                hits += [f"{name}:{n}: {line.strip()}"
+                         for n, line in enumerate(f, 1)
+                         if by_position.search(line)]
+    assert hits == []
+    # the pattern does catch the forms it is there for (@: a list's
+    # name, kept out of this file's own lines)
+    for bad in ('new = bench["@"][22:]', "@[:n]", 'cell = b["@"][0]',
+                "@[-1]", 'assert len(bench["@"]) == 3'):
+        for name in ("per_layer", "workloads"):
+            assert by_position.search(bad.replace("@", name)), bad
+    for fine in ('m["workloads"] == [CELL]', 'bench["per_layer"] += METRICS',
+                 'for m in bench["per_layer"]:', 'by_name["x"]["workloads"]',
+                 'm["workloads"] = m["workloads"] + [CELL]'):
+        assert not by_position.search(fine), fine
 
 
 # ---- exits -------------------------------------------------------------
