@@ -1071,9 +1071,17 @@ class Config:
                    "order keys span epoch_batch^2 and must fit int32 "
                    "(cc/maat.py closure branch)")
         if self.sim_full_row:
-            _check(self.workload == WorkloadKind.YCSB,
-                   "sim_full_row materializes YCSB payload bytes; TPCC/PPS "
-                   "rows are numeric columns (materialized always)")
+            _check(self.workload == WorkloadKind.YCSB
+                   or (self.workload == WorkloadKind.TPCC
+                       and self.tpcc_full_schema),
+                   "sim_full_row materializes string bytes: YCSB's "
+                   "payload fields, or TPCC's full schema "
+                   "(tpcc_full_schema=true: the short schema and PPS "
+                   "have numeric columns only)")
+            _check(self.workload == WorkloadKind.YCSB
+                   or self.device_parts == 1,
+                   "TPCC full-width rows are loaded on one device "
+                   "(the column-at-a-time loader builds no mesh layout)")
         if self.workload == WorkloadKind.YCSB:
             _check(self.max_accesses >= self.req_per_query,
                    "max_accesses must cover req_per_query")

@@ -127,6 +127,11 @@ def run_levels(cfg, wl, db, queries, exec_commit, verdict, stats,
         stats = dict(stats)
         db = wl.execute(db, queries, m, verdict.order, stats,
                         level_exec=level_exec)
+        if "level_pass_cnt" in stats:
+            # passes of this loop (the served chained path counts them:
+            # `engine/step.init_device_stats(level_passes=True)`)
+            stats["level_pass_cnt"] = stats["level_pass_cnt"] + \
+                jnp.uint32(1)
         return lvl + 1, db, stats
 
     _, db, stats = jax.lax.while_loop(

@@ -61,9 +61,15 @@ jax.tree_util.register_dataclass(
     meta_fields=[])
 
 
-def init_device_stats(n_txn_types: int = 1, n_parts: int = 1) -> dict:
+def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
+                      level_passes: bool = False) -> dict:
+    """``level_passes``: add ``level_pass_cnt``, which
+    `engine/epoch.run_levels` counts where it finds it — asked for by
+    the server of a chained backend alone, so every other program's
+    stats pytree (and with it its compiled text) is what it was."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
+        **({"level_pass_cnt": z()} if level_passes else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the
         # contention-adaptive router's input).  Always present so the

@@ -273,13 +273,34 @@ def state_digest(db) -> str:
     excluded — the digest covers ROW state, so an elastic run with no
     rebalance hashes identically to the same tables under static
     membership."""
+    return state_digests(db)[0]
+
+
+def state_digests(db) -> tuple[str, dict[str, str]]:
+    """(`state_digest`, {leaf path: sha256 of that leaf alone}): the whole
+    digest says THAT a replay differs, a leaf's own says WHERE — a path
+    reads ``STOCK.columns.S_QUANTITY`` / ``ORDER.row_cnt``.  One pull of
+    each leaf; its two hashes run side by side (hashlib releases the
+    interpreter lock), so the pair costs the wall time of one."""
     import hashlib
+    from concurrent.futures import ThreadPoolExecutor
 
     import jax
 
     h = hashlib.sha256()
-    for path, leaf in jax.tree_util.tree_flatten_with_path(db)[0]:
-        if any(str(getattr(p, "key", "")).startswith("__") for p in path):
-            continue
-        h.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
-    return h.hexdigest()
+    per_leaf: dict[str, str] = {}
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        for path, leaf in jax.tree_util.tree_flatten_with_path(db)[0]:
+            if any(str(getattr(p, "key", "")).startswith("__")
+                   for p in path):
+                continue
+            buf = np.ascontiguousarray(np.asarray(leaf)).reshape(-1).view(
+                np.uint8)
+            own = pool.submit(hashlib.sha256, buf)
+            try:
+                h.update(buf)
+            finally:        # (a future is drained on every path)
+                per_leaf[".".join(
+                    str(getattr(p, "key", getattr(p, "name", p)))
+                    for p in path)] = own.result().hexdigest()
+    return h.hexdigest(), per_leaf

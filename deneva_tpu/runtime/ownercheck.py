@@ -48,7 +48,7 @@ OWNER: dict[str, str] = {
     "n_repl": DISPATCH, "b_loc": DISPATCH, "b_merged": DISPATCH,
     "wl": DISPATCH, "be": DISPATCH, "vote_mode": DISPATCH,
     "defer_budget": DISPATCH, "C": DISPATCH, "K": DISPATCH,
-    "_width": DISPATCH, "_n_scalars": DISPATCH,
+    "_width": DISPATCH, "_n_scalars": DISPATCH, "_counts_levels": DISPATCH,
     "vote_step": DISPATCH, "check_step": DISPATCH, "apply_step": DISPATCH,
     "maat_vote": DISPATCH, "group_step": DISPATCH,
     "_elastic": DISPATCH, "_M": DISPATCH, "_full_planes": DISPATCH,

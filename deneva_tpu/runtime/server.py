@@ -42,9 +42,6 @@ from collections import deque
 import numpy as np
 
 from deneva_tpu.config import CCAlg, Config
-# make_dist_step: ServerNode's replay paths use it, and it stays
-# importable from here because benchmark/verdicts_child.py (an accepted
-# benchmark file) imports it from this module (ROADMAP D0 repoints it)
 from deneva_tpu.engine.epoch import (make_dist_group, make_dist_step,
                                      make_vote_steps)
 from deneva_tpu.runtime import replication as georepl
@@ -188,8 +185,15 @@ class ServerNode:
         t_load = time.monotonic()
         self.db = self.wl.load()
         self.cc_state = self.be.init_state(cfg)
+        # `run_levels`' loop runs where a chained backend executes its
+        # levels in the group program of one device: there its passes
+        # are counted (`level_pass_cnt`)
+        self._counts_levels = (
+            self.be.chained and not forwarding_applies(self.be, self.wl)
+            and not self.vote_mode and cfg.device_parts == 1)
         self.dev_stats = init_device_stats(
-            len(getattr(self.wl, "txn_type_names", ("txn",))))
+            len(getattr(self.wl, "txn_type_names", ("txn",))),
+            level_passes=self._counts_levels)
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -2937,12 +2941,14 @@ class ServerNode:
             window_compile_cnt=n_comp - self._compiles_meas,
             run_commit_cnt=int(final["total_txn_commit_cnt"]),
             run_abort_cnt=int(final["total_txn_abort_cnt"]))
-        from deneva_tpu.runtime.logger import state_digest
+        from deneva_tpu.runtime.logger import state_digests
         if cfg.logging:
             # the table as the device holds it after the last logged
             # epoch: a replay of the command log on any backend must
-            # hash to the same digest (runtime/logger.replay_log)
-            self.info["state_digest"] = state_digest(self.db)
+            # hash to the same digest (runtime/logger.replay_log); the
+            # leaves' own digests say WHICH table and column differs
+            self.info["state_digest"], self.info["column_digests"] = \
+                state_digests(self.db)
         st = self.stats
         st.set("total_runtime", end - self._t_meas)
         st.set("epoch_cnt", float(epochs_run))
@@ -2953,6 +2959,9 @@ class ServerNode:
         for dev in EXEC_COUNTERS:
             if dev.endswith("_lanes"):
                 st.set(dev[:-1] + "_cnt", float(final[dev] - measured[dev]))
+        if self._counts_levels:
+            st.set("level_pass_cnt", float(final["level_pass_cnt"]
+                                           - measured["level_pass_cnt"]))
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):
                 key = f"{fam}_by_type"

@@ -42,6 +42,7 @@ tested against.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -52,7 +53,9 @@ from deneva_tpu.config import Config
 from deneva_tpu.ops import last_writer
 from deneva_tpu.storage.catalog import parse_schema
 from deneva_tpu.workloads.base import partition_owned, partition_slot
-from deneva_tpu.storage.table import DeviceTable, fill_columns, to_mc_layout
+from deneva_tpu.storage.table import (DeviceTable, fill_columns, padded_rows,
+                                      to_mc_layout)
+from deneva_tpu.workloads.ycsb import _field_bytes
 
 # ---------------------------------------------------------------------------
 # schema (column set of benchmarks/TPCC_short_schema.txt)
@@ -91,8 +94,12 @@ TPCC_SCHEMA = "".join(
 
 # TPCC_FULL_SCHEMA extras (reference `benchmarks/TPCC_full_schema.txt`):
 # the columns the short schema drops.  Strings materialize as fingerprint
-# words (storage/table.py); loader fills them deterministically, and the
-# full-schema execution deltas below keep S_YTD/S_ORDER_CNT/OL_* live.
+# words (storage/table.py) or, with ``sim_full_row``, as their bytes at
+# the schema's widths (``uint8[rows, size]``: a STOCK row holds 290 B of
+# strings and a CUSTOMER row 607 B, 314 and 651 B with their 4 B
+# numbers); the loader fills them
+# deterministically from the row id, and the full-schema execution deltas
+# below keep S_YTD/S_ORDER_CNT/OL_* live.
 _FULL_EXTRA = {
     "WAREHOUSE": [("W_NAME", "string", 10), ("W_STREET_1", "string", 20),
                   ("W_STREET_2", "string", 20), ("W_CITY", "string", 20),
@@ -137,6 +144,16 @@ TID = {name: i for i, name in enumerate(_SCHEMA_COLS)}
 
 TPCC_PAYMENT = 0
 TPCC_NEW_ORDER = 1
+
+# full-width rows keep the ten S_DIST_xx of a stock row as ONE array of
+# (row, district) cells, ``S_DIST: uint8[rows * 10, 24]`` with cell
+# ``row * 10 + d``: NewOrder copies exactly one of the ten into each
+# order line, picked by the order's district, and one gather of the
+# lines' cells reads it where ten columns would take ten gathers of
+# every line.  The layout is the builder's; the bytes are the schema's.
+S_DIST = "S_DIST"
+_S_DIST_COLS = tuple(f"S_DIST_{i:02d}" for i in range(1, 11))
+_DIST_INFO_BYTES = 24
 
 _LASTNAMES = 1000          # Lastname(NURand(255,0,999)), tpcc_helper.cpp
 
@@ -191,6 +208,9 @@ class TPCCWorkload:
     def __init__(self, cfg: Config):
         self.cfg = cfg
         self.full_schema = cfg.tpcc_full_schema
+        # strings as bytes at the schema's widths (config.validate: only
+        # with the full schema, on one device)
+        self.full_row = cfg.sim_full_row
         self.catalog = parse_schema(tpcc_schema(self.full_schema))
         self.n_wh = cfg.num_wh
         self.n_dist = 10                     # DIST_PER_WARE (tpcc_const.h)
@@ -334,6 +354,8 @@ class TPCCWorkload:
         index, so the whole load is a single XLA program: zero
         host->device bytes, compile + run in seconds at any scale."""
         db = jax.jit(self._build_db)()
+        if self.full_row:
+            db = self._fill_strings(db)
         if self.cfg.audit:
             # isolation audit stamp tables (cc/base.audit_observe):
             # loader-installed so every db-construction path threads the
@@ -348,7 +370,8 @@ class TPCCWorkload:
         db = {}
 
         def tab(name, cap, ring=False):
-            t = DeviceTable.create(self.catalog.table(name), cap, ring=ring)
+            t = DeviceTable.create(self.catalog.table(name), cap,
+                                   full_row=self.full_row, ring=ring)
             db[name] = t
             return t
 
@@ -432,13 +455,20 @@ class TPCCWorkload:
                     continue
                 cols = dict(db[t].columns)
                 ids = jnp.arange(n, dtype=jnp.int32).astype(jnp.uint32)
-                for j, (cn, _ct, _sz) in enumerate(extras):
+                for j, (cn, ct, _sz) in enumerate(extras):
                     if cn in ("S_YTD", "S_ORDER_CNT", "C_DELIVERY_CNT"):
                         continue       # spec-initialized counters: zero
+                    if self.full_row and ct == "string":
+                        continue       # bytes: `_fill_strings`
                     v = ids * jnp.uint32(2654435761) \
                         + jnp.uint32(0x9E3779B9) * jnp.uint32(j + 1)
                     cols[cn] = cols[cn].at[:n].set(
                         v.astype(cols[cn].dtype))
+                if self.full_row and t == "STOCK":
+                    for cn in _S_DIST_COLS:
+                        del cols[cn]
+                    cols[S_DIST] = jnp.zeros(
+                        (padded_rows(n) * 10, _DIST_INFO_BYTES), jnp.uint8)
                 db[t] = db[t]._replace(columns=cols)
 
         D = cfg.device_parts
@@ -464,6 +494,30 @@ class TPCCWorkload:
                 np.zeros(0, np.int32), np.zeros(0, np.int32),
                 miss_slot=db["ORDER"].capacity,
                 cap=self.cfg.insert_table_cap)
+        return db
+
+    def _fill_strings(self, db):
+        """Full-width rows: the fixed tables' string columns get their
+        bytes — column j of a table holds ``_field_bytes(row, j + 1)`` at
+        the schema's width, cell ``(row, d)`` of `S_DIST` what column
+        S_DIST_<d+1> would — ONE COLUMN AT A TIME, each in place (the
+        zeros `_build_db` made are donated): one program over all of
+        them holds 6.6 GB of temporaries beside 8.8 GB of tables at 128
+        warehouses (the chip's compiler, no chip attached)."""
+        # (donation off the CPU backend only, as `make_dist_group`)
+        fill = _string_filler(jax.default_backend() != "cpu")
+        db = dict(db)
+        for t, extras in _FULL_EXTRA.items():
+            if db[t].ring:             # ring tables fill at insert time
+                continue
+            n = db[t].capacity         # a fixed table is loaded full
+            cols = dict(db[t].columns)
+            for j, (cn, ct, _sz) in enumerate(extras):
+                if ct == "string" and cn in cols:
+                    cols[cn] = fill(cols[cn], jnp.uint32(j + 1), n, 1)
+            if t == "STOCK":
+                cols[S_DIST] = fill(cols[S_DIST], jnp.uint32(1), n, 10)
+            db[t] = db[t]._replace(columns=cols)
         return db
 
     # -- generation (tpcc_query.cpp:144-260) ----------------------------
@@ -687,6 +741,16 @@ class TPCCWorkload:
         neworder = mask & ~is_pay
         db = self._exec_payment(db, q, pay, stats)
         db = self._exec_neworder(db, q, neworder, order, stats, level_exec)
+        if "write_scatter_lanes" in stats:
+            # lanes handed to a scatter, a scatter_add or an append, by
+            # call: a pass hands each the whole batch whatever its mask
+            # — Payment's three accumulator rows and its HISTORY row,
+            # NewOrder's D_NEXT_O_ID, ORDER and NEW-ORDER rows, and per
+            # item the stock scatter, the stock adds and the ORDER-LINE
+            n = q.w_id.shape[0]
+            stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + \
+                jnp.uint32((6 + bool(self.cfg.wh_update)) * n
+                           + 3 * n * self.ipt)
         return db
 
     def _exec_payment(self, db, q, m, stats):
@@ -695,27 +759,29 @@ class TPCCWorkload:
         component lands only on its owner (remote slots resolve to trash),
         so a cross-warehouse payment splits naturally across nodes."""
         amt = jnp.where(m, q.h_amount, 0.0)
-        if self.cfg.wh_update:
-            db["WAREHOUSE"] = db["WAREHOUSE"].scatter_add(
-                self.wh_slot(q.w_id), {"W_YTD": amt}, mask=m)
-        db["DISTRICT"] = db["DISTRICT"].scatter_add(
-            self.dist_slot(q.w_id, q.d_id), {"D_YTD": amt}, mask=m)
-        ck = self.cust_slot(q.c_w_id, q.c_d_id, q.c_id)
-        db["CUSTOMER"] = db["CUSTOMER"].scatter_add(
-            ck, {"C_BALANCE": -amt, "C_YTD_PAYMENT": amt,
-                 "C_PAYMENT_CNT": m.astype(jnp.int32)}, mask=m)
         hist_row = {"H_C_ID": q.c_id, "H_C_D_ID": q.c_d_id,
                     "H_C_W_ID": q.c_w_id, "H_D_ID": q.d_id,
                     "H_W_ID": q.w_id, "H_AMOUNT": q.h_amount}
         if self.full_schema:
             n = q.w_id.shape[0]
             hist_row["H_DATE"] = jnp.full((n,), 2013, jnp.int32)
-            hist_row["H_DATA"] = (q.c_id.astype(jnp.uint32)
-                                  * jnp.uint32(0x9E3779B9))
-        hist, _ = db["HISTORY"].append(hist_row,
-                                       m & self.wh_owned(q.w_id),
-                                       anchor=q.w_id)
-        db["HISTORY"] = hist
+            hist_row["H_DATA"] = (
+                _field_bytes(q.c_id, q.w_id, _DIST_INFO_BYTES)
+                if self.full_row else
+                q.c_id.astype(jnp.uint32) * jnp.uint32(0x9E3779B9))
+        with jax.named_scope("ep.write"):
+            if self.cfg.wh_update:
+                db["WAREHOUSE"] = db["WAREHOUSE"].scatter_add(
+                    self.wh_slot(q.w_id), {"W_YTD": amt}, mask=m)
+            db["DISTRICT"] = db["DISTRICT"].scatter_add(
+                self.dist_slot(q.w_id, q.d_id), {"D_YTD": amt}, mask=m)
+            ck = self.cust_slot(q.c_w_id, q.c_d_id, q.c_id)
+            db["CUSTOMER"] = db["CUSTOMER"].scatter_add(
+                ck, {"C_BALANCE": -amt, "C_YTD_PAYMENT": amt,
+                     "C_PAYMENT_CNT": m.astype(jnp.int32)}, mask=m)
+            m_h = m & self.wh_owned(q.w_id)
+            db["HISTORY"], _ = db["HISTORY"].append(
+                _live_rows(hist_row, m_h), m_h, anchor=q.w_id)
         # W_YTD + D_YTD + 3 customer cols + HISTORY row per payment
         stats["write_cnt"] = stats["write_cnt"] + \
             (m.sum() * 6).astype(jnp.uint32)
@@ -727,67 +793,69 @@ class TPCCWorkload:
         per-district segmented prefix sum over the committed batch in
         serialization order — D_NEXT_O_ID++ under the row latch, batched."""
         n = q.w_id.shape[0]
+        I = self.ipt
         dist = db["DISTRICT"]
         dk = self.dist_key(q.w_id, q.d_id)          # global (segment id)
         dslot = self.dist_slot(q.w_id, q.d_id)      # local (storage)
         owned = self.wh_owned(q.w_id)
-
-        # taxes / discount reads feed the checksum (keeps gathers alive)
-        w_tax = db["WAREHOUSE"].gather(self.wh_slot(q.w_id),
-                                       ("W_TAX",))["W_TAX"]
-        d = dist.gather(dslot, ("D_TAX", "D_NEXT_O_ID"))
-        c_disc = db["CUSTOMER"].gather(
-            self.cust_slot(q.w_id, q.d_id, q.c_id),
-            ("C_DISCOUNT",))["C_DISCOUNT"]
-        # per-lane integer conversion BEFORE the sum: uint32 addition is
-        # associative, so the multi-chip psum of per-chip partial sums is
-        # bit-identical to the single-chip value (mc.py contract) — a
-        # float sum would round differently per reduction order
-        stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
-            jnp.where(m, (w_tax + d["D_TAX"] + c_disc) * 1000, 0)
-            .astype(jnp.uint32), dtype=jnp.uint32)
-
-        # o_id = snapshot next_o_id + rank among committed same-district
-        # neworders ordered by serialization order
-        big = jnp.int32(jnp.iinfo(jnp.int32).max)
-        # bounded segment id (masked rows share one trailing segment) so
-        # the composite sort key stays within int32
-        seg = jnp.where(m, dk, jnp.int32(self.n_districts))
-        order_rank = jnp.argsort(jnp.argsort(jnp.where(m, order, big)))
-        sort_key = seg * (2 * n) + order_rank.astype(jnp.int32)
-        perm = jnp.argsort(sort_key)
-        sorted_seg = jnp.take(seg, perm)
-        new_segment = jnp.concatenate(
-            [jnp.ones((1,), bool), sorted_seg[1:] != sorted_seg[:-1]])
-        pos = jnp.arange(n) - jax.lax.cummax(
-            jnp.where(new_segment, jnp.arange(n), 0))
-        rank = jnp.zeros((n,), jnp.int32).at[perm].set(pos.astype(jnp.int32))
-        o_id = d["D_NEXT_O_ID"] + rank
-
-        db["DISTRICT"] = dist.scatter_add(
-            dslot, {"D_NEXT_O_ID": m.astype(jnp.int32)}, mask=m)
-
-        # stock update (new_order_8): non-commutative quantity rule ->
-        # gather/modify/last-writer scatter; S_REMOTE_CNT is scatter_add
-        I = self.ipt
+        bcast = lambda x: jnp.broadcast_to(x[:, None], (n, I)).reshape(-1)  # noqa: E731
         iv = (q.item_valid & m[:, None]).reshape(-1)
         sk = self.stock_slot(q.supply_w, q.items).reshape(-1)
         qty = q.quantity.reshape(-1)
         stock = db["STOCK"]
-        s_q = stock.gather(sk, ("S_QUANTITY",))["S_QUANTITY"]
+
+        with jax.named_scope("ep.read"):
+            # taxes / discount reads feed the checksum (keeps gathers
+            # alive)
+            w_tax = db["WAREHOUSE"].gather(self.wh_slot(q.w_id),
+                                           ("W_TAX",))["W_TAX"]
+            d = dist.gather(dslot, ("D_TAX", "D_NEXT_O_ID"))
+            c_disc = db["CUSTOMER"].gather(
+                self.cust_slot(q.w_id, q.d_id, q.c_id),
+                ("C_DISCOUNT",))["C_DISCOUNT"]
+            # per-lane integer conversion BEFORE the sum: uint32 addition
+            # is associative, so the multi-chip psum of per-chip partial
+            # sums is bit-identical to the single-chip value (mc.py
+            # contract) — a float sum would round differently per
+            # reduction order
+            stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
+                jnp.where(m, (w_tax + d["D_TAX"] + c_disc) * 1000, 0)
+                .astype(jnp.uint32), dtype=jnp.uint32)
+            s_q = stock.gather(sk, ("S_QUANTITY",))["S_QUANTITY"]
+            if self.full_schema:
+                price = jnp.take(db["ITEM"].columns["I_PRICE"],
+                                 jnp.clip(q.items, 0, self.max_items - 1),
+                                 axis=0).reshape(-1)
+            if self.full_row:
+                # the line's OL_DIST_INFO: the stock row's S_DIST_<d_id>
+                # (new_order_8 copies it), one 24 B cell a line
+                cell = jnp.where((sk < 0) | (sk > stock.capacity),
+                                 stock.capacity, sk) * 10 + bcast(q.d_id)
+                dist_info = jnp.take(stock.columns[S_DIST], cell, axis=0)
+
+        with jax.named_scope("ep.oid"):
+            # o_id = snapshot next_o_id + rank among committed
+            # same-district neworders ordered by serialization order
+            big = jnp.int32(jnp.iinfo(jnp.int32).max)
+            # bounded segment id (masked rows share one trailing segment)
+            # so the composite sort key stays within int32
+            seg = jnp.where(m, dk, jnp.int32(self.n_districts))
+            order_rank = jnp.argsort(jnp.argsort(jnp.where(m, order, big)))
+            sort_key = seg * (2 * n) + order_rank.astype(jnp.int32)
+            perm = jnp.argsort(sort_key)
+            sorted_seg = jnp.take(seg, perm)
+            new_segment = jnp.concatenate(
+                [jnp.ones((1,), bool), sorted_seg[1:] != sorted_seg[:-1]])
+            pos = jnp.arange(n) - jax.lax.cummax(
+                jnp.where(new_segment, jnp.arange(n), 0))
+            rank = jnp.zeros((n,), jnp.int32).at[perm].set(
+                pos.astype(jnp.int32))
+            o_id = d["D_NEXT_O_ID"] + rank
+
+        # stock update (new_order_8): non-commutative quantity rule ->
+        # gather/modify/last-writer scatter; S_REMOTE_CNT is scatter_add
         # strict: replenish at s_q - qty <= 10 (tpcc_txn.cpp new_order_8/9)
         new_q = jnp.where(s_q - qty > 10, s_q - qty, s_q - qty + 91)
-        if level_exec:
-            # chained sub-round: the level's committed set is stock-
-            # conflict-free and item_valid dedups in-txn items, so every
-            # valid lane IS the final writer — the scatter-max
-            # tournament (4 full-table passes) is redundant
-            win = iv
-        else:
-            worder = jnp.broadcast_to(order[:, None], (n, I)).reshape(-1)
-            win = last_writer(jnp.where(iv, sk, stock.capacity), worder, iv,
-                              stock.capacity)
-        stock = stock.scatter(sk, {"S_QUANTITY": new_q}, mask=win)
         remote = (q.supply_w != q.w_id[:, None]).reshape(-1)
         adds = {"S_REMOTE_CNT": (iv & remote).astype(jnp.int32)}
         if self.full_schema:
@@ -795,8 +863,6 @@ class TPCCWorkload:
             # quantity, s_order_cnt++) — commutative scatter-adds
             adds["S_YTD"] = jnp.where(iv, qty, 0)
             adds["S_ORDER_CNT"] = iv.astype(jnp.int32)
-        db["STOCK"] = stock.scatter_add(sk, adds, mask=iv)
-
         # inserts: ORDER, NEW-ORDER, ORDER-LINE (new_order_1 / _3 / _9) —
         # at the home warehouse's owner node only
         m_ins = m & owned
@@ -808,40 +874,85 @@ class TPCCWorkload:
                      "O_ALL_LOCAL": all_local.astype(jnp.int32)}
         if self.full_schema:
             order_row["O_CARRIER_ID"] = jnp.zeros((n,), jnp.int32)
-        db["ORDER"], oslots = db["ORDER"].append(order_row, m_ins,
-                                                 anchor=q.w_id)
-        if "ORDER_IDX" in db:
-            # between-epoch batched merge into the dynamic ordered index
-            # (one fused sort per epoch instead of per-key tree descents)
-            db["ORDER_IDX"] = db["ORDER_IDX"].insert(
-                self.order_index_key(q.w_id, q.d_id, o_id), oslots, m_ins)
-        db["NEW-ORDER"], _ = db["NEW-ORDER"].append(
-            {"NO_O_ID": o_id, "NO_D_ID": q.d_id, "NO_W_ID": q.w_id}, m_ins,
-            anchor=q.w_id)
         ol_m = (q.item_valid & m_ins[:, None]).reshape(-1)
-        bcast = lambda x: jnp.broadcast_to(x[:, None], (n, I)).reshape(-1)  # noqa: E731
         ol_row = {"OL_O_ID": bcast(o_id), "OL_D_ID": bcast(q.d_id),
                   "OL_W_ID": bcast(q.w_id),
                   "OL_NUMBER": jnp.broadcast_to(jnp.arange(I)[None], (n, I)
                                                 ).reshape(-1),
                   "OL_I_ID": q.items.reshape(-1),
-                  "OL_QUANTITY": q.quantity.reshape(-1)}
+                  "OL_QUANTITY": qty}
         if self.full_schema:
-            price = jnp.take(db["ITEM"].columns["I_PRICE"],
-                             jnp.clip(q.items, 0, self.max_items - 1),
-                             axis=0).reshape(-1)
             ol_row["OL_SUPPLY_W_ID"] = q.supply_w.reshape(-1)
             ol_row["OL_DELIVERY_D"] = jnp.zeros((n * I,), jnp.int32)
-            ol_row["OL_AMOUNT"] = (q.quantity.reshape(-1) * price
-                                   ).astype(jnp.float32)
-            ol_row["OL_DIST_INFO"] = (q.items.reshape(-1).astype(jnp.uint32)
-                                      * jnp.uint32(2654435761))
-        db["ORDER-LINE"], _ = db["ORDER-LINE"].append(ol_row, ol_m,
-                                                      anchor=bcast(q.w_id))
+            ol_row["OL_AMOUNT"] = (qty * price).astype(jnp.float32)
+            ol_row["OL_DIST_INFO"] = dist_info if self.full_row else (
+                q.items.reshape(-1).astype(jnp.uint32)
+                * jnp.uint32(2654435761))
+
+        with jax.named_scope("ep.write"):
+            db["DISTRICT"] = dist.scatter_add(
+                dslot, {"D_NEXT_O_ID": m.astype(jnp.int32)}, mask=m)
+            if level_exec:
+                # chained sub-round: the level's committed set is stock-
+                # conflict-free and item_valid dedups in-txn items, so
+                # every valid lane IS the final writer — the scatter-max
+                # tournament (4 full-table passes) is redundant
+                win = iv
+            else:
+                worder = bcast(order)
+                win = last_writer(jnp.where(iv, sk, stock.capacity), worder,
+                                  iv, stock.capacity)
+            stock = stock.scatter(
+                sk, _live_rows({"S_QUANTITY": new_q}, win), mask=win)
+            db["STOCK"] = stock.scatter_add(sk, adds, mask=iv)
+            db["ORDER"], oslots = db["ORDER"].append(
+                _live_rows(order_row, m_ins), m_ins, anchor=q.w_id)
+            if "ORDER_IDX" in db:
+                # between-epoch batched merge into the dynamic ordered
+                # index (one fused sort per epoch instead of per-key tree
+                # descents)
+                db["ORDER_IDX"] = db["ORDER_IDX"].insert(
+                    self.order_index_key(q.w_id, q.d_id, o_id), oslots,
+                    m_ins)
+            db["NEW-ORDER"], _ = db["NEW-ORDER"].append(
+                _live_rows({"NO_O_ID": o_id, "NO_D_ID": q.d_id,
+                            "NO_W_ID": q.w_id}, m_ins), m_ins,
+                anchor=q.w_id)
+            db["ORDER-LINE"], _ = db["ORDER-LINE"].append(
+                _live_rows(ol_row, ol_m), ol_m, anchor=bcast(q.w_id))
 
         stats["write_cnt"] = stats["write_cnt"] + \
             (iv.sum() + m.sum() * 2).astype(jnp.uint32)
         return db
+
+
+@functools.lru_cache(maxsize=None)
+def _string_filler(donate: bool):
+    """Jitted ``(col, salt, n, cells) -> col`` with rows ``[0, n * cells)``
+    of a ``uint8[rows, size]`` column set to the bytes of (row //
+    ``cells``, ``salt`` + row % ``cells``), in place where ``donate``."""
+    @functools.partial(jax.jit, static_argnums=(2, 3),
+                       donate_argnums=(0,) if donate else ())
+    def fill(col, salt, n, cells):
+        r = jnp.arange(col.shape[0], dtype=jnp.uint32)
+        v = _field_bytes(r // jnp.uint32(cells),
+                         salt + r % jnp.uint32(cells), col.shape[1])
+        # (the trash and pad rows past the loaded ones stay zero)
+        return jnp.where((r < n * cells)[:, None], v, col)
+    return fill
+
+
+def _live_rows(rows: dict, mask: jax.Array) -> dict:
+    """``rows`` with the lanes outside ``mask`` zeroed.  A masked lane of
+    a scatter or an append lands in the table's trash slot, where which
+    of the lanes is left standing is the compiler's choice: writing
+    zeros there keeps the trash row at its load value whichever wins, so
+    a table's bytes are a function of the committed stream alone (the
+    per-column digests of `runtime/logger.state_digests` are compared
+    with a serial reference's)."""
+    return {n: jnp.where(mask.reshape(mask.shape + (1,) * (v.ndim - 1)),
+                         v, jnp.zeros((), v.dtype))
+            for n, v in rows.items()}
 
 
 def _rand01(ids: jax.Array, salt: int) -> jax.Array:

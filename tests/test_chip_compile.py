@@ -406,3 +406,59 @@ def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
         f"= u8[{f0.shape[0]},{f0.shape[1]}]" in ln for ln in scatters)
     # the matrix itself: one [B, B] result of A x A fused compares
     assert re.search(rf"f32\[{b},{b}\]\S* fusion\(", hlo)
+
+
+# ---- the TPC-C cell (PR 36): tpcc_fullschema_tpubatch.mixed --------------
+
+CELL_TPCC = "tpcc_fullschema_tpubatch.mixed"
+
+
+def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
+    """128 warehouses at the full schema's row widths, as the cell's timed
+    launch builds them: the nine tables are 8.6 GB of rows and the chip
+    holds them in under 9 GB (narrow `uint8[rows, size]` columns are
+    tiled with the ROWS minor: a width that is a multiple of 8 pads
+    nothing); the group program updates them in place with tens of MB
+    of temporaries and copies none of the wide columns; the loader —
+    one program for the numbers, one a string column in place — never
+    needs more than the tables and half a GB."""
+    from deneva_tpu.engine.step import init_device_stats
+    from deneva_tpu.workloads import get_workload
+    from deneva_tpu.workloads.tpcc import S_DIST, _string_filler
+    cfg = _cell_cfg(CELL_TPCC)
+    assert (cfg.num_wh, cfg.sim_full_row, cfg.epoch_batch) == (128, True,
+                                                               1024)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    # the server of a chained backend counts its level passes
+    state["stats"] = jax.eval_shape(
+        lambda: init_device_stats(2, level_passes=True))
+    state, feed = _with_sharding((state, feed), one_chip)
+    table = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"]))
+    assert 8.5e9 < table < 8.7e9
+    compiled, secs = _compile(group, state["db"], state["cc_state"],
+                              state["stats"], *feed)
+    need = _report("tpcc_fullschema_128wh", compiled, secs)
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= table           # updated in place
+    assert m.output_size_in_bytes < 1.03 * table    # rows minor: no padding
+    assert m.temp_size_in_bytes < 256e6
+    assert table + cfg.pipeline_groups * (need - table) < 0.6 * HBM_BYTES
+    hlo = compiled.as_text()
+    for name, col in (("STOCK", S_DIST), ("ORDER-LINE", "OL_DIST_INFO"),
+                      ("CUSTOMER", "C_DATA")):
+        c = state["db"][name].columns[col]
+        assert not _column_copies(hlo, f"u8[{c.shape[0]},{c.shape[1]}]")
+    # the loader: the numbers' program, then the widest string column
+    wl = get_workload(cfg)
+    built, secs = _compile(jax.jit(wl._build_db, out_shardings=one_chip))
+    assert _report("tpcc_build_db", built, secs) < 1.03 * table
+    cells = state["db"]["STOCK"].columns[S_DIST]
+    fill, secs = _compile(
+        _string_filler(True), cells,
+        jax.ShapeDtypeStruct((), np.uint32, sharding=one_chip),
+        wl.n_stock_loc, 10)
+    m = fill.memory_analysis()
+    _report("tpcc_fill_s_dist", fill, secs)
+    assert m.alias_size_in_bytes >= cells.size and \
+        m.temp_size_in_bytes < 1e9

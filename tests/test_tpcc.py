@@ -329,3 +329,83 @@ def test_full_schema_mode():
     short_stats = {k: np.asarray(v) for k, v in s_short.stats.items()}
     assert int(short_stats["total_txn_commit_cnt"]) == \
         int(stats["total_txn_commit_cnt"])
+
+
+def test_full_width_rows_load_and_append_bytes():
+    """``sim_full_row`` with the full schema: every string is its bytes at
+    the schema's width — STOCK 338 B and CUSTOMER 695 B a row as the
+    schema file counts them (8 B numbers; 314 / 651 B at the program's 4 B),
+    the ten S_DIST_xx one array of (row, district) cells — the loader
+    fills them from (row, column), NewOrder copies the stock row's cell
+    of its district into the line, Payment appends H_DATA's bytes, and
+    no masked lane leaves anything in a trash row."""
+    from deneva_tpu.workloads.tpcc import (_FULL_EXTRA, _SCHEMA_COLS, S_DIST,
+                                           _field_bytes)
+    cfg = tpcc_cfg(tpcc_full_schema=True, sim_full_row=True,
+                   cc_alg="TPU_BATCH", perc_payment=0.5).validate()
+    wl = get_workload(cfg)
+    db = jax.device_get(wl.load())
+    widths = {t: {cn: sz for cn, ct, sz in ex if ct == "string"}
+              for t, ex in _FULL_EXTRA.items()}
+    for t, cols in widths.items():
+        for cn, sz in cols.items():
+            if t == "STOCK" and cn.startswith("S_DIST_"):
+                assert cn not in db[t].columns
+                continue
+            col = db[t].columns[cn]
+            assert col.dtype == np.uint8 and col.shape[1] == sz, (t, cn)
+    row_bytes = {t: 8 * len(_SCHEMA_COLS[t]) + sum(
+        sz for _cn, _ct, sz in _FULL_EXTRA.get(t, ())) for t in _SCHEMA_COLS}
+    assert row_bytes["STOCK"] == 338 and row_bytes["CUSTOMER"] == 695
+    held = {t: sum(int(np.prod(v.shape[1:])) * v.dtype.itemsize
+                   for cn, v in db[t].columns.items() if cn != S_DIST)
+            + (240 if t == "STOCK" else 0) for t in db}
+    assert held["STOCK"] == 314 and held["CUSTOMER"] == 651
+    assert held["ORDER-LINE"] == 60 and held["HISTORY"] == 52
+    # the loader's bytes: column j of a table holds (row, j + 1)
+    n = wl.n_stock_loc
+    cells = db["STOCK"].columns[S_DIST]
+    assert cells.shape == (db["STOCK"].columns["S_I_ID"].shape[0] * 10, 24)
+    for row, d in ((0, 0), (7, 3), (n - 1, 9)):
+        assert (cells[row * 10 + d] == np.asarray(
+            _field_bytes(jnp.uint32(row), d + 1, 24))).all()
+    assert not cells[n * 10:].any()
+    c_data = db["CUSTOMER"].columns["C_DATA"]
+    assert (c_data[5] == np.asarray(_field_bytes(jnp.uint32(5), 13, 500))
+            ).all() and not c_data[wl.n_cust_loc:].any()
+
+    state = run_epochs(cfg, n=20)
+    db = state.db
+    n_ord = int(db["ORDER"].row_cnt)
+    n_ol = int(db["ORDER-LINE"].row_cnt)
+    n_hist = int(db["HISTORY"].row_cnt)
+    assert n_ord > 0 and n_ol > n_ord and n_hist > 0
+    ol = {k: np.asarray(v) for k, v in db["ORDER-LINE"].columns.items()}
+    cells = np.asarray(db["STOCK"].columns[S_DIST])
+    srow = ol["OL_SUPPLY_W_ID"][:n_ol] * cfg.max_items + ol["OL_I_ID"][:n_ol]
+    assert (ol["OL_DIST_INFO"][:n_ol]
+            == cells[srow * 10 + ol["OL_D_ID"][:n_ol]]).all()
+    assert ol["OL_DIST_INFO"][:n_ol].any(axis=1).all()
+    h = {k: np.asarray(v) for k, v in db["HISTORY"].columns.items()}
+    assert (h["H_DATA"][:n_hist] == np.asarray(_field_bytes(
+        jnp.asarray(h["H_C_ID"][:n_hist]), jnp.asarray(h["H_W_ID"][:n_hist]),
+        24))).all()
+    # masked lanes write zeros: every trash and pad row is as loaded
+    for t in ("HISTORY", "ORDER", "NEW-ORDER", "ORDER-LINE", "STOCK",
+              "CUSTOMER", "DISTRICT", "WAREHOUSE"):
+        cap = db[t].capacity
+        for cn, v in db[t].columns.items():
+            lo = cap * 10 if cn == S_DIST else cap
+            assert not np.asarray(v)[lo:].any(), (t, cn)
+
+
+def test_sim_full_row_validates_for_tpcc_with_the_full_schema_only():
+    ok = tpcc_cfg(tpcc_full_schema=True, sim_full_row=True).validate()
+    assert ok.sim_full_row and get_workload(ok).full_row
+    with pytest.raises(ValueError, match="tpcc_full_schema"):
+        tpcc_cfg(sim_full_row=True).validate()
+    with pytest.raises(ValueError, match="one device"):
+        tpcc_cfg(tpcc_full_schema=True, sim_full_row=True,
+                 device_parts=2).validate()
+    with pytest.raises(ValueError, match="sim_full_row"):
+        tpcc_cfg(workload="PPS", sim_full_row=True).validate()
