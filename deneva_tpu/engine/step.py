@@ -39,7 +39,7 @@ from deneva_tpu.engine.epoch import (access_batch, count_verdict,
                                      observe_audit, plan_owner, run_levels)
 from deneva_tpu.engine.pool import PoolState, TxnPool
 from deneva_tpu.ops import forwarding_applies
-from deneva_tpu.workloads.base import EXEC_COUNTERS
+from deneva_tpu.workloads.base import APPEND_COUNTERS, EXEC_COUNTERS
 
 LAT_BUCKETS = 64
 RETRY_BUCKETS = 8      # per-txn restart/wait counts at commit (clipped)
@@ -62,14 +62,19 @@ jax.tree_util.register_dataclass(
 
 
 def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
-                      level_passes: bool = False) -> dict:
+                      level_passes: bool = False,
+                      append_lanes: bool = False) -> dict:
     """``level_passes``: add ``level_pass_cnt``, which
     `engine/epoch.run_levels` counts where it finds it — asked for by
     the server of a chained backend alone, so every other program's
-    stats pytree (and with it its compiled text) is what it was."""
+    stats pytree (and with it its compiled text) is what it was.
+    ``append_lanes``: likewise `workloads/base.APPEND_COUNTERS`, which
+    `storage/table.DeviceTable.append` counts — asked for by the server
+    of a workload with ring tables."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z()} if level_passes else {}),
+        **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the
         # contention-adaptive router's input).  Always present so the

@@ -53,7 +53,7 @@ from deneva_tpu.runtime.telemetry import (ST_ADMIT, ST_BATCH, ST_HOLD,
 from deneva_tpu.runtime.native import NativeTransport
 from deneva_tpu.runtime.stages import StageClock, span as stage_span
 from deneva_tpu.stats import Stats
-from deneva_tpu.workloads.base import EXEC_COUNTERS
+from deneva_tpu.workloads.base import APPEND_COUNTERS, EXEC_COUNTERS
 
 _TAG_MASK = np.int64((1 << 40) - 1)
 
@@ -191,9 +191,14 @@ class ServerNode:
         self._counts_levels = (
             self.be.chained and not forwarding_applies(self.be, self.wl)
             and not self.vote_mode and cfg.device_parts == 1)
+        # a workload with ring tables appends to them on one device:
+        # there `DeviceTable.append` counts how its lanes were written
+        # (`workloads/base.APPEND_COUNTERS`)
         self.dev_stats = init_device_stats(
             len(getattr(self.wl, "txn_type_names", ("txn",))),
-            level_passes=self._counts_levels)
+            level_passes=self._counts_levels,
+            append_lanes=cfg.device_parts == 1 and any(
+                getattr(t, "ring", False) for t in self.db.values()))
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -2956,7 +2961,9 @@ class ServerNode:
                   "defer_cnt", "write_cnt"):
             st.set(k, float(final[k] - measured[k]))
         # the executors' lane counters: `<x>_lanes` -> `<x>_lane_cnt`
-        for dev in EXEC_COUNTERS:
+        # (the append counters where this server's stats carry them)
+        for dev in EXEC_COUNTERS + tuple(
+                k for k in APPEND_COUNTERS if k in final):
             if dev.endswith("_lanes"):
                 st.set(dev[:-1] + "_cnt", float(final[dev] - measured[dev]))
         if self._counts_levels:

@@ -55,6 +55,16 @@ EXEC_COUNTERS = (
 )
 
 
+# What `storage/table.DeviceTable.append` counts where the ``stats`` dict
+# it is handed carries them: live lanes written through a window, and
+# lanes of calls that fell back to the scatter (more lanes than the table
+# has rows).  NOT part of ``EXEC_COUNTERS``: the server of a workload
+# with ring tables on one device asks for them
+# (`engine/step.init_device_stats(append_lanes=True)`), so every other
+# program's stats pytree is what it was.
+APPEND_COUNTERS = ("append_window_lanes", "append_scatter_lanes")
+
+
 def partition_owned(key: jax.Array, n_parts: int, me: int) -> jax.Array:
     """bool mask: does this node own ``key`` under modulo striping
     (reference GET_NODE_ID, `system/global.h:294`)?"""

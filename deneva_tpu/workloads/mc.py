@@ -111,11 +111,11 @@ class McTableView:
         loc, _ = self._loc(slots)
         return self._with(self.local.scatter_add(loc, updates, mask=mask))
 
-    def append(self, rows, mask, anchor=None):
+    def append(self, rows, mask, anchor=None, stats=None):
         assert anchor is not None, \
             "multi-chip append needs the row ownership anchor"
         m = mask & (anchor.astype(jnp.int32) % self.d_parts == self.me)
-        local, slots = self.local.append(rows, m)
+        local, slots = self.local.append(rows, m, stats=stats)
         return self._with(local), slots
 
     def assemble(self) -> DeviceTable:

@@ -747,6 +747,9 @@ class TPCCWorkload:
             # — Payment's three accumulator rows and its HISTORY row,
             # NewOrder's D_NEXT_O_ID, ORDER and NEW-ORDER rows, and per
             # item the stock scatter, the stock adds and the ORDER-LINE
+            # (an append scatters nothing: its own counters are
+            # `workloads/base.APPEND_COUNTERS`; the formula is the one
+            # `exec.write_lanes_per_epoch` was accepted with)
             n = q.w_id.shape[0]
             stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + \
                 jnp.uint32((6 + bool(self.cfg.wh_update)) * n
@@ -781,7 +784,7 @@ class TPCCWorkload:
                      "C_PAYMENT_CNT": m.astype(jnp.int32)}, mask=m)
             m_h = m & self.wh_owned(q.w_id)
             db["HISTORY"], _ = db["HISTORY"].append(
-                _live_rows(hist_row, m_h), m_h, anchor=q.w_id)
+                hist_row, m_h, anchor=q.w_id, stats=stats)
         # W_YTD + D_YTD + 3 customer cols + HISTORY row per payment
         stats["write_cnt"] = stats["write_cnt"] + \
             (m.sum() * 6).astype(jnp.uint32)
@@ -906,7 +909,7 @@ class TPCCWorkload:
                 sk, _live_rows({"S_QUANTITY": new_q}, win), mask=win)
             db["STOCK"] = stock.scatter_add(sk, adds, mask=iv)
             db["ORDER"], oslots = db["ORDER"].append(
-                _live_rows(order_row, m_ins), m_ins, anchor=q.w_id)
+                order_row, m_ins, anchor=q.w_id, stats=stats)
             if "ORDER_IDX" in db:
                 # between-epoch batched merge into the dynamic ordered
                 # index (one fused sort per epoch instead of per-key tree
@@ -915,11 +918,10 @@ class TPCCWorkload:
                     self.order_index_key(q.w_id, q.d_id, o_id), oslots,
                     m_ins)
             db["NEW-ORDER"], _ = db["NEW-ORDER"].append(
-                _live_rows({"NO_O_ID": o_id, "NO_D_ID": q.d_id,
-                            "NO_W_ID": q.w_id}, m_ins), m_ins,
-                anchor=q.w_id)
+                {"NO_O_ID": o_id, "NO_D_ID": q.d_id, "NO_W_ID": q.w_id},
+                m_ins, anchor=q.w_id, stats=stats)
             db["ORDER-LINE"], _ = db["ORDER-LINE"].append(
-                _live_rows(ol_row, ol_m), ol_m, anchor=bcast(q.w_id))
+                ol_row, ol_m, anchor=bcast(q.w_id), stats=stats)
 
         stats["write_cnt"] = stats["write_cnt"] + \
             (iv.sum() + m.sum() * 2).astype(jnp.uint32)
@@ -944,7 +946,8 @@ def _string_filler(donate: bool):
 
 def _live_rows(rows: dict, mask: jax.Array) -> dict:
     """``rows`` with the lanes outside ``mask`` zeroed.  A masked lane of
-    a scatter or an append lands in the table's trash slot, where which
+    a scatter lands in the table's trash slot (an append writes no
+    masked lane: `storage/table.DeviceTable.append`), where which
     of the lanes is left standing is the compiler's choice: writing
     zeros there keeps the trash row at its load value whichever wins, so
     a table's bytes are a function of the committed stream alone (the

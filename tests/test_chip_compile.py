@@ -15,6 +15,7 @@ cache off (conftest.py) — a described-device executable cannot be read
 back.
 """
 
+import re
 import time
 
 import jax
@@ -133,7 +134,6 @@ def _column_copies(hlo: str, shape: str) -> list[str]:
     """Where the compiled program copies an array of ``shape``: "entry"
     for the entry computation, "inner" for any other (a loop body, a
     conditional's branch: once an EPOCH or more)."""
-    import re
     where = None
     copies = []
     for ln in hlo.splitlines():
@@ -145,9 +145,15 @@ def _column_copies(hlo: str, shape: str) -> list[str]:
     return copies
 
 
+def _hlo_shape(x) -> str:
+    """``x``'s shape as the compiled text writes it, without the layout."""
+    names = {"int32": "s32", "float32": "f32", "uint8": "u8",
+             "uint32": "u32"}
+    return f"{names[np.dtype(x.dtype).name]}[{','.join(map(str, x.shape))}]"
+
+
 def _row_gathers(hlo: str, width: int) -> set[int]:
     """Lanes of every row gather of ``width``-byte rows in the program."""
-    import re
     return {int(m.group(1)) for m in re.finditer(
         r"= u8\[(\d+)," + str(width) + r"\]\S* gather\(", hlo)}
 
@@ -323,7 +329,6 @@ def test_dp4_cell_group_names_what_the_mesh_adds(dp4_cell):
     — `phase_reduce.hlo_scopes` then reads its consumers'), the exchange
     blocks are cut at the batch's real width (10 accesses: 20,480 lanes
     a block), and the per-shard plan sort is `ep.plan`'s."""
-    import re
     hlo = dp4_cell[3].as_text()
     seen = {}
     for ln in hlo.splitlines():
@@ -378,7 +383,6 @@ def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
     matmuls, 0.18 ms) — the U-vs-W conflict matrix is `ops.conflict.
     key_overlap`'s compare — nothing of an arena's size anywhere in the
     program, and it still fits the chip."""
-    import re
     cfg = _cell_cfg("ycsb_fullrow_occ.medium")
     b, k = cfg.epoch_batch, cfg.conflict_buckets
     assert (cfg.cc_alg, b, k, cfg.conflict_exact) == ("OCC", 1024, 8192,
@@ -429,9 +433,10 @@ def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
     assert (cfg.num_wh, cfg.sim_full_row, cfg.epoch_batch) == (128, True,
                                                                1024)
     group, state, feed = _group_program(cfg, monkeypatch)
-    # the server of a chained backend counts its level passes
+    # the server of a chained backend counts its level passes, and one
+    # whose workload has ring tables how their appends were written
     state["stats"] = jax.eval_shape(
-        lambda: init_device_stats(2, level_passes=True))
+        lambda: init_device_stats(2, level_passes=True, append_lanes=True))
     state, feed = _with_sharding((state, feed), one_chip)
     table = sum(x.size * x.dtype.itemsize
                 for x in jax.tree.leaves(state["db"]))
@@ -449,6 +454,23 @@ def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
                       ("CUSTOMER", "C_DATA")):
         c = state["db"][name].columns[col]
         assert not _column_copies(hlo, f"u8[{c.shape[0]},{c.shape[1]}]")
+    # the four rings are appended to through windows (PR 37): every ring
+    # column by `dynamic-update-slice` (one window at the cursor, one at
+    # row 0 for what wraps), none by a scatter, none copied
+    rings = ("HISTORY", "ORDER", "NEW-ORDER", "ORDER-LINE")
+    shapes = {_hlo_shape(c) for t in rings
+              for c in state["db"][t].columns.values()}
+    assert shapes == {"s32[31457344]", "f32[31457344]", "u8[31457344,24]",
+                      "s32[2097216]", "f32[2097216]", "u8[2097216,24]"}
+    n_cols = sum(len(state["db"][t].columns) for t in rings)
+    windows = 0
+    for shape in shapes:
+        assert not _column_copies(hlo, shape), shape
+        assert not re.search(
+            r"= " + re.escape(shape) + r"\S* scatter\(", hlo), shape
+        windows += len(re.findall(
+            r"= " + re.escape(shape) + r"\S* dynamic-update-slice\(", hlo))
+    assert windows == 2 * n_cols == 58
     # the loader: the numbers' program, then the widest string column
     wl = get_workload(cfg)
     built, secs = _compile(jax.jit(wl._build_db, out_shardings=one_chip))

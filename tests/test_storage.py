@@ -85,6 +85,115 @@ def test_device_table_append_prefix_sum_and_overflow():
     assert int(tab.row_cnt) == 4
 
 
+APPEND_SCHEMA = """\
+TABLE=RING
+\t8,int64_t,A
+\t8,double,B
+\t24,string,C
+\t10,string,D
+"""
+
+# (ring, capacity, lanes a call, live lanes of each call): which lanes are
+# live is drawn; the counts place the cursor
+APPEND_CASES = {
+    "ring_wrap_inside_one_call": (True, 40, 16, [16, 16, 11, 0, 7]),
+    "ring_call_ends_on_capacity": (True, 48, 16, [16, 9, 7, 16, 5, 16]),
+    "ring_second_wrap": (True, 24, 16, [16, 16, 16, 3, 16, 16]),
+    "ring_clamped_first_window": (True, 40, 16, [13, 13, 5, 16, 16, 1]),
+    "ring_lanes_equal_capacity": (True, 16, 16, [5, 16, 9, 16, 0, 2]),
+    "ring_random_masks": (True, 37, 8, [3, 8, 0, 5, 8, 8, 1, 7, 8, 6]),
+    "ring_empty_masks": (True, 40, 16, [0, 0]),
+    "ring_more_lanes_than_rows": (True, 8, 16, [3, 5, 0, 8, 2]),
+    "table_fills_and_drops": (False, 40, 16, [16, 7, 0, 16, 16, 4]),
+    "table_ends_on_capacity": (False, 32, 16, [16, 16, 16]),
+    "table_clamped_window": (False, 40, 16, [13, 13, 5, 16]),
+    "table_more_lanes_than_rows": (False, 8, 16, [3, 4, 6, 0]),
+}
+
+
+@pytest.mark.parametrize("case", APPEND_CASES)
+def test_device_table_append_matches_the_slot_model(case):
+    """`DeviceTable.append` against numpy's ``slots = (row_cnt + rank) %
+    capacity``: the windows (a wrap inside a call, a call that ends on
+    ``capacity``, a first window that `dynamic_update_slice` would clamp)
+    and the scatter kept for a call of more lanes than rows write the
+    same rows, return the same slots and cursor, leave the trash and pad
+    rows zero, and count their lanes where the stats carry the
+    counters."""
+    import jax
+    ring, cap, n, lives = APPEND_CASES[case]
+    tab = DeviceTable.create(parse_schema(APPEND_SCHEMA).table("RING"), cap,
+                             full_row=True, ring=ring)
+    assert tab.columns["C"].shape[1:] == (24,)
+    model = {c: np.zeros(v.shape, v.dtype) for c, v in tab.columns.items()}
+    rng = np.random.default_rng(len(case))
+    zero = jnp.zeros((), jnp.uint32)
+
+    @jax.jit
+    def step(tab, rows, mask):
+        stats = {"append_window_lanes": zero, "append_scatter_lanes": zero}
+        tab, slots = tab.append(rows, mask, stats=stats)
+        return tab, slots, stats
+
+    cnt = 0
+    for k in lives:
+        mask = np.zeros(n, bool)
+        mask[rng.choice(n, size=k, replace=False)] = True
+        rows = {"A": rng.integers(1, 1 << 30, n).astype(np.int32),
+                "B": rng.random(n).astype(np.float32) + 1,
+                "C": rng.integers(1, 256, (n, 24)).astype(np.uint8),
+                "D": rng.integers(1, 256, (n, 10)).astype(np.uint8)}
+        tab, slots, stats = step(tab, rows, mask)
+        want = cnt + np.cumsum(mask) - mask
+        if ring:
+            want = np.where(mask, want % cap, cap)
+            cnt += k
+        else:
+            want = np.where(mask & (want < cap), want, cap)
+            cnt = min(cnt + k, cap)
+        np.testing.assert_array_equal(slots, want)
+        assert int(tab.row_cnt) == cnt
+        written = want != cap
+        for c in model:
+            model[c][want[written]] = rows[c][written]
+            # every row, the trash slot and the pad rows among them
+            np.testing.assert_array_equal(np.asarray(tab.columns[c]),
+                                          model[c], err_msg=c)
+            assert not model[c][cap:].any()
+        window, scatter = (0, n) if n > cap else (int(written.sum()), 0)
+        assert (int(stats["append_window_lanes"]),
+                int(stats["append_scatter_lanes"])) == (window, scatter)
+    # a dict without the counters is left alone
+    stats = {}
+    tab.append(rows, mask, stats=stats)
+    assert stats == {}
+
+
+def test_compact_live_keeps_lane_order_for_every_mask_of_twelve_lanes():
+    """`storage.table._compact_live` — the shifts by one bit of the
+    move a round — on all 4,096 masks of 12 lanes at once: the live
+    lanes arrive at the front in lane order, none lost to another on
+    the way, whatever the widths of the columns beside them."""
+    import jax
+    from deneva_tpu.storage.table import _compact_live
+    n = 12
+    masks = (np.arange(1 << n)[:, None] >> np.arange(n)) & 1
+    rows = {"A": jnp.arange(1, n + 1, dtype=jnp.int32),
+            "B": jnp.arange(1, n + 1, dtype=jnp.float32) / 7,
+            "C": (jnp.arange(n * 10).reshape(n, 10) % 251 + 1
+                  ).astype(jnp.uint8)}
+    out = jax.vmap(lambda m: _compact_live(rows, m))(
+        jnp.asarray(masks, jnp.int32))
+    for m, ids in zip(masks, np.asarray(out["A"])):     # every mask
+        live = np.flatnonzero(m) + 1
+        assert (ids[:len(live)] == live).all()
+    for c, v in rows.items():       # every column, every 37th mask
+        v, got = np.asarray(v), np.asarray(out[c])
+        assert got.dtype == v.dtype and got.shape == (1 << n,) + v.shape
+        for m, g in zip(masks[::37], got[::37]):
+            np.testing.assert_array_equal(g[:m.sum()], v[m.astype(bool)])
+
+
 def test_dense_index():
     idx = DenseIndex(base=100, stride=1, size=50, miss_slot=999)
     out = idx.lookup(jnp.array([100, 149, 150, 99, 7]))

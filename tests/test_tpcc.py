@@ -409,3 +409,53 @@ def test_sim_full_row_validates_for_tpcc_with_the_full_schema_only():
                  device_parts=2).validate()
     with pytest.raises(ValueError, match="sim_full_row"):
         tpcc_cfg(workload="PPS", sim_full_row=True).validate()
+
+
+def test_served_rings_wrap_and_the_serial_reference_follows(tmp_path,
+                                                            monkeypatch):
+    """The benchmark's toy TPC-C launch with rings of 256 orders (epochs
+    of 128 lanes: every append takes the windows of
+    `storage/table.DeviceTable.append`): HISTORY, ORDER, NEW-ORDER and
+    ORDER-LINE each wrap at least once, inside a call where the cursor
+    falls so, and `benchmark/references/tpcc_serial.py` — numpy, a slot
+    rule of its own — reproduces every leaf.  The benchmark's timed
+    launch wraps its 2^21-order rings where its check, which reads the
+    verify launch's first 0.75 s, does not look."""
+    import importlib.util
+    import os
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+
+    def script(rel):
+        spec = importlib.util.spec_from_file_location(
+            "wrap_" + os.path.basename(rel)[:-3], os.path.join(bench, rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    run, ref = script("run.py"), script("references/tpcc_serial.py")
+    cap = 256
+    cell = run.load_cell("tpcc_fullschema_tpubatch.mixed")
+    cell["config_file"]["fields"].update(
+        num_wh=4, cust_per_dist=64, max_items=128, epoch_batch=128,
+        pipeline_epochs=4, max_txn_in_flight=4096, client_batch_size=128,
+        insert_table_cap=cap)
+    cell["traffic_file"].update(warmup_secs=0.5, ring_txns=1 << 13)
+    monkeypatch.setattr(run, "SERVER_PLATFORM", "cpu")
+    res, fields, log, _ = run.logged_launch(cell, 3_000_000_019,
+                                            str(tmp_path))
+    info = res["server"]["info"]
+    checks, notes = ref.verify(log, fields, info)
+    assert [n for n, v, lim in checks if v > lim] == [], (checks, notes)
+    sz = ref.Sizes(fields)
+    tab, _ = ref.replay(log, sz)
+    assert ref.digests(ref.columns(sz, tab)) == info["column_digests"]
+    assert len(tab.history) > cap and len(tab.orders) > cap
+    assert sum(len(ln) for ln in tab.lines) > cap * sz.ipt
+    s = res["server"]["summary"]
+    # every lane went through a window, and the window's rows are what
+    # its commits insert: 1 a Payment, 2 + its valid lines a NewOrder
+    # (write_cnt: 6 a Payment, 2 + its lines a NewOrder)
+    assert s["append_scatter_lane_cnt"] == 0
+    assert s["append_window_lane_cnt"] == \
+        s["write_cnt"] - 5 * s["tpcc_payment_commit_cnt"] > 0
