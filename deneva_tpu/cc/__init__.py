@@ -17,11 +17,11 @@ from typing import Any, Callable
 
 from deneva_tpu.config import CCAlg, Config
 from deneva_tpu.cc.base import (AUDIT_KEY, AccessBatch,  # noqa: F401
-                                Incidence, Verdict, audit_init,
+                                Incidence, Recon, Verdict, audit_init,
                                 audit_mutate_verdict, audit_observe,
                                 build_conflict_incidence, build_incidence,
                                 committed_write_frontier, conflict_density,
-                                gate_order_free)
+                                gate_order_free, stale_recon)
 from deneva_tpu.cc import maat as _maat
 from deneva_tpu.cc import occ as _occ
 from deneva_tpu.cc import timestamp as _tsmod

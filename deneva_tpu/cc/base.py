@@ -29,7 +29,8 @@ execution wavefront level:
   analogue of parking a txn on a row's waiter list and resuming it via
   `txn_table.restart_txn` (`system/txn_table.cpp:151-176`) — the
   reference's subtlest machinery (SURVEY §7 hard-part #1) reduced to a
-  mask.
+  mask.  Calvin's stale-reconnaissance restart
+  (`system/sequencer.cpp:88-115`) is a defer too: `stale_recon`.
 
 Verdict invariants (asserted in tests): commit/abort/defer are disjoint,
 cover ``active``, and the committed set is serializable — for level-0
@@ -49,7 +50,19 @@ from dataclasses import dataclass
 import jax
 
 from deneva_tpu.ops import (access_incidence, bucket_hash, combine_key,
-                            key_overlap)
+                            earlier_edges, key_overlap)
+
+
+@dataclass(frozen=True)
+class Recon:
+    """A workload's mark on its plan: the access columns whose READ rows
+    a lane's other keys were derived from (PPS: the mapping rows of a
+    walk, whose part keys fill the lane's remaining accesses), and the
+    columns in which a WRITE of such a row can sit (None: any).  Static,
+    so `stale_recon` compares those columns alone."""
+
+    reads: tuple[int, ...]
+    writes: tuple[int, ...] | None = None
 
 
 @dataclass
@@ -78,6 +91,9 @@ class AccessBatch:
     # T/O family consumes it directly for its cross-epoch watermark
     # rules; the incidence builder consumes it for the ordered views.
     order_free: jax.Array | None = None
+    # the plan's reconnaissance mark (None: no key of the plan came out
+    # of a row the batch can write, and nothing below looks)
+    recon: Recon | None = None
 
     @property
     def shape(self) -> tuple[int, int]:
@@ -88,7 +104,7 @@ jax.tree_util.register_dataclass(
     AccessBatch,
     data_fields=["table_ids", "keys", "is_read", "is_write", "valid",
                  "ts", "rank", "active", "ro_hint", "order_free"],
-    meta_fields=[],
+    meta_fields=["recon"],
 )
 
 
@@ -156,6 +172,34 @@ class Incidence:
         matrix from, a pairwise compare of the exact combined keys
         (`ops.conflict.key_overlap`)."""
         return key_overlap(self.ident, self.mask(a), self.mask(b))
+
+
+def stale_recon(batch: AccessBatch) -> jax.Array:
+    """bool[B]: the active lanes whose reconnaissance is STALE in this
+    epoch — an earlier-serialised active lane of the epoch writes a row
+    that the lane's other keys were derived from (`Recon`).
+
+    The plan resolved those keys against the epoch's snapshot.  A backend
+    that executes the reader after the writer inside the epoch (chained
+    levels: CALVIN, TPU_BATCH, DGCC) would run it on keys the writer has
+    made obsolete, so `engine/epoch.epoch_core` — the one place every
+    verdict passes — takes such a lane out of the batch BEFORE
+    validation: it defers whole (no abort), contributes no edge to the
+    epoch's levels, keeps its rank, and is planned again from the
+    snapshot of the epoch that readmits it, where that writer's row is
+    as written.  This is Calvin's restart of a transaction whose
+    reconnaissance an UpdateProductPart overtook
+    (`system/sequencer.cpp:88-115`), as a deterministic rule of the
+    batch alone.  A backend that executes every committed lane on the
+    snapshot (the sweeps) already loses such a reader to its own
+    U-vs-W test and is left alone.  A writer that itself waits this
+    epoch still counts: the rule reads the admitted batch, not the
+    verdict, so a reference can restate it from the command log."""
+    r = batch.recon
+    ident = combine_key(batch.table_ids, batch.keys)
+    hit = key_overlap(ident, batch.valid & batch.is_read,
+                      batch.valid & batch.is_write, r.reads, r.writes)
+    return earlier_edges(hit, batch.rank, batch.active).any(axis=1)
 
 
 def gate_order_free(cfg, be, order_free: jax.Array | None

@@ -1071,17 +1071,17 @@ class Config:
                    "order keys span epoch_batch^2 and must fit int32 "
                    "(cc/maat.py closure branch)")
         if self.sim_full_row:
-            _check(self.workload == WorkloadKind.YCSB
-                   or (self.workload == WorkloadKind.TPCC
-                       and self.tpcc_full_schema),
+            _check(self.workload != WorkloadKind.TPCC
+                   or self.tpcc_full_schema,
                    "sim_full_row materializes string bytes: YCSB's "
-                   "payload fields, or TPCC's full schema "
-                   "(tpcc_full_schema=true: the short schema and PPS "
-                   "have numeric columns only)")
+                   "payload fields, PPS's ten strings a row, or TPCC's "
+                   "full schema (tpcc_full_schema=true: the short "
+                   "schema has numeric columns only)")
             _check(self.workload == WorkloadKind.YCSB
                    or self.device_parts == 1,
-                   "TPCC full-width rows are loaded on one device "
-                   "(the column-at-a-time loader builds no mesh layout)")
+                   "TPCC and PPS full-width rows are loaded on one "
+                   "device (their loaders build no mesh layout of a "
+                   "string column)")
         if self.workload == WorkloadKind.YCSB:
             _check(self.max_accesses >= self.req_per_query,
                    "max_accesses must cover req_per_query")
@@ -1487,6 +1487,12 @@ class Config:
             _check(not self.audit_mutate,
                    "ctrl does not compose with audit_mutate (the "
                    "seeded fault targets the static OCC path)")
+            _check(self.workload != WorkloadKind.PPS,
+                   "ctrl does not compose with PPS: the router's mixed "
+                   "branch hands each backend its own sub-batch, and "
+                   "the stale-reconnaissance rule (cc/base.stale_recon) "
+                   "needs every mapping write of the epoch in the batch "
+                   "it reads")
             _check(not self.escrow_order_free,
                    "ctrl does not compose with escrow ordering "
                    "exemptions yet (the router's cross-backend batch "

@@ -21,9 +21,10 @@ access batch, and on the forwarding path the plan sort of
 `forward_verdict`, which validates nothing), `ep.validate` (incidence +
 the backend's sweep: sweep backends only), `ep.read` / `ep.write`
 (inside the workload's executor, where the gather and the scatter are),
-`ep.levels`, `ep.repair`, `ep.stats` (counters); the group program adds
-`ep.decode` and `grp.pack`.  Nested scopes read innermost-first: a
-gather under `ep.levels/ep.read` is a read.
+`ep.levels`, `ep.repair`, `ep.stats` (counters), `ep.recon` (where a
+plan marks reconnaissance: its mapping gather and the stale test); the
+group program adds `ep.decode` and `grp.pack`.  Nested scopes read
+innermost-first: a gather under `ep.levels/ep.read` is a read.
 """
 
 from __future__ import annotations
@@ -37,7 +38,8 @@ import jax.numpy as jnp
 
 from deneva_tpu.cc import (AUDIT_KEY, AccessBatch, audit_mutate_verdict,
                            audit_observe, build_conflict_incidence,
-                           conflict_density, gate_order_free, get_backend)
+                           conflict_density, gate_order_free, get_backend,
+                           stale_recon)
 from deneva_tpu.cc.depgraph import witness_count
 from deneva_tpu.config import CCAlg, Config, Mode
 from deneva_tpu.ops import (forward_verdict, forwarding_applies,
@@ -66,7 +68,8 @@ def access_batch(cfg, be, planned, *, ts, rank, active, **over):
         table_ids=planned["table_ids"], keys=planned["keys"],
         is_read=planned["is_read"], is_write=planned["is_write"],
         valid=planned["valid"], ts=ts, rank=rank, active=active,
-        order_free=gate_order_free(cfg, be, planned.get("order_free")))
+        order_free=gate_order_free(cfg, be, planned.get("order_free")),
+        recon=planned.get("recon"))
     fields.update(over)
     return AccessBatch(**fields)
 
@@ -218,6 +221,14 @@ def epoch_core(cfg: Config, wl, be, db, cc_state, stats, queries, batch, *,
         verdict, cc_state = get_backend("NOCC").validate(
             cfg, cc_state, batch, None)
     else:
+        stale = None
+        if be.chained and batch.recon is not None:
+            # stale reconnaissance (`cc/base.stale_recon`): out of the
+            # batch before any edge is drawn, deferred whole below
+            with jax.named_scope("ep.recon"):
+                stale = stale_recon(batch)
+                batch = dataclasses.replace(batch,
+                                            active=batch.active & ~stale)
         with jax.named_scope("ep.validate"):
             # (None for DGCC: an exact-key lane graph, cc/depgraph, no
             # hashed incidence — its verdict is a pure replicated
@@ -228,6 +239,14 @@ def epoch_core(cfg: Config, wl, be, db, cc_state, stats, queries, batch, *,
             # assignment, where the lane graph is in hand
             kw = {"stats": stats} if be.alg == CCAlg.DGCC else {}
             verdict, cc_state = be.validate(cfg, cc_state, batch, inc, **kw)
+        if stale is not None:
+            verdict = dataclasses.replace(verdict,
+                                          defer=verdict.defer | stale)
+            if "recon_defer_cnt" in stats:
+                # (the served PPS program counts them:
+                # `engine/step.init_device_stats(recon_defers=True)`)
+                stats["recon_defer_cnt"] = stats["recon_defer_cnt"] + \
+                    stale.sum(dtype=jnp.uint32)
         if cfg.audit_mutate:
             # seeded edge-derivation fault (the audit plane's anti-inert
             # knob): flipped losers execute and ack like any commit — a

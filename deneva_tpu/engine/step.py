@@ -63,17 +63,22 @@ jax.tree_util.register_dataclass(
 
 def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
                       level_passes: bool = False,
-                      append_lanes: bool = False) -> dict:
+                      append_lanes: bool = False,
+                      recon_defers: bool = False) -> dict:
     """``level_passes``: add ``level_pass_cnt``, which
     `engine/epoch.run_levels` counts where it finds it — asked for by
     the server of a chained backend alone, so every other program's
     stats pytree (and with it its compiled text) is what it was.
     ``append_lanes``: likewise `workloads/base.APPEND_COUNTERS`, which
     `storage/table.DeviceTable.append` counts — asked for by the server
-    of a workload with ring tables."""
+    of a workload with ring tables.  ``recon_defers``: likewise
+    ``recon_defer_cnt``, the lanes `engine/epoch.epoch_core` defers on
+    stale reconnaissance — asked for by the server of a chained backend
+    whose workload marks reconnaissance (PPS)."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z()} if level_passes else {}),
+        **({"recon_defer_cnt": z()} if recon_defers else {}),
         **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the

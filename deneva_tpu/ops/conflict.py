@@ -82,12 +82,16 @@ _PAD_A = np.uint32((-2654435761) % (1 << 32))
 _PAD_B = np.uint32((-2 * 2654435761) % (1 << 32))
 
 
-def key_overlap(ident: jax.Array, mask_a: jax.Array,
-                mask_b: jax.Array) -> jax.Array:
+def key_overlap(ident: jax.Array, mask_a: jax.Array, mask_b: jax.Array,
+                cols_a=None, cols_b=None) -> jax.Array:
     """bool[B, B]: does txn i's A-set hold a key of txn j's B-set?
 
     ident: uint32[B, A] combined identities (`combine_key`) of the padded
-    access slots; mask_a / mask_b: bool[B, A], the slots of each side.
+    access slots; mask_a / mask_b: bool[B, A], the slots of each side;
+    ``cols_a`` / ``cols_b``: the access columns either side can sit in,
+    where the caller knows them (None: all A) — the compare is unrolled
+    over their product, so a side that lives in one column costs a
+    twenty-first of one that may live anywhere among 21.
     One compare and one OR per key pair on the VPU, A x A of them
     unrolled over a [B, B] accumulator — the chip's compiler makes ONE
     loop fusion of it with the caller's `earlier_edges` and cast folded
@@ -99,9 +103,9 @@ def key_overlap(ident: jax.Array, mask_a: jax.Array,
     kb = jnp.where(mask_b, ident, _PAD_B).T        # [A, B]: rows of lanes
     b, a = ident.shape
     m = jnp.zeros((b, b), bool)
-    for i in range(a):
+    for i in range(a) if cols_a is None else cols_a:
         col = ka[:, i, None]
-        for j in range(a):
+        for j in range(a) if cols_b is None else cols_b:
             m |= col == kb[j]
     return m
 

@@ -194,11 +194,16 @@ class ServerNode:
         # a workload with ring tables appends to them on one device:
         # there `DeviceTable.append` counts how its lanes were written
         # (`workloads/base.APPEND_COUNTERS`)
+        # ... and where its workload marks reconnaissance (PPS), the
+        # lanes deferred on a stale one (`cc/base.stale_recon`)
+        counts_recon = self._counts_levels \
+            and getattr(self.wl, "recon", None) is not None
         self.dev_stats = init_device_stats(
             len(getattr(self.wl, "txn_type_names", ("txn",))),
             level_passes=self._counts_levels,
             append_lanes=cfg.device_parts == 1 and any(
-                getattr(t, "ring", False) for t in self.db.values()))
+                getattr(t, "ring", False) for t in self.db.values()),
+            recon_defers=counts_recon)
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -2954,6 +2959,11 @@ class ServerNode:
             # leaves' own digests say WHICH table and column differs
             self.info["state_digest"], self.info["column_digests"] = \
                 state_digests(self.db)
+            # what the committed reads returned and how many lanes
+            # waited, whole run: a reference that replays the log holds
+            # both (a read-only transaction changes no digest)
+            self.info["read_checksum"] = int(final["read_checksum"])
+            self.info["run_defer_cnt"] = int(final["defer_cnt"])
         st = self.stats
         st.set("total_runtime", end - self._t_meas)
         st.set("epoch_cnt", float(epochs_run))
@@ -2966,14 +2976,19 @@ class ServerNode:
                 k for k in APPEND_COUNTERS if k in final):
             if dev.endswith("_lanes"):
                 st.set(dev[:-1] + "_cnt", float(final[dev] - measured[dev]))
-        if self._counts_levels:
-            st.set("level_pass_cnt", float(final["level_pass_cnt"]
-                                           - measured["level_pass_cnt"]))
+        for k in ("level_pass_cnt", "recon_defer_cnt"):
+            if k in final:      # (where this server's stats carry them)
+                st.set(k, float(final[k] - measured[k]))
+        by_type = final["commit_by_type"] - measured["commit_by_type"]
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):
                 key = f"{fam}_by_type"
                 st.set(f"{nm}_{fam}_cnt",
                        float(final[key][i] - measured[key][i]))
+        # a workload's own sums over its types (PPS: look-ups, orders,
+        # updates — what its roofline counts bytes for)
+        for nm, types in getattr(self.wl, "commit_groups", {}).items():
+            st.set(f"{nm}_commit_cnt", float(by_type[list(types)].sum()))
         # exact first-abort count, tracked host-side in the retry path
         st.set("unique_txn_abort_cnt",
                float(self._uniq_aborts - getattr(self, "_uniq_meas", 0)))

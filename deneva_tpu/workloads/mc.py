@@ -14,8 +14,10 @@ CHIPS, for any workload and any CC backend:
 * Tables live in the **owner-major stacked layout**
   (`storage.table.to_mc_layout`): block ``d`` of every column holds the
   rows whose ownership anchor ≡ d (mod D), so sharding dim 0 over the
-  mesh hands each chip exactly its partition; read-only tables (ITEM /
-  USES / SUPPLIES) are replicated like the reference's per-node copies.
+  mesh hands each chip exactly its partition; the tables every chip
+  needs whole (TPC-C's read-only ITEM; PPS's USES / SUPPLIES mappings,
+  which every chip WRITES alike from the replicated batch) are
+  replicated like the reference's per-node copies.
 * Execution runs the workload's **unmodified** ``execute`` body under
   `shard_map`: each chip passes global slots through a `McTableView`
   that translates them to block-local rows — non-owned lanes read 0 and

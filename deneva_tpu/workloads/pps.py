@@ -12,26 +12,44 @@ parts).  Eight transaction types mixed by ``perc_*`` config
       part rows (`pps_txn.cpp:729-808,893-960`)
   ORDERPRODUCT    — the mapping walk, then PART_AMOUNT -= 1 on each used
       part (`pps_txn.cpp:962-973` run_orderproduct_5)
-  UPDATEPRODUCTPART — write the product's part field
-      (`pps_txn.cpp:975-982` set_value(1, part_key))
+  UPDATEPRODUCTPART — rewrite one product-to-part mapping: USES.PART_KEY
+      of the product's first mapping row (`pps_txn.cpp:975-982`
+      set_value(1, part_key): field 1 of a USES row is PART_KEY)
   UPDATEPART      — PART_AMOUNT += 100 (`pps_txn.cpp:997-1006`)
 
 **The recon path** (SURVEY §7: the most exotic reference machinery): under
 Calvin the part keys behind a product are unknown until USES is read, so
 the sequencer pre-runs a reconnaissance txn and restarts the real txn with
 the keys filled in (`system/sequencer.cpp:88-115`, `:239-257`).  Here every
-transaction's RW-set is planned against the epoch snapshot: ``plan`` simply
+transaction's RW-set is planned against the epoch snapshot: ``plan``
 *gathers* the USES/SUPPLIES mapping rows on device and declares the
-resolved part rows in the same RW-set — reconnaissance is one gather,
-and the restart loop vanishes.  The mapping reads are declared as CC reads
-(exactly the rows the reference locks), so a concurrent writer of the
-mapping would conflict and serialize correctly; in PPS (as in the
-reference) the USES/SUPPLIES tables are never written after load, so the
-snapshot plan is always exact.
+resolved part rows in the same RW-set — reconnaissance is one gather.
+The mapping IS written (UPDATEPRODUCTPART), so reconnaissance can go
+STALE: a walk planned from the snapshot whose product an earlier
+transaction of the same epoch rewrites holds part keys that are no longer
+the product's.  ``plan`` marks the mapping reads as the accesses its
+other keys came out of (`cc.Recon`), and `cc/base.stale_recon` defers
+such a lane whole under the backends that would run it after the writer
+(CALVIN / TPU_BATCH); it is planned again from the snapshot of the epoch
+that readmits it — the sequencer's restart, as a rule of the batch.  The
+mapping reads and the mapping write are declared CC accesses (exactly the
+rows the reference locks), so a validating backend loses the stale reader
+to its own read-write test, and a LATER-ranked writer is ordered after
+the walk by the levels.
 
 TPU shape: all primary keys are dense -> free `DenseIndex`; the nonunique
 USES/SUPPLIES indexes (count-suffixed probes `pps_txn.cpp:755-768`) are
-dense [anchor*10 + j] layouts — the index walk is an affine gather.
+dense [anchor*10 + j] layouts — the index walk is an affine gather.  With
+``sim_full_row`` the three anchor tables hold their rows at the schema's
+widths: a row's ten 10-byte strings are ONE ``uint8[rows, 100]`` leaf
+(`FIELDS`; bytes 10 j .. 10 j + 9 = FIELD<j+1> by the byte law of (row,
+column), `workloads/ycsb._field_bytes`), so that a read of a row is one
+row gather and not ten, and a part row read by a walk or a GETPART folds
+its bytes into ``read_checksum`` beside PART_AMOUNT.
+
+Scopes: ``ep.recon`` (the mapping gather of ``plan``), ``ep.read`` (the
+walk at execution: mapping rows, part rows), ``ep.write`` (the part adds,
+the mapping scatter).
 """
 
 from __future__ import annotations
@@ -42,23 +60,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deneva_tpu.cc.base import Recon
 from deneva_tpu.config import Config
 from deneva_tpu.ops import last_writer
 from deneva_tpu.storage.catalog import parse_schema
 from deneva_tpu.workloads.base import partition_owned, partition_slot
-from deneva_tpu.storage.table import DeviceTable, fill_columns, to_mc_layout
+from deneva_tpu.storage.table import (DeviceTable, fill_columns, padded_rows,
+                                      to_mc_layout)
+from deneva_tpu.workloads.ycsb import _field_bytes
 
 _FIELDS = "".join(f"\t10,string,FIELD{i}\n" for i in range(1, 11))
 PPS_SCHEMA = (
     "TABLE=PARTS\n\t8,int64_t,PART_KEY\n\t8,int64_t,PART_AMOUNT\n" + _FIELDS
-    + "TABLE=PRODUCTS\n\t8,int64_t,PRODUCT_KEY\n\t8,int64_t,PRODUCT_PART\n"
-    + _FIELDS
+    + "TABLE=PRODUCTS\n\t8,int64_t,PRODUCT_KEY\n" + _FIELDS
     + "TABLE=SUPPLIERS\n\t8,int64_t,SUPPLIER_KEY\n" + _FIELDS
     + "TABLE=USES\n\t8,int64_t,PRODUCT_KEY\n\t8,int64_t,PART_KEY\n"
     + "TABLE=SUPPLIES\n\t8,int64_t,SUPPLIER_KEY\n\t8,int64_t,PART_KEY\n")
 
 TID = {"PARTS": 20, "PRODUCTS": 21, "SUPPLIERS": 22, "USES": 23,
        "SUPPLIES": 24}
+# full-width rows: a row's ten strings, one leaf (the module docstring)
+FIELDS, N_FIELDS, FIELD_BYTES = "FIELDS", 10, 10
 
 (GETPART, GETPRODUCT, GETSUPPLIER, GETPARTBYPRODUCT, GETPARTBYSUPPLIER,
  ORDERPRODUCT, UPDATEPRODUCTPART, UPDATEPART) = range(8)
@@ -87,22 +109,36 @@ class PPSWorkload:
                       "pps_orderproduct", "pps_updateproductpart",
                       "pps_updatepart")
 
+    # `[summary]` sums over the types, as the server prints them
+    # (`<name>_commit_cnt`): the walks that read, the walk that orders,
+    # the mapping writer — what `pps_epoch_bytes` counts bytes for
+    commit_groups = {
+        "pps_lookup": (GETPARTBYPRODUCT, GETPARTBYSUPPLIER),
+        "pps_order": (ORDERPRODUCT,),
+        "pps_update": (UPDATEPRODUCTPART,)}
+
     def txn_type_of(self, q: PPSQuery) -> jax.Array:
         return q.txn_type
 
     def __init__(self, cfg: Config):
         self.cfg = cfg
         self.catalog = parse_schema(PPS_SCHEMA)
+        self.full_row = cfg.sim_full_row
         self.n_parts = cfg.pps_parts_cnt
         self.n_products = cfg.pps_products_cnt
         self.n_suppliers = cfg.pps_suppliers_cnt
         self.per = cfg.pps_parts_per        # MAX_PPS_PART_PER_PRODUCT (config.h:230)
+        # the mapping rows of a walk are where its part keys come from,
+        # and UPDATEPRODUCTPART's write of one sits in the first of
+        # those columns (`plan`)
+        self.recon = Recon(reads=tuple(range(1, 1 + self.per)), writes=(1,))
         # partitioned deployment: PARTS/PRODUCTS/SUPPLIERS stripe by
-        # key % part_cnt; the immutable USES/SUPPLIES mapping tables are
-        # replicated on every node (like TPCC's read-only ITEM), which is
-        # what lets on-device recon (`plan`) stay local — the reference
-        # instead ships recon results through the sequencer
-        # (`system/sequencer.cpp:88-115`)
+        # key % part_cnt; the USES/SUPPLIES mapping tables are
+        # replicated on every node, which is what lets on-device recon
+        # (`plan`) stay local — the reference instead ships recon
+        # results through the sequencer (`system/sequencer.cpp:88-115`).
+        # Every holder of a copy sees the whole merged batch and applies
+        # each committed mapping write to its own (`execute`)
         self.n_pt = max(cfg.part_cnt, 1)
         self.me = cfg.node_id if self.n_pt > 1 else 0
         for nm, n in (("pps_parts_cnt", self.n_parts),
@@ -134,28 +170,36 @@ class PPSWorkload:
     def part_slot(self, key):
         return self._slot(key, self.n_parts_loc)
 
-    def product_slot(self, key):
-        return self._slot(key, self.n_products_loc)
-
     # -- loader (pps_wl.cpp:71-111 threadInit*) -------------------------
     def load(self):
         db = {}
         p, me = self.n_pt, self.me
 
-        def fill(name, cap, cols):
-            t = DeviceTable.create(self.catalog.table(name), cap)
-            db[name] = fill_columns(t, cap, cols)
+        def fill(name, cap, cols, ids=None):
+            t = DeviceTable.create(self.catalog.table(name), cap,
+                                   full_row=self.full_row)
+            t = fill_columns(t, cap, cols)
+            if self.full_row and ids is not None:
+                # the ten string columns as ONE leaf of the row's bytes
+                tcols = {n: v for n, v in t.columns.items()
+                         if not n.startswith("FIELD")}
+                tcols[FIELDS] = jnp.zeros(
+                    (padded_rows(cap), N_FIELDS * FIELD_BYTES), jnp.uint8
+                ).at[:cap].set(row_bytes(jnp.asarray(ids)))
+                t = t._replace(columns=tcols)
+            db[name] = t
 
         p_ids = me + p * np.arange(self.n_parts_loc, dtype=np.int32)
         fill("PARTS", self.n_parts_loc,
              {"PART_KEY": p_ids,
-              "PART_AMOUNT": np.full(self.n_parts_loc, 10000, np.int32)})
+              "PART_AMOUNT": np.full(self.n_parts_loc, 10000, np.int32)},
+             p_ids)
         pr_ids = me + p * np.arange(self.n_products_loc, dtype=np.int32)
-        fill("PRODUCTS", self.n_products_loc,
-             {"PRODUCT_KEY": pr_ids,
-              "PRODUCT_PART": _map_part(pr_ids, 0, 0, self.n_parts)})
+        fill("PRODUCTS", self.n_products_loc, {"PRODUCT_KEY": pr_ids},
+             pr_ids)
         s_ids = me + p * np.arange(self.n_suppliers_loc, dtype=np.int32)
-        fill("SUPPLIERS", self.n_suppliers_loc, {"SUPPLIER_KEY": s_ids})
+        fill("SUPPLIERS", self.n_suppliers_loc, {"SUPPLIER_KEY": s_ids},
+             s_ids)
 
         # mapping tables: row (anchor*per + j) -> part (pps_wl.cpp uses
         # URand parts per anchor; here a deterministic hash map)
@@ -171,9 +215,10 @@ class PPSWorkload:
                                     self.n_parts)})
         D = self.cfg.device_parts
         if D > 1:
-            # anchor keys stripe across chips; the immutable USES/SUPPLIES
-            # mapping tables replicate (what keeps recon local, see class
-            # docstring), exactly like the multi-process deployment
+            # anchor keys stripe across chips; the USES/SUPPLIES mapping
+            # tables replicate (what keeps recon local, see above): every
+            # chip writes its copy alike, exactly like the multi-process
+            # deployment
             for name in ("PARTS", "PRODUCTS", "SUPPLIERS"):
                 db[name] = to_mc_layout(db[name], D)
             for name in ("USES", "SUPPLIES"):
@@ -217,6 +262,20 @@ class PPSWorkload:
                         product_key=scalars[:, 2],
                         supplier_key=scalars[:, 3])
 
+    def _walk_parts(self, db, q: PPSQuery, by_prod) -> jax.Array:
+        """int32[n, per]: the part keys a walk of each lane's product (or
+        supplier) resolves to in ``db``.  A mapping table is dense,
+        [anchor * per + j], so an anchor's keys are ONE row of ``per``
+        numbers: a row gather of n lanes a table (n x per single-key
+        gathers cost the chip 7 ns a lane, PERF.md section 6, PR 38)."""
+        def rows(name, anchor):
+            col = db[name].columns["PART_KEY"]
+            n_anchor = db[name].capacity // self.per
+            return jnp.take(col[:n_anchor * self.per].reshape(
+                n_anchor, self.per), anchor, axis=0)
+        return jnp.where(by_prod[:, None], rows("USES", q.product_key),
+                         rows("SUPPLIES", q.supplier_key))
+
     # -- RW-set planning with on-device recon ---------------------------
     def plan(self, db, q: PPSQuery) -> dict:
         n = q.txn_type.shape[0]
@@ -228,6 +287,7 @@ class PPSWorkload:
         anchor_is_supp = (t == GETSUPPLIER) | (t == GETPARTBYSUPPLIER)
         by_prod = ((t == GETPARTBYPRODUCT) | (t == ORDERPRODUCT))
         walks = by_prod | (t == GETPARTBYSUPPLIER)
+        remaps = t == UPDATEPRODUCTPART
 
         tables = jnp.zeros((n, A), jnp.int32)
         keys = jnp.zeros((n, A), jnp.int32)
@@ -245,11 +305,10 @@ class PPSWorkload:
         a_key = jnp.where(anchor_is_part, q.part_key,
                           jnp.where(anchor_is_supp, q.supplier_key,
                                     q.product_key))
-        a_write = (t == UPDATEPRODUCTPART) | (t == UPDATEPART)
         tables = tables.at[:, 0].set(a_tid)
         keys = keys.at[:, 0].set(a_key)
         is_read = is_read.at[:, 0].set(True)
-        is_write = is_write.at[:, 0].set(a_write)
+        is_write = is_write.at[:, 0].set(t == UPDATEPART)
         valid = valid.at[:, 0].set(True)
         owner = owner.at[:, 0].set(a_key % p_nodes)
         # UPDATEPART is a pure escrow add (PART_AMOUNT += 100, no read
@@ -257,24 +316,28 @@ class PPSWorkload:
         # READ stays ordered against every add (base.build_incidence)
         order_free = order_free.at[:, 0].set(t == UPDATEPART)
 
-        # accesses 1..per: USES/SUPPLIES mapping rows (reads);
-        # recon: gather the referenced part keys from the snapshot
+        # accesses 1..per: USES/SUPPLIES mapping rows — a walk reads all
+        # of its anchor's, UPDATEPRODUCTPART writes its product's first
+        # (blind: it reads nothing of the row).  recon: gather the
+        # referenced part keys from the snapshot
         lane = jnp.arange(per)
-        map_key = jnp.where(by_prod[:, None], q.product_key[:, None],
+        uses = by_prod | remaps
+        map_key = jnp.where(uses[:, None], q.product_key[:, None],
                             q.supplier_key[:, None]) * per + lane[None, :]
-        map_tid = jnp.where(by_prod, TID["USES"], TID["SUPPLIES"])
-        part_keys = jnp.where(
-            by_prod[:, None],
-            jnp.take(db["USES"].columns["PART_KEY"], map_key, axis=0),
-            jnp.take(db["SUPPLIES"].columns["PART_KEY"], map_key, axis=0))
+        map_tid = jnp.where(uses, TID["USES"], TID["SUPPLIES"])
+        with jax.named_scope("ep.recon"):
+            part_keys = self._walk_parts(db, q, by_prod)
         wmask = walks[:, None] & jnp.ones((n, per), bool)
+        mw = remaps[:, None] & (lane == 0)[None, :]
         tables = tables.at[:, 1:1 + per].set(map_tid[:, None])
         keys = keys.at[:, 1:1 + per].set(map_key)
         is_read = is_read.at[:, 1:1 + per].set(wmask)
-        valid = valid.at[:, 1:1 + per].set(wmask)
-        # USES/SUPPLIES replicate; their immutable reads are validated at
-        # the walk anchor's owner (one participant, never a conflict)
-        anchor = jnp.where(by_prod, q.product_key, q.supplier_key)
+        is_write = is_write.at[:, 1:1 + per].set(mw)
+        valid = valid.at[:, 1:1 + per].set(wmask | mw)
+        # USES/SUPPLIES replicate; their accesses are validated at the
+        # anchor's owner — the writer of a product's mapping row and
+        # every walk of that product meet at one participant
+        anchor = jnp.where(uses, q.product_key, q.supplier_key)
         owner = owner.at[:, 1:1 + per].set((anchor % p_nodes)[:, None])
 
         # accesses 1+per..1+2*per: resolved part rows
@@ -292,11 +355,11 @@ class PPSWorkload:
 
         return dict(table_ids=tables, keys=keys, is_read=is_read,
                     is_write=is_write, valid=valid, order_free=order_free,
-                    owner=owner)
+                    owner=owner, recon=self.recon)
 
     # -- execution ------------------------------------------------------
-    # UPDATE* txns rewrite mapping fields read in the same txn (recon),
-    # so the single-pass forwarding executor does not apply
+    # a walk's part keys come out of a row that UPDATEPRODUCTPART
+    # rewrites, so the single-pass forwarding executor does not apply
     blind_writes = False
 
     def execute(self, db, q: PPSQuery, mask: jax.Array, order: jax.Array,
@@ -305,52 +368,83 @@ class PPSWorkload:
         t = q.txn_type
         per = self.per
         n = t.shape[0]
+        by_prod = (t == GETPARTBYPRODUCT) | (t == ORDERPRODUCT)
+        lookup = mask & ((t == GETPARTBYPRODUCT) | (t == GETPARTBYSUPPLIER))
 
-        # reads feed the checksum (anchor row field); remote anchors read
-        # the trash row and stay masked out of this node's stat
-        anchor_amt = db["PARTS"].gather(self.part_slot(q.part_key),
-                                        ("PART_AMOUNT",))["PART_AMOUNT"]
-        stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
-            jnp.where(mask & (t == GETPART) & self._owned(q.part_key),
-                      anchor_amt, 0)
-        ).astype(jnp.uint32)
+        with jax.named_scope("ep.read"):
+            # the walk: the anchor's mapping rows as they stand now (a
+            # lane that runs here is no stale one: the rows are what
+            # `plan` read), then the part rows they name.  One gather
+            # of PARTS serves GETPART's anchor (lane 0 of a row of
+            # per + 1) and the walks' parts; the whole row is read
+            parts = self._walk_parts(db, q, by_prod)
+            pk = jnp.concatenate([q.part_key[:, None], parts], axis=1)
+            rows = db["PARTS"].gather(
+                self.part_slot(pk).reshape(-1),
+                ("PART_AMOUNT",) + ((FIELDS,) if self.full_row else ()))
+            val = rows["PART_AMOUNT"].astype(jnp.uint32)
+            if self.full_row:
+                val = val + rows[FIELDS].sum(axis=1, dtype=jnp.uint32)
+            # reads feed the checksum (committed lanes only); remote
+            # parts read the trash row and stay out of this node's stat
+            use = jnp.concatenate(
+                [(mask & (t == GETPART))[:, None],
+                 lookup[:, None] & jnp.ones((n, per), bool)], axis=1)
+            stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
+                jnp.where(use & self._owned(pk), val.reshape(n, per + 1), 0),
+                dtype=jnp.uint32)
 
-        # ORDERPRODUCT: PART_AMOUNT -= 1 on each part of the product
-        # (parts resolve via the replicated USES map; each node applies
-        # the decrements for the part rows it owns)
-        om = mask & (t == ORDERPRODUCT)
-        lane = jnp.arange(per)
-        ukey = q.product_key[:, None] * per + lane[None, :]
-        parts = jnp.take(db["USES"].columns["PART_KEY"], ukey, axis=0)
-        m2 = om[:, None] & jnp.ones((n, per), bool)
-        db["PARTS"] = db["PARTS"].scatter_add(
-            self.part_slot(parts).reshape(-1),
-            {"PART_AMOUNT": jnp.where(m2, -1, 0).reshape(-1)},
-            mask=m2.reshape(-1))
+        with jax.named_scope("ep.write"):
+            # ORDERPRODUCT: PART_AMOUNT -= 1 on each part of the product;
+            # UPDATEPART: PART_AMOUNT += 100 (run_updatepart_1) — escrow
+            # adds, one call (each node applies those of the rows it owns)
+            om = mask & (t == ORDERPRODUCT)
+            um = mask & (t == UPDATEPART)
+            live = jnp.concatenate(
+                [um[:, None], om[:, None] & jnp.ones((n, per), bool)],
+                axis=1)
+            delta = jnp.where(live, jnp.where(jnp.arange(per + 1) == 0,
+                                              100, -1), 0)
+            db["PARTS"] = db["PARTS"].scatter_add(
+                self.part_slot(pk).reshape(-1),
+                {"PART_AMOUNT": delta.reshape(-1)}, mask=live.reshape(-1))
 
-        # UPDATEPART: PART_AMOUNT += 100 (run_updatepart_1)
-        um = mask & (t == UPDATEPART)
-        db["PARTS"] = db["PARTS"].scatter_add(
-            self.part_slot(q.part_key),
-            {"PART_AMOUNT": jnp.where(um, 100, 0)}, mask=um)
-
-        # UPDATEPRODUCTPART: product's part field = part_key
-        # (run_updateproductpart_1 set_value(1, part_key))
-        pm = mask & (t == UPDATEPRODUCTPART)
-        pslot = self.product_slot(q.product_key)
-        if level_exec:
-            # chained sub-round: committed set is write-conflict-free,
-            # so each product has at most one writer in this call
-            win = pm
-        else:
-            win = last_writer(jnp.where(pm, pslot, db["PRODUCTS"].capacity),
-                              order, pm, db["PRODUCTS"].capacity)
-        db["PRODUCTS"] = db["PRODUCTS"].scatter(
-            pslot, {"PRODUCT_PART": q.part_key}, mask=win)
+            # UPDATEPRODUCTPART: the product's first mapping row names
+            # another part (run_updateproductpart_1 set_value(1,
+            # part_key)).  USES is replicated: every holder of a copy
+            # applies the batch's writes to its own, whoever owns the
+            # product.  A masked lane writes zeros: it lands in the
+            # trash row, where which lane is left standing is the
+            # compiler's choice (`workloads/tpcc._live_rows`)
+            pm = mask & (t == UPDATEPRODUCTPART)
+            urow = q.product_key * per
+            cap = db["USES"].capacity
+            if level_exec:
+                # chained sub-round: committed set is write-conflict-free,
+                # so each mapping row has at most one writer in this call
+                win = pm
+            else:
+                win = last_writer(jnp.where(pm, urow, cap), order, pm, cap)
+            db["USES"] = db["USES"].scatter(
+                urow, {"PART_KEY": jnp.where(win, q.part_key, 0)}, mask=win)
 
         stats["write_cnt"] = stats["write_cnt"] + (
             (om.sum() * per) + um.sum() + pm.sum()).astype(jnp.uint32)
+        # lanes handed to a gather / to a scatter or a scatter_add, by
+        # call: a pass hands each the whole batch whatever its mask
+        stats["read_gather_lanes"] = stats["read_gather_lanes"] + \
+            jnp.uint32(n * (per + 3))
+        stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + \
+            jnp.uint32(n * (per + 2))
         return db
+
+
+def row_bytes(ids: jax.Array) -> jax.Array:
+    """uint8[len(ids), 100]: the ten strings of rows ``ids`` — string
+    column j + 1 of a row holds ``_field_bytes(row, j + 1, 10)``."""
+    col = jnp.arange(1, N_FIELDS + 1, dtype=jnp.uint32)
+    return _field_bytes(ids.astype(jnp.uint32)[:, None], col[None, :],
+                        FIELD_BYTES).reshape(ids.shape[0], -1)
 
 
 def _map_part(anchor, j, salt, n_parts) -> np.ndarray:

@@ -484,3 +484,51 @@ def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
     _report("tpcc_fill_s_dist", fill, secs)
     assert m.alias_size_in_bytes >= cells.size and \
         m.temp_size_in_bytes < 1e9
+
+
+# ---- the PPS cell (PR 38): pps_fullrow_tpubatch.lookup_order_update ------
+
+CELL_PPS = "pps_fullrow_tpubatch.lookup_order_update"
+
+
+def test_pps_group_program_compiles_for_v5e(one_chip, monkeypatch):
+    """The PPS cell's group program at its timed launch's shapes (epochs
+    of 1,024 lanes over 21 accesses, C=8, the five tables at the
+    schema's row widths: 1.46 MB): the chip's compiler accepts it; the
+    only scatters are the part adds and the mapping write (both into a
+    `s32[10048]` column: nothing scatters into a table's string bytes
+    or copies them inside an epoch); a pass gathers whole part rows in ONE row gather of
+    `u8[11264, 100]`; and the stale test is one more `[1024, 1024]`
+    compare beside `validate_calvin`'s."""
+    from deneva_tpu.engine.step import init_device_stats
+    from deneva_tpu.workloads.pps import FIELDS
+    cfg = _cell_cfg(CELL_PPS)
+    assert (cfg.pps_parts_cnt, cfg.sim_full_row, cfg.epoch_batch,
+            cfg.max_accesses) == (10000, True, 1024, 21)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    # the server of a chained backend counts its level passes, and one
+    # whose workload marks reconnaissance the lanes deferred on a stale one
+    state["stats"] = jax.eval_shape(
+        lambda: init_device_stats(8, level_passes=True, recon_defers=True))
+    state, feed = _with_sharding((state, feed), one_chip)
+    table = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"]))
+    assert 1.4e6 < table < 1.5e6
+    compiled, secs = _compile(group, state["db"], state["cc_state"],
+                              state["stats"], *feed)
+    need = _report("pps_fullrow", compiled, secs)
+    assert need < 64e6
+    hlo = compiled.as_text()
+    amount = _hlo_shape(state["db"]["PARTS"].columns["PART_AMOUNT"])
+    assert amount == _hlo_shape(state["db"]["USES"].columns["PART_KEY"]) \
+        == "s32[10048]"
+    scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
+    assert scatters and all(f"= {amount}" in ln for ln in scatters)
+    strings = _hlo_shape(state["db"]["PARTS"].columns[FIELDS])
+    # (a relayout copy of the 1 MB of strings once a GROUP is the entry
+    # computation's, as the YCSB cells have it; none inside an epoch)
+    assert strings == "u8[10048,100]" \
+        and "inner" not in _column_copies(hlo, strings)
+    b, per = cfg.epoch_batch, cfg.pps_parts_per
+    assert _row_gathers(hlo, 100) == {b * (per + 1)}
+    assert len(re.findall(rf"pred\[{b},{b}\]\S* fusion\(", hlo)) >= 2

@@ -407,8 +407,14 @@ def test_sim_full_row_validates_for_tpcc_with_the_full_schema_only():
     with pytest.raises(ValueError, match="one device"):
         tpcc_cfg(tpcc_full_schema=True, sim_full_row=True,
                  device_parts=2).validate()
-    with pytest.raises(ValueError, match="sim_full_row"):
-        tpcc_cfg(workload="PPS", sim_full_row=True).validate()
+    # PPS holds full-width rows too, on one device (PR 38: this was the
+    # refusal of PPS, turned into its opposite)
+    pps = tpcc_cfg(workload="PPS", sim_full_row=True, max_accesses=21)
+    assert get_workload(pps.validate()).full_row
+    assert get_workload(pps).load()["PARTS"].columns["FIELDS"].shape[1] == 100
+    with pytest.raises(ValueError, match="one device"):
+        tpcc_cfg(workload="PPS", sim_full_row=True, max_accesses=21,
+                 device_parts=2).validate()
 
 
 def test_served_rings_wrap_and_the_serial_reference_follows(tmp_path,
