@@ -96,6 +96,25 @@ def count_verdict(stats: dict, wl, queries, commit, abort, defer) -> None:
         (onehot & abort[:, None]).sum(axis=0, dtype=jnp.uint32)
 
 
+# the widths a near-empty level pass runs at
+NARROW_WIDTHS = (32, 128)
+
+
+def level_widths(b: int) -> tuple[int, ...]:
+    """The static widths a level pass of a batch of ``b`` may run at,
+    narrowest first: `NARROW_WIDTHS`, then ``b`` — a function of the
+    batch alone, and one rung wherever the batch is no wider than the
+    widest of them (the toy launches' whole batch).  Why 32 beside 128
+    (`PERF.md` section 6, PR 39): a scatter into one of STOCK's 51 MB
+    columns costs the chip ~0.09 us a lane up to the ~0.2 ms a sweep of
+    the column takes, so TPC-C's passes 1-2 (~16 and 1 transactions x
+    15 lines) pay 0.70 ms for the four at 128 transactions and 0.15 at
+    32.  Not more rungs: each is an executor body to compile (TPC-C:
+    ~2.5 s) and a conditional on a narrow pass's way (TPC-C: ~26 us,
+    its carry is a hundred buffers)."""
+    return NARROW_WIDTHS + (b,) if b > NARROW_WIDTHS[-1] else (b,)
+
+
 def run_levels(cfg, wl, db, queries, exec_commit, verdict, stats,
                level_exec=True):
     """Chained sub-round execution to the DYNAMIC depth of this epoch:
@@ -105,40 +124,94 @@ def run_levels(cfg, wl, db, queries, exec_commit, verdict, stats,
     Level-l txns read state that includes all writes of levels < l (the
     deterministic lock-queue order).  A `lax.while_loop` runs exactly
     ``max committed level + 1`` passes instead of unrolling the full
-    ``exec_subrounds`` budget — at low contention most epochs execute 1-2
-    levels, so a generous budget (deep-chain admission) no longer costs
-    idle full-batch passes on shallow epochs.
+    ``exec_subrounds`` budget, and a near-empty pass costs its level's
+    LIVE transactions, not the batch: once an epoch the committed lanes
+    are ordered by (level, lane), stably, so level l is a contiguous
+    run of that order; a pass whose run fits a narrow rung of the
+    batch's `level_widths` gathers the queries and ``verdict.order`` at
+    the run's first lanes and calls the executor at the narrowest such
+    width, the lanes past the run masked (a masked lane writes what a
+    masked lane of a whole-batch pass writes: zeros into a trash row,
+    nothing into a ring, 0 into an add).  Lane order is kept inside a
+    level, so ring appends land in the same slots and every rank by
+    ``order`` is the same: the tables are those of the whole-batch
+    pass, leaf for leaf.  A fuller level runs on the batch as it stands
+    (no gather), and a batch of one rung traces to that pass alone.
+    The executors' lane counters count lanes HANDED to a call, so they
+    follow the width.
 
     ``level_exec=True`` (CALVIN/TPU_BATCH): each level's committed set
-    is write-conflict-free by construction (true conflicts are a subset
-    of the hashed over-approximation), so executors skip the
-    ``last_writer`` scatter-max tournament.  ``level_exec=False``
-    (DGCC): a wave may carry several writers of one key — rw anti-
-    dependencies and blind ww chains serialize by the in-wave order
-    tournament instead of extra waves, which is what keeps DGCC's
-    wavefront shallow at write-heavy contention.
+    is write-conflict-free by construction (`wavefront_levels` over the
+    exact-key conflict matrix), so executors skip the ``last_writer``
+    scatter-max tournament.  ``level_exec=False`` (DGCC): a wave may
+    carry several writers of one key — rw anti-dependencies and blind
+    ww chains serialize by the in-wave order tournament instead of
+    extra waves, which is what keeps DGCC's wavefront shallow at
+    write-heavy contention.
+
+    Where ``stats`` carries them (the served chained path:
+    `engine/step.init_device_stats(level_passes=True)`),
+    ``level_pass_cnt`` counts the passes and ``narrow_pass_cnt`` those
+    run under the batch's width.
     """
+    b = exec_commit.shape[0]
+    *narrow, full = level_widths(b)
+    assert full == b and all(w < b for w in narrow), (narrow, full)
     lv_max = jnp.max(jnp.where(exec_commit, verdict.level, 0))
 
-    def cond(carry):
-        lvl, _, _ = carry
-        return lvl <= lv_max
+    def whole(lvl, db, stats):
+        stats = dict(stats)
+        db = wl.execute(db, queries, exec_commit & (verdict.level == lvl),
+                        verdict.order, stats, level_exec=level_exec)
+        return db, stats
+
+    if narrow:
+        # the committed lanes by (level, lane); what did not commit
+        # sorts behind every level.  (`narrow[-1]` lanes of padding: a
+        # `dynamic_slice` that does not fit is moved, not cut)
+        lv = jnp.where(exec_commit, verdict.level, lv_max + 1)
+        by_level = jnp.pad(jnp.argsort(lv, stable=True).astype(jnp.int32),
+                           (0, narrow[-1]))
+
+    def front(w, off, n, db, stats):
+        stats = dict(stats)
+        lanes = jax.lax.dynamic_slice_in_dim(by_level, off, w)
+        rows = lambda a: jnp.take(a, lanes, axis=0, mode="clip")  # noqa: E731
+        db = wl.execute(db, jax.tree.map(rows, queries),
+                        jnp.arange(w, dtype=jnp.int32) < n,
+                        rows(verdict.order), stats, level_exec=level_exec)
+        if "narrow_pass_cnt" in stats:
+            stats["narrow_pass_cnt"] = stats["narrow_pass_cnt"] + \
+                jnp.uint32(1)
+        return db, stats
 
     def body(carry):
-        lvl, db, stats = carry
-        m = exec_commit & (verdict.level == lvl)
-        stats = dict(stats)
-        db = wl.execute(db, queries, m, verdict.order, stats,
-                        level_exec=level_exec)
+        lvl, off, db, stats = carry
+        if narrow:
+            # (a compare-and-sum: `jnp.bincount` is a scatter-add on the
+            # chip.)  Two-way `lax.cond`s, the whole-batch pass against
+            # the narrow ones and those among themselves, narrowest
+            # innermost: under a `lax.switch`, and in a nest that leans
+            # the other way, the chip's compiler copies whole columns
+            # (TPC-C's `OL_DIST_INFO`, 755 MB) inside a middle branch
+            n = (lv == lvl).sum(dtype=jnp.int32)
+            run = functools.partial(front, narrow[0], off, n)
+            for under, w in zip(narrow, narrow[1:]):
+                run = functools.partial(jax.lax.cond, n <= under, run,
+                                        functools.partial(front, w, off, n))
+            db, stats = jax.lax.cond(n <= narrow[-1], run,
+                                     functools.partial(whole, lvl), db, stats)
+            off = off + n
+        else:
+            db, stats = whole(lvl, db, stats)
         if "level_pass_cnt" in stats:
-            # passes of this loop (the served chained path counts them:
-            # `engine/step.init_device_stats(level_passes=True)`)
             stats["level_pass_cnt"] = stats["level_pass_cnt"] + \
                 jnp.uint32(1)
-        return lvl + 1, db, stats
+        return lvl + 1, off, db, stats
 
-    _, db, stats = jax.lax.while_loop(
-        cond, body, (jnp.zeros((), jnp.int32), db, stats))
+    z = jnp.zeros((), jnp.int32)
+    _, _, db, stats = jax.lax.while_loop(
+        lambda carry: carry[0] <= lv_max, body, (z, z, db, stats))
     return db, stats
 
 

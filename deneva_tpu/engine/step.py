@@ -65,8 +65,9 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
                       level_passes: bool = False,
                       append_lanes: bool = False,
                       recon_defers: bool = False) -> dict:
-    """``level_passes``: add ``level_pass_cnt``, which
-    `engine/epoch.run_levels` counts where it finds it — asked for by
+    """``level_passes``: add ``level_pass_cnt`` and ``narrow_pass_cnt``,
+    which `engine/epoch.run_levels` counts where it finds them (its
+    passes, and those run under the batch's width) — asked for by
     the server of a chained backend alone, so every other program's
     stats pytree (and with it its compiled text) is what it was.
     ``append_lanes``: likewise `workloads/base.APPEND_COUNTERS`, which
@@ -77,7 +78,8 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
     whose workload marks reconnaissance (PPS)."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
-        **({"level_pass_cnt": z()} if level_passes else {}),
+        **({"level_pass_cnt": z(), "narrow_pass_cnt": z()}
+           if level_passes else {}),
         **({"recon_defer_cnt": z()} if recon_defers else {}),
         **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
         # per-partition observed-conflict density (cc/base.

@@ -187,7 +187,7 @@ class ServerNode:
         self.cc_state = self.be.init_state(cfg)
         # `run_levels`' loop runs where a chained backend executes its
         # levels in the group program of one device: there its passes
-        # are counted (`level_pass_cnt`)
+        # are counted (`level_pass_cnt`, `narrow_pass_cnt`)
         self._counts_levels = (
             self.be.chained and not forwarding_applies(self.be, self.wl)
             and not self.vote_mode and cfg.device_parts == 1)
@@ -2976,7 +2976,7 @@ class ServerNode:
                 k for k in APPEND_COUNTERS if k in final):
             if dev.endswith("_lanes"):
                 st.set(dev[:-1] + "_cnt", float(final[dev] - measured[dev]))
-        for k in ("level_pass_cnt", "recon_defer_cnt"):
+        for k in ("level_pass_cnt", "narrow_pass_cnt", "recon_defer_cnt"):
             if k in final:      # (where this server's stats carry them)
                 st.set(k, float(final[k] - measured[k]))
         by_type = final["commit_by_type"] - measured["commit_by_type"]

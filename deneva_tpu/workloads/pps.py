@@ -431,7 +431,7 @@ class PPSWorkload:
         stats["write_cnt"] = stats["write_cnt"] + (
             (om.sum() * per) + um.sum() + pm.sum()).astype(jnp.uint32)
         # lanes handed to a gather / to a scatter or a scatter_add, by
-        # call: a pass hands each the whole batch whatever its mask
+        # call: a pass hands each all its lanes whatever its mask
         stats["read_gather_lanes"] = stats["read_gather_lanes"] + \
             jnp.uint32(n * (per + 3))
         stats["write_scatter_lanes"] = stats["write_scatter_lanes"] + \

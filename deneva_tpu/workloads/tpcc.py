@@ -743,7 +743,7 @@ class TPCCWorkload:
         db = self._exec_neworder(db, q, neworder, order, stats, level_exec)
         if "write_scatter_lanes" in stats:
             # lanes handed to a scatter, a scatter_add or an append, by
-            # call: a pass hands each the whole batch whatever its mask
+            # call: a pass hands each all its lanes whatever its mask
             # — Payment's three accumulator rows and its HISTORY row,
             # NewOrder's D_NEXT_O_ID, ORDER and NEW-ORDER rows, and per
             # item the stock scatter, the stock adds and the ORDER-LINE

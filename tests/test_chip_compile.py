@@ -158,6 +158,16 @@ def _row_gathers(hlo: str, width: int) -> set[int]:
         r"= u8\[(\d+)," + str(width) + r"\]\S* gather\(", hlo)}
 
 
+def _scatter_lanes(hlo: str, shape: str) -> list[int]:
+    """Lanes of every scatter into an array of ``shape``: the rows of
+    the scatter's index operand."""
+    rows = {m.group(1): int(m.group(2)) for m in re.finditer(
+        r"(%[\w.\-]+) = [a-z]\d+\[(\d+)(?:,\d+)*\]", hlo)}
+    return sorted(rows[m.group(1)] for m in re.finditer(
+        r"= " + re.escape(shape) + r"\S* scatter\(%[\w.\-]+, (%[\w.\-]+),",
+        hlo))
+
+
 @pytest.mark.parametrize("over", [
     dict(), dict(cc_alg="OCC", epoch_batch=1024, max_txn_in_flight=1 << 17,
                  client_batch_size=1024)], ids=["tpu_batch", "occ"])
@@ -425,7 +435,18 @@ def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
     nothing); the group program updates them in place with tens of MB
     of temporaries and copies none of the wide columns; the loader —
     one program for the numbers, one a string column in place — never
-    needs more than the tables and half a GB."""
+    needs more than the tables and half a GB.
+
+    Since PR 39 a level pass whose level fits 32 or 128 transactions
+    runs at that width (`engine/epoch.level_widths`): two more executor
+    bodies beside the whole-batch one, under two-way `lax.cond`s with
+    the narrowest innermost.  All three update the 8.6 GB in place,
+    append through windows and scatter into STOCK at their own lane
+    counts.  (Under a `lax.switch`, and in a nest that leans the other
+    way, the chip's compiler copied `OL_DIST_INFO`, `H_DATA` and a
+    STOCK column whole inside a middle branch: my compiles for v5e,
+    PR 39.)"""
+    from deneva_tpu.engine.epoch import level_widths
     from deneva_tpu.engine.step import init_device_stats
     from deneva_tpu.workloads import get_workload
     from deneva_tpu.workloads.tpcc import S_DIST, _string_filler
@@ -463,6 +484,8 @@ def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
     assert shapes == {"s32[31457344]", "f32[31457344]", "u8[31457344,24]",
                       "s32[2097216]", "f32[2097216]", "u8[2097216,24]"}
     n_cols = sum(len(state["db"][t].columns) for t in rings)
+    widths = level_widths(cfg.epoch_batch)
+    assert widths == (32, 128, cfg.epoch_batch)
     windows = 0
     for shape in shapes:
         assert not _column_copies(hlo, shape), shape
@@ -470,7 +493,21 @@ def test_tpcc_full_schema_deployment_fits_one_v5e(one_chip, monkeypatch):
             r"= " + re.escape(shape) + r"\S* scatter\(", hlo), shape
         windows += len(re.findall(
             r"= " + re.escape(shape) + r"\S* dynamic-update-slice\(", hlo))
-    assert windows == 2 * n_cols == 58
+    assert windows == 2 * n_cols * len(widths) == 58 * len(widths)
+    # no column of any table is copied whole by a plain copy (the
+    # parent's program had none either), and STOCK's four scatters are
+    # there once a width, at that width's lanes
+    for tab in state["db"].values():
+        for c in tab.columns.values():
+            if c.size * c.dtype.itemsize > 1 << 20:
+                assert not _column_copies(hlo, _hlo_shape(c)), _hlo_shape(c)
+    ipt = cfg.max_items_per_txn
+    assert _scatter_lanes(hlo, "s32[12800064]") == sorted(
+        4 * [w * ipt for w in widths])
+    # (the compiler's own asynchronous copies of a STOCK column, parts
+    # of its scatters: two in the parent's one body, no more now)
+    assert len(re.findall(r"= \(s32\[12800064\]\S*, s32\[12800064\]\S*, "
+                          r"\S+ copy-start\(", hlo)) <= 2
     # the loader: the numbers' program, then the widest string column
     wl = get_workload(cfg)
     built, secs = _compile(jax.jit(wl._build_db, out_shardings=one_chip))
@@ -529,6 +566,37 @@ def test_pps_group_program_compiles_for_v5e(one_chip, monkeypatch):
     # computation's, as the YCSB cells have it; none inside an epoch)
     assert strings == "u8[10048,100]" \
         and "inner" not in _column_copies(hlo, strings)
+    # (one such gather a width of `engine/epoch.level_widths`: PR 39)
+    from deneva_tpu.engine.epoch import level_widths
     b, per = cfg.epoch_batch, cfg.pps_parts_per
-    assert _row_gathers(hlo, 100) == {b * (per + 1)}
+    assert _row_gathers(hlo, 100) == {w * (per + 1)
+                                      for w in level_widths(b)}
     assert len(re.findall(rf"pred\[{b},{b}\]\S* fusion\(", hlo)) >= 2
+
+
+# ---- the three YCSB cells and `run_levels` (PR 39) ------------------------
+
+@pytest.mark.parametrize("cell", [
+    "ycsb_fullrow_tpubatch.hot", "ycsb_fullrow_occ.medium", CELL_DP4])
+def test_the_ycsb_cells_programs_never_reach_run_levels(cell, monkeypatch):
+    """The forwarding executor returns from `epoch_core` before the
+    chained levels and OCC is not chained: with `run_levels` made
+    unreachable the three cells' group programs still trace, and their
+    stats carry neither pass counter — what PR 39 changed is not in
+    them (their stripped v5e HLO is the parent's line for line: PERF.md
+    section 6)."""
+    import contextlib
+    from deneva_tpu.engine import epoch
+    from deneva_tpu.parallel import mesh as M
+
+    def unreachable(*a, **kw):
+        raise AssertionError("run_levels reached")
+    monkeypatch.setattr(epoch, "run_levels", unreachable)
+    cfg = _cell_cfg(cell)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    assert not {"level_pass_cnt", "narrow_pass_cnt"} & set(state["stats"])
+    with (M.use_mesh(M.make_mesh(cfg.device_parts))
+          if cfg.device_parts > 1 else contextlib.nullcontext()):
+        out = jax.eval_shape(group, state["db"], state["cc_state"],
+                             state["stats"], *feed)
+    assert jax.tree.structure(out[2]) == jax.tree.structure(state["stats"])
