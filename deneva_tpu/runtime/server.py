@@ -2908,6 +2908,10 @@ class ServerNode:
         stage_keys = clk.since(self._stage_meas)
         stage_keys["stage_wall_time"] = \
             time.monotonic() - self._t_meas if measured is not None else 0.0
+        # every interval the clock closed, reduced once: the window's
+        # pass walls and the run's longest intervals with their CPU, on
+        # the `[device]` line (`tools/stage_record.py` prints it)
+        self.info["stage_record"] = clk.record(self._stage_meas)
         epochs_run = epoch0 + C
         # final: release remaining group-committed acks, notify clients
         # and my replica, emit summary

@@ -1,7 +1,8 @@
 """The dispatch loop's stage clock (`runtime/stages.py`) and the epoch
 program's named phases: one recorder call per loop boundary, window
-sums that add up to the wall, the `[timeline]` / `[crit]` lines as they
-were, `srv.*` spans in a live profiler trace, and `jax.named_scope`s that
+sums that add up to the wall, the thread's CPU beside the wall and every
+interval kept (PR 40), the `[timeline]` / `[crit]` lines as they were,
+`srv.*` spans in a live profiler trace, and `jax.named_scope`s that
 change no verdict and no byte of the table."""
 
 import json
@@ -9,6 +10,7 @@ import os
 import re
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -21,29 +23,40 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture
 def clock(monkeypatch):
-    """A hand-driven `time.monotonic` for the recorder: t[0] is now."""
-    t = [100.0]
+    """Hand-driven clocks for the recorder: t[0] is now on the wall,
+    t[1] on the dispatch thread's CPU clock, t[2] on the process's."""
+    t = [100.0, 5.0, 9.0]
     monkeypatch.setattr(stages.time, "monotonic", lambda: t[0])
+    monkeypatch.setattr(stages.time, "thread_time", lambda: t[1])
+    monkeypatch.setattr(stages.time, "process_time", lambda: t[2])
     return t
 
 
-def _pass(clk, t, epoch0, secs):
-    """One dispatch pass spending ``secs[stage]`` in each stage."""
+def _pass(clk, t, epoch0, secs, cpu=None):
+    """One dispatch pass spending ``secs[stage]`` in each stage, of it
+    ``cpu[stage]`` = (on this thread's CPU, on the other threads')."""
+    def spend(s):
+        t[0] += secs.get(s, 0.0)
+        if cpu is not None:
+            mine, others = cpu.get(s, (0.0, 0.0))
+            t[1] += mine
+            t[2] += mine + others
+
     clk.begin_pass(epoch0, 4, queue_txns=10)
     for s in ("drain", "admit", "collect", "feed", "dispatch"):
-        t[0] += secs.get(s, 0.0)
+        spend(s)
         nxt = {"drain": "admit", "admit": "collect", "collect": "feed",
                "feed": "dispatch", "dispatch": "other"}[s]
         at = clk.enter(nxt)
         if nxt == "dispatch":
             t_disp = at
     clk.enter("retire_wait", epoch0)
-    t[0] += secs.get("retire_wait", 0.0)
+    spend("retire_wait")
     clk.enter("retire", epoch0)
-    t[0] += secs.get("retire", 0.0)
+    spend("retire")
     clk.enter("other")
     clk.retired(t_disp)
-    t[0] += secs.get("other", 0.0)
+    spend("other")
 
 
 def test_every_second_of_the_loop_belongs_to_one_stage(clock):
@@ -179,6 +192,204 @@ def test_the_spans_land_in_a_live_profiler_trace(tmp_path):
         ("srv.dispatch", 40), ("srv.retire_wait", 36), ("srv.retire", 36)])
 
 
+# ---- the thread's CPU beside the wall, and every interval kept (PR 40) ---
+
+SECS = dict(drain=0.01, admit=0.2, collect=0.03, feed=0.004, dispatch=0.05,
+            retire_wait=0.6, retire=0.1, other=0.006)
+# (this thread's CPU, the other threads') inside each stage: admit works,
+# retire is held up by another thread, the wait burns nothing
+CPU = dict(drain=(0.01, 0.0), admit=(0.19, 0.02), feed=(0.004, 0.0),
+           dispatch=(0.03, 0.01), retire=(0.02, 0.07), other=(0.006, 0.0),
+           retire_wait=(0.001, 0.3))
+
+
+def test_cpu_seconds_ride_the_same_boundaries_as_the_wall(clock):
+    clk = StageClock()
+    _pass(clk, clock, 0, SECS, CPU)               # warm-up
+    snap = clk.snapshot()
+    for g in (1, 2):
+        _pass(clk, clock, 4 * g, SECS, CPU)
+    clk.shift("dispatch", "collect", 0.02)        # moves wall only
+    clk.end()
+    win, whole = clk.since(snap), clk.since(None)
+    for s in STAGES:
+        mine = CPU.get(s, (0.0, 0.0))[0]
+        assert win[f"stage_{s}_cpu_time"] == pytest.approx(2 * mine)
+        assert whole[f"stage_{s}_cpu_time"] == pytest.approx(3 * mine)
+        assert win[f"stage_{s}_cpu_time"] <= 2 * SECS[s] + 1e-9
+    assert win["stage_dispatch_time"] == pytest.approx(2 * 0.05 - 0.02)
+    assert win["process_cpu_time"] == pytest.approx(
+        2 * sum(a + b for a, b in CPU.values()))
+    assert whole["process_cpu_time"] == pytest.approx(
+        3 * sum(a + b for a, b in CPU.values()))
+    # the accepted keys are all there, beside the nine new ones
+    assert {k for k in win if "cpu" in k} == {
+        f"stage_{s}_cpu_time" for s in STAGES} | {"process_cpu_time"}
+    assert set(win) - {k for k in win if "cpu" in k} == {
+        f"stage_{s}_time" for s in STAGES} | {
+        "stage_epoch_cnt", "queue_txn_mean", "pipeline_time_mean"}
+
+
+def test_a_busy_stage_reads_cpu_near_its_wall_and_a_sleeping_one_none():
+    """On the real clocks: a stage that spins is charged the CPU it
+    burnt, one that sleeps next to nothing, and no stage more CPU than
+    wall (the two clocks are read a fraction of a microsecond apart)."""
+    clk = StageClock()
+    clk.begin_pass(0, 4, 0)
+    clk.enter("admit")
+    c0 = time.thread_time()
+    while time.thread_time() - c0 < 0.05:
+        pass
+    clk.enter("retire_wait")
+    time.sleep(0.05)
+    clk.end()
+    got = clk.since(None)
+    assert got["stage_admit_cpu_time"] == pytest.approx(0.05, abs=0.005)
+    assert got["stage_retire_wait_time"] >= 0.05
+    assert got["stage_retire_wait_cpu_time"] < 0.01
+    for s in STAGES:
+        assert got[f"stage_{s}_cpu_time"] <= got[f"stage_{s}_time"] + 1e-4
+    assert got["process_cpu_time"] >= sum(
+        got[f"stage_{s}_cpu_time"] for s in STAGES) - 1e-4
+    by_stage = {r["stage"]: r for r in clk.record(None)["longest"]}
+    busy, wait = by_stage["admit"], by_stage["retire_wait"]
+    assert not busy["wait"] and busy["cpu_s"] == pytest.approx(0.05, abs=0.005)
+    assert wait["wait"] and wait["cpu_s"] < 0.01 <= 0.05 <= wait["wall_s"]
+
+
+def test_the_records_walls_add_up_to_the_loops_wall(clock):
+    clk = StageClock()
+    t0 = clock[0]
+    clock[0] += 0.5                   # set-up before the first pass: `other`
+    for g in range(3):
+        _pass(clk, clock, 4 * g, SECS, CPU)
+    clk.end()
+    rows = clk.rows()
+    assert len(rows) == clk.intervals == 1 + 3 * 9    # 9 boundaries a pass
+    assert rows[:, 3].sum() == pytest.approx(clock[0] - t0)
+    # back to back: an interval starts where the one before it ended
+    assert rows[0, 2] == t0
+    np.testing.assert_allclose(rows[1:, 2], rows[:-1, 2] + rows[:-1, 3])
+    got = clk.since(None)
+    for i, s in enumerate(STAGES):
+        mine = rows[rows[:, 0] == i]
+        assert mine[:, 3].sum() == pytest.approx(got[f"stage_{s}_time"])
+        assert mine[:, 4].sum() == pytest.approx(got[f"stage_{s}_cpu_time"])
+    # the sixth field is the process clock's last READING, taken at a
+    # pass's start (each pass here outlasts `PROCESS_EVERY_S`): it never
+    # falls, and a pass's intervals all carry its start's reading
+    assert (np.diff(rows[:, 5]) >= 0).all()
+    assert len(set(rows[1:10, 5])) == 1 and rows[10, 5] > rows[9, 5]
+    assert rows[10, 5] - rows[1, 5] == pytest.approx(
+        sum(a + b for a, b in CPU.values()))
+    rec = clk.record(None)
+    assert (rec["intervals"], rec["dropped"]) == (28, 0)
+    assert rec["t_end"] - rec["t_start"] == pytest.approx(clock[0] - t0)
+
+
+def test_the_process_clock_is_read_at_a_pass_start_ten_times_a_second(
+        clock, monkeypatch):
+    """It sums over the process's threads (tens of microseconds in a
+    server of hundreds): not at every boundary."""
+    reads = []
+    monkeypatch.setattr(stages.time, "process_time",
+                        lambda: reads.append(clock[0]) or clock[2])
+    clk = StageClock()
+    assert len(reads) == 1                        # the clock's first
+    for g in range(30):                           # passes of 0.03 s
+        _pass(clk, clock, 4 * g, dict(admit=0.01, retire=0.02),
+              dict(admit=(0.01, 0.0), retire=(0.01, 0.02)))
+    assert len(reads) == 1 + 7                    # every fourth pass
+    assert np.diff(reads)[1:] == pytest.approx(0.12)
+    clk.end()
+    rec = clk.record(None)
+    # an interval's others' CPU: over the four passes between readings
+    r = rec["longest"][5]
+    assert (r["stage"], r["span_s"], r["others_cpu_s"]) == (
+        "retire", pytest.approx(0.12), pytest.approx(4 * 0.02))
+    n = len(reads)
+    snap = clk.snapshot()
+    win = clk.since(snap)
+    assert len(reads) == n + 2 and win["process_cpu_time"] == 0
+
+
+def test_the_ring_keeps_the_newest_intervals_and_counts_the_dropped(
+        clock, monkeypatch):
+    assert stages.RING == 1 << 17
+    assert len(StageClock()._ring) == 6 * 8 * stages.RING   # preallocated
+    monkeypatch.setattr(stages, "RING", 64)
+    clk = StageClock()
+    for g in range(30):
+        _pass(clk, clock, 4 * g, dict(admit=0.001 * (g + 1)))
+    clk.end()
+    rows = clk.rows()
+    assert clk.intervals == 30 * 9 + 1 and len(rows) == 64
+    rec = clk.record(None)
+    assert (rec["intervals"], rec["dropped"]) == (271, 271 - 64)
+    # the newest: oldest first, back to back, up to the loop's end
+    np.testing.assert_allclose(rows[1:, 2], rows[:-1, 2] + rows[:-1, 3])
+    assert rows[-1, 2] + rows[-1, 3] == pytest.approx(clock[0])
+    # the longest admit of all is the last pass's, and it was kept
+    assert (rec["longest"][0]["stage"], rec["longest"][0]["group"]) == (
+        "admit", 4 * 29)
+    assert rec["longest"][0]["wall_s"] == pytest.approx(0.030)
+
+
+def test_longest_is_sorted_carries_the_spans_group_and_marks_the_waits(
+        clock):
+    clk = StageClock()
+    t0 = clock[0]
+    _pass(clk, clock, 0, SECS, CPU)               # warm-up
+    snap = clk.snapshot()
+    for g, admit in ((1, 0.2), (2, 0.9), (3, 0.3)):
+        clk.begin_pass(4 * g, 4, 0)
+        clk.enter("admit")
+        clock[0] += admit
+        clock[1] += 0.1                           # 0.1 s of it on the CPU
+        clock[2] += 0.1 + 0.75 * admit            # the rest: other threads
+        clk.enter("dispatch")
+        clock[0] += 0.01
+        # a retirement belongs to the group it retires, as its span does
+        clk.enter("retire_wait", 4 * (g - 1))
+        clock[0] += 0.5
+        clk.enter("retire", 4 * (g - 1))
+        clock[0] += 0.05
+        clk.enter("other")
+    clk.end()
+    rec = clk.record(snap)
+    assert rec["clock"] == "CLOCK_MONOTONIC" and rec["cpus"] >= 1
+    assert rec["t_start"] == t0 and rec["t_meas"] == snap["now"]
+    assert rec["t_end"] == clock[0]
+    for key in ("longest", "longest_work"):
+        walls = [r["wall_s"] for r in rec[key]]
+        assert walls == sorted(walls, reverse=True) and len(walls) <= 16
+        assert set(rec[key][0]) == {"stage", "group", "at_s", "wall_s",
+                                    "cpu_s", "others_cpu_s", "span_s",
+                                    "wait"}
+    # the other threads' CPU is read over the span between two readings
+    # of the process clock that enclose the interval: here its pass
+    top = rec["longest"][0]
+    assert top == dict(stage="admit", group=8, at_s=pytest.approx(
+        top["at_s"]), wall_s=0.9, cpu_s=0.1, others_cpu_s=0.675,
+        span_s=1.46, wait=False)
+    # the last pass is closed by no reading: nothing is claimed for it
+    last = [r for r in rec["longest"] if r["stage"] == "admit"
+            and r["group"] == 12][0]
+    assert last["others_cpu_s"] is None and last["span_s"] is None
+    assert clock[0] - t0 > top["at_s"] > 1.0      # since the clock's start
+    assert [(r["stage"], r["wait"]) for r in rec["longest"][1:5]] == [
+        ("retire_wait", True)] * 4
+    # the wait of the pass of group 12 carries the group it retired
+    assert {r["group"] for r in rec["longest"][1:5]} == {0, 4, 8}
+    assert not [r for r in rec["longest_work"] if r["wait"]]
+    assert [r["stage"] for r in rec["longest_work"][:3]] == ["admit"] * 3
+    assert len(rec["longest"]) == 16
+    # the window's passes, begin_pass to begin_pass: two whole ones
+    assert rec["pass_wall_s"] == dict(
+        p50=pytest.approx((0.76 + 1.46) / 2), p99=pytest.approx(
+            0.76 + 0.99 * 0.7), max=pytest.approx(1.46))
+
+
 # ---- a short served run --------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -219,6 +430,42 @@ def test_window_stage_seconds_add_up_to_the_window_wall(served):
     # the reference's two worker times are read off the same clock
     assert s["worker_process_time"] >= s["stage_retire_wait_time"]
     assert s["worker_idle_time"] >= 0
+
+
+def test_the_closing_lines_carry_the_cpu_keys_and_the_stage_record(served):
+    from deneva_tpu.stats import parse_summary
+    line = [ln for ln in served if ln.startswith("node 0 (server): ")][0]
+    s = parse_summary(line.split(": ", 1)[1])
+    # every accepted key of the clock, as it was
+    for k in [f"stage_{st}_time" for st in STAGES] + [
+            "stage_wall_time", "stage_epoch_cnt", "queue_txn_mean",
+            "pipeline_time_mean"]:
+        assert k in s
+    # the nine new ones: no stage is charged more CPU than wall, the
+    # thread's CPU is a part of the process's, the loop does work
+    for st in STAGES:
+        assert 0 <= s[f"stage_{st}_cpu_time"] <= s[f"stage_{st}_time"] + 1e-3
+    cpu = sum(s[f"stage_{st}_cpu_time"] for st in STAGES)
+    assert 0 < cpu <= s["process_cpu_time"] + 1e-3
+    assert s["stage_admit_cpu_time"] > 0
+    dev = [ln for ln in served if ln.startswith("[device] node=0 ")][0]
+    rec = json.loads(dev.split(" ", 2)[2])["stage_record"]
+    assert rec["clock"] == "CLOCK_MONOTONIC" and rec["cpus"] >= 1
+    assert rec["t_start"] < rec["t_meas"] < rec["t_end"]
+    # the window the `[summary]` keys cover is the record's
+    assert rec["t_end"] - rec["t_meas"] == pytest.approx(
+        s["stage_wall_time"], abs=0.05)
+    assert rec["intervals"] > 9 * 10 and rec["dropped"] == 0
+    pw = rec["pass_wall_s"]
+    assert 0 < pw["p50"] <= pw["p99"] <= pw["max"] < s["stage_wall_time"]
+    for key in ("longest", "longest_work"):
+        walls = [r["wall_s"] for r in rec[key]]
+        assert len(walls) == 16 and walls == sorted(walls, reverse=True)
+        assert all(r["wait"] == (r["stage"] in ("retire_wait", "collect"))
+                   and r["cpu_s"] <= r["wall_s"] + 1e-3 for r in rec[key])
+        assert all(r["span_s"] is None or r["span_s"] >= r["wall_s"] - 1e-6
+                   for r in rec[key])
+    assert not [r for r in rec["longest_work"] if r["wait"]]
 
 
 def test_summary_carries_the_lanes_handed_to_the_write_scatter(served):
