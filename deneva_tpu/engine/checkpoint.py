@@ -37,8 +37,10 @@ import numpy as np
 #              audit_drop_cnt device counters, and with audit armed the
 #              db pytree gains the __audit__ version-stamp tables);
 #          9 = PR 26 (write_scatter_lanes device counter);
-#         10 = PR 30 (read_gather_lanes device counter).
-SCHEMA_VERSION = 10
+#         10 = PR 30 (read_gather_lanes device counter);
+#         11 = PR 41 (mc_defer_pass_cnt device counter, where a mesh's
+#              server asks for it).
+SCHEMA_VERSION = 11
 
 
 def save_state(path: str, state) -> None:

@@ -64,7 +64,8 @@ jax.tree_util.register_dataclass(
 def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
                       level_passes: bool = False,
                       append_lanes: bool = False,
-                      recon_defers: bool = False) -> dict:
+                      recon_defers: bool = False,
+                      mc_defer_passes: bool = False) -> dict:
     """``level_passes``: add ``level_pass_cnt`` and ``narrow_pass_cnt``,
     which `engine/epoch.run_levels` counts where it finds them (its
     passes, and those run under the batch's width) — asked for by
@@ -75,12 +76,17 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
     of a workload with ring tables.  ``recon_defers``: likewise
     ``recon_defer_cnt``, the lanes `engine/epoch.epoch_core` defers on
     stale reconnaissance — asked for by the server of a chained backend
-    whose workload marks reconnaissance (PPS)."""
+    whose workload marks reconnaissance (PPS).  ``mc_defer_passes``:
+    likewise ``mc_defer_pass_cnt``, the shard-epochs in which
+    `workloads/ycsb.YCSBWorkload.execute_mc` RAN its capacity-defer pass
+    (a slice with an owner's block over `ops.mc_pair_cap`) — asked for
+    by the server of a forwarding backend on a mesh."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z(), "narrow_pass_cnt": z()}
            if level_passes else {}),
         **({"recon_defer_cnt": z()} if recon_defers else {}),
+        **({"mc_defer_pass_cnt": z()} if mc_defer_passes else {}),
         **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the

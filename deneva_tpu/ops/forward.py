@@ -119,8 +119,12 @@ def mc_plan_defer(keys: jax.Array, ts: jax.Array, valid: jax.Array,
     N)) — the production path computes the identical rule shard-locally
     inside `ycsb.execute_mc` (each chip sorts only its N/D slice and an
     all_gather shares the per-txn bits), keeping every per-epoch term
-    O(N/D).  This form is kept as the executable spec and for the unit
-    tests.
+    O(N/D), and runs that pass only on a shard whose slice holds more
+    than pair_cap lanes of one owner: elsewhere this mask is all False
+    over the slice's txns, which the shard knows from its owner counts
+    before any sort (`tests/test_exchange_counts.py` holds both sides
+    to this function).  This form is kept as the executable spec and
+    for the unit tests.
 
     The sharded plan gives source chip s a balanced N/D input slice and
     routes lanes to their owner (key % D) in fixed pair_cap-sized

@@ -203,7 +203,11 @@ class ServerNode:
             level_passes=self._counts_levels,
             append_lanes=cfg.device_parts == 1 and any(
                 getattr(t, "ring", False) for t in self.db.values()),
-            recon_defers=counts_recon)
+            recon_defers=counts_recon,
+            # the owner exchange of a mesh (`wl.execute_mc`): the
+            # shard-epochs that ran its capacity-defer pass
+            mc_defer_passes=(cfg.device_parts > 1 and not self.vote_mode
+                             and forwarding_applies(self.be, self.wl)))
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -2980,7 +2984,8 @@ class ServerNode:
                 k for k in APPEND_COUNTERS if k in final):
             if dev.endswith("_lanes"):
                 st.set(dev[:-1] + "_cnt", float(final[dev] - measured[dev]))
-        for k in ("level_pass_cnt", "narrow_pass_cnt", "recon_defer_cnt"):
+        for k in ("level_pass_cnt", "narrow_pass_cnt", "recon_defer_cnt",
+                  "mc_defer_pass_cnt"):
             if k in final:      # (where this server's stats carry them)
                 st.set(k, float(final[k] - measured[k]))
         by_type = final["commit_by_type"] - measured["commit_by_type"]

@@ -456,7 +456,16 @@ class YCSBWorkload:
         against `ops.mc_plan_defer`'s replicated spec: each chip sorts
         only its own slice, reduces per-txn overflow bits, and one
         all_gather replicates the identical defer mask to every chip
-        (and to the caller, who builds the epoch verdict from it).  Set
+        (and to the caller, who builds the epoch verdict from it).  A
+        chip counts its slice's lanes per owner ONCE per use with
+        compares (no `jnp.bincount`: a scatter-add on the chip) and
+        RUNS that pass only where a real owner's count is over pair_cap
+        — a lane is over iff its position in its owner's run is >=
+        pair_cap, so elsewhere the mask is all False and the pass's two
+        sorts are skipped by a per-shard ``lax.cond`` (no collective in
+        either branch; ``mc_defer_pass_cnt`` counts the shard-epochs
+        that ran it, where the server's stats carry it).  The block
+        starts come from the same compare-and-sum of the survivors.  Set
         ``mc_plan_capacity=0`` for the round-3 replicated-plan mode
         (zero capacity factors, zero defers, full-batch sort per chip).
 
@@ -468,10 +477,11 @@ class YCSBWorkload:
         size); each local block's last row is its trash.
 
         Scopes (metadata only, as `engine/epoch.make_epoch_body`'s):
-        everything the mesh ADDS to an epoch — the slice cuts, the defer
-        pass, the owner sort, the block cuts, the three ``all_to_all``s,
-        the ``all_gather`` of the defer bits and the three ``psum``s —
-        is `ep.exchange`; the per-shard plan and slot map are `ep.plan`,
+        everything the mesh ADDS to an epoch — the slice cuts, the owner
+        counts, the defer pass and the conditional around it, the owner
+        sort, the block cuts, the three ``all_to_all``s, the
+        ``all_gather`` of the defer bits and the ``psum``s — is
+        `ep.exchange`; the per-shard plan and slot map are `ep.plan`,
         as on one chip; `ep.read` / `ep.write` are the shared executor's.
         """
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -511,19 +521,41 @@ class YCSBWorkload:
                 vs = v2.reshape(-1)
                 lane = jnp.arange(sl, dtype=jnp.int32)
                 owner = jnp.where(vs, ks % d_parts, d_parts)
-                # defer pass (O(N/D) analogue of ops.mc_plan_defer):
-                # age-priority positions per (slice, owner) block;
-                # overflow bits reduce per txn via the sort-by-txn
-                # reshape trick, then one all_gather replicates them
-                so, _, stx = jax.lax.sort((owner, tss, lane // a),
-                                          num_keys=2, is_stable=True)
-                head = jnp.concatenate([jnp.ones((1,), bool),
-                                        so[1:] != so[:-1]])
-                start = jax.lax.cummax(jnp.where(head, lane, 0))
-                over = (lane - start >= pair_cap) & (so != d_parts)
-                _, sov = jax.lax.sort((stx, over), num_keys=1,
-                                      is_stable=True)
-                dfr = sov.reshape(bD, a).any(axis=1)
+                # lanes of my slice per owner, by compare-and-sum
+                # (`jnp.bincount` is a scatter-add on the chip: it was
+                # half of this scope's device time)
+                owners = jnp.arange(d_parts + 1, dtype=jnp.int32)
+
+                def count(o):
+                    return (o[:, None] == owners).sum(0, dtype=jnp.int32)
+
+                def defer_pass(owner, tss):
+                    # (O(N/D) analogue of ops.mc_plan_defer):
+                    # age-priority positions per (slice, owner) block;
+                    # overflow bits reduce per txn via the sort-by-txn
+                    # reshape trick
+                    so, _, stx = jax.lax.sort((owner, tss, lane // a),
+                                              num_keys=2, is_stable=True)
+                    head = jnp.concatenate([jnp.ones((1,), bool),
+                                            so[1:] != so[:-1]])
+                    start = jax.lax.cummax(jnp.where(head, lane, 0))
+                    over = (lane - start >= pair_cap) & (so != d_parts)
+                    _, sov = jax.lax.sort((stx, over), num_keys=1,
+                                          is_stable=True)
+                    return sov.reshape(bD, a).any(axis=1)
+
+                # a lane is over iff its position in its owner's run is
+                # >= pair_cap, so where every real owner's count fits
+                # its block the pass's mask is all False and its two
+                # sorts are not run: the predicate is this shard's own
+                # (no collective in either branch; the zeros come from a
+                # shard-varying operand, which `cond` wants of both
+                # sides); one all_gather replicates the bits below
+                fits = (count(owner)[:d_parts] <= pair_cap).all()
+                dfr = jax.lax.cond(
+                    fits, lambda owner, tss: jnp.zeros_like(t2, dtype=bool),
+                    defer_pass, owner, tss)
+                ran = jax.lax.psum((~fits).astype(jnp.uint32), AXIS)
                 # each sender excludes ITS deferred txns' lanes before
                 # cutting blocks, so no chip ever receives one — the
                 # global mask is just the shards concatenated
@@ -541,7 +573,7 @@ class YCSBWorkload:
                 _, _, ck, cr, cw = jax.lax.sort(
                     (owner2, tss, ks2, rs, ws2), num_keys=2,
                     is_stable=True)
-                cnt = jnp.bincount(owner2, length=d_parts + 1)
+                cnt = count(owner2)
                 starts = jnp.cumsum(cnt) - cnt
                 # fixed-size block per destination (dynamic start is
                 # clamped near the tail — stray lanes are masked after
@@ -558,6 +590,7 @@ class YCSBWorkload:
                 bw = bw & mine
             else:
                 dfr = jnp.zeros((b,), bool)
+                ran = jnp.uint32(0)
             with jax.named_scope("ep.plan"):
                 if pair_cap:
                     p = forward_plan_flat(bk, br, bw)
@@ -575,13 +608,13 @@ class YCSBWorkload:
                 f0, p, slots, trash, mono=True)
             return (f0, jax.lax.psum(cks, AXIS), jax.lax.psum(wcnt, AXIS),
                     jax.lax.psum(lanes, AXIS), jax.lax.psum(rlanes, AXIS),
-                    dfr)
+                    ran, dfr)
 
         with jax.named_scope("ep.exchange"):
-            f0, cks, wcnt, lanes, rlanes, dfr = jax.shard_map(
+            f0, cks, wcnt, lanes, rlanes, passes, dfr = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(AXIS), P(), P(), P(), P(), P()),
-                out_specs=(P(AXIS), P(), P(), P(), P(),
+                out_specs=(P(AXIS), P(), P(), P(), P(), P(),
                            P(AXIS) if pair_cap else P()))(
                     tab.columns["F0"], batch.keys, batch.rank, batch.ts,
                     batch.is_write, valid)
@@ -591,11 +624,15 @@ class YCSBWorkload:
                 # carries this scope
                 dfr = jax.lax.with_sharding_constraint(
                     dfr, NamedSharding(mesh, P()))
-            # (in the scope: the chip's compiler merges the four psums
+            # (in the scope: the chip's compiler merges the psums
             # into one all-reduce that keeps no op_name; a trace reads
             # its scope from these consumers)
             _count(stats, read_checksum=cks, write_cnt=wcnt,
                    write_scatter_lanes=lanes, read_gather_lanes=rlanes)
+            if "mc_defer_pass_cnt" in stats:
+                # shard-epochs that RAN the defer pass (asked for by the
+                # server of a mesh: `engine/step.init_device_stats`)
+                _count(stats, mc_defer_pass_cnt=passes)
         db = dict(db)
         db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
         return db, dfr

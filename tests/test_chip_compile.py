@@ -273,11 +273,16 @@ def dp4_cell(topo):
     """(cfg, mesh, state, compiled group, seconds) of the cell's group
     program — 25,165,824 full rows over the four described chips, epochs
     of 16,384, C=32 — compiled ONCE for the tests below."""
+    from deneva_tpu.engine.step import init_device_stats
     from deneva_tpu.parallel import mesh as M
     cfg = _cell_cfg()
     mesh = Mesh(np.array(topo.devices[:4]), (M.AXIS,))
     with pytest.MonkeyPatch.context() as mp:
         group, state, feed = _group_program(cfg, mp)
+        # the server of a forwarding backend on a mesh counts the
+        # shard-epochs that ran the exchange's defer pass (PR 41)
+        state["stats"] = jax.eval_shape(
+            lambda: init_device_stats(1, mc_defer_passes=True))
         state = _with_sharding(state, M.state_shardings(mesh, state))
         feed = _with_sharding(feed, NamedSharding(mesh, P()))
         with M.use_mesh(mesh):
@@ -338,8 +343,17 @@ def test_dp4_cell_group_names_what_the_mesh_adds(dp4_cell):
     `ep.exchange` (an all-reduce the compiler merged may keep no op_name
     — `phase_reduce.hlo_scopes` then reads its consumers'), the exchange
     blocks are cut at the batch's real width (10 accesses: 20,480 lanes
-    a block), and the per-shard plan sort is `ep.plan`'s."""
+    a block), and the per-shard plan sort is `ep.plan`'s.
+
+    Since PR 41 the exchange counts its owners with compares: nothing
+    under `ep.exchange` is a scatter or a `kCustom` fusion (the
+    `jnp.bincount` behind the block starts was a scatter-add of 40,960
+    lanes into `s32[5]`, 0.38 ms an epoch), and the capacity-defer
+    pass sits in a conditional of that scope — two of its three sorts
+    are a branch's, whose `op_name` keeps the scope, so a trace still
+    charges them to `phase.exchange`."""
     hlo = dp4_cell[3].as_text()
+    assert "mc_defer_pass_cnt" in dp4_cell[2]["stats"]
     seen = {}
     for ln in hlo.splitlines():
         m = re.search(r" (all-to-all|all-gather|all-reduce)[a-z\-]*\(", ln)
@@ -360,6 +374,29 @@ def test_dp4_cell_group_names_what_the_mesh_adds(dp4_cell):
     # (`ep.read`: the read heads' compaction, PR 30)
     assert sorted(inner) == ["ep.exchange"] * 3 + ["ep.plan", "ep.read",
                                                    "ep.write"]
+    # every instruction whose innermost scope is the exchange's, fused
+    # computations' bodies included, by the computation that holds it
+    where, exchange = None, []
+    for ln in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(", ln)
+        if head:
+            where = head.group(1)
+        name = re.search(r'op_name="([^"]*)"', ln)
+        scopes = [p for p in name.group(1).split("/")
+                  if p.startswith(("ep.", "grp."))] if name else []
+        if scopes and scopes[-1] == "ep.exchange":
+            exchange.append((where, ln))
+    assert not [ln for _, ln in exchange
+                if " scatter(" in ln or "kind=kCustom" in ln]
+    (cond,) = [ln for _, ln in exchange if " conditional(" in ln]
+    branches = re.search(r"branch_computations=\{([^}]*)\}", cond)
+    branches = branches.group(1).split(", ")
+    assert len(branches) == 2
+    # the (owner, ts, txn) and the (txn, over) sort are the pass's: in
+    # ONE branch; the five-operand owner sort runs in every epoch
+    held = [where for where, ln in exchange if re.search(r" sort\(", ln)]
+    assert len(held) == 3
+    assert sorted(held.count(b) for b in branches) == [0, 2]
 
 
 def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
