@@ -141,10 +141,14 @@ class MVCCState:
     his: jax.Array   # int32[K, H] recent version-boundary ts (0 = the
     #                  load-time base version, retained until overwritten)
     pos: jax.Array   # int32[K] next ring slot per bucket
+    lossy: jax.Array  # int32[K] greatest boundary of an epoch that wrote
+    #                   the bucket at two timestamps (the earlier
+    #                   writer's version is in no ring); 0 = none yet
 
 
 jax.tree_util.register_dataclass(
-    MVCCState, data_fields=["rts", "wts", "his", "pos"], meta_fields=[])
+    MVCCState, data_fields=["rts", "wts", "his", "pos", "lossy"],
+    meta_fields=[])
 
 
 def init_to_state(cfg) -> TOState:
@@ -158,7 +162,8 @@ def init_mvcc_state(cfg) -> MVCCState:
     return MVCCState(rts=jnp.zeros((k,), jnp.int32),
                      wts=jnp.zeros((k,), jnp.int32),
                      his=jnp.zeros((k, h), jnp.int32),
-                     pos=jnp.zeros((k,), jnp.int32))
+                     pos=jnp.zeros((k,), jnp.int32),
+                     lossy=jnp.zeros((k,), jnp.int32))
 
 
 def _readonly(batch: AccessBatch) -> jax.Array:
@@ -169,6 +174,19 @@ def _readonly(batch: AccessBatch) -> jax.Array:
         return batch.ro_hint
     v = batch.valid & batch.active[:, None]
     return ~(v & batch.is_write).any(axis=1)
+
+
+def _history_read_lanes(cfg, state: MVCCState, batch: AccessBatch
+                        ) -> jax.Array:
+    """bool[B, A]: MVCC's pure read lanes whose version is out of reach
+    — older than the bucket's retained boundaries (version recycled,
+    row_mvcc.cpp:303-321) or below an epoch that kept only the later of
+    two writers' versions (`MVCCState.lossy`)."""
+    wm = _wm_bucket(cfg, batch)
+    ts = batch.ts[:, None]
+    floor = jnp.take(jnp.maximum(state.his.min(axis=1), state.lossy), wm)
+    return batch.valid & batch.active[:, None] & batch.is_read \
+        & ~batch.is_write & (jnp.take(state.wts, wm) > ts) & (ts < floor)
 
 
 def _stale_read_lanes(cfg, state, batch: AccessBatch,
@@ -184,13 +202,11 @@ def _stale_read_lanes(cfg, state, batch: AccessBatch,
     ts = batch.ts[:, None]
     if mvcc:
         # pure reads serve the retained version at their ts; only reads
-        # older than the bounded history (version recycled,
-        # row_mvcc.cpp:303-321) or RMW reads (must read latest) abort
-        his_min = jnp.take(state.his.min(axis=1), wm)
-        pure = batch.is_read & ~batch.is_write
+        # whose version is out of reach or RMW reads (must read latest)
+        # abort
         rmw = batch.is_read & batch.is_write
-        read_bad = v & ((pure & (wts_at > ts) & (ts < his_min))
-                        | (rmw & (wts_at > ts)))
+        read_bad = _history_read_lanes(cfg, state, batch) \
+            | (v & rmw & (wts_at > ts))
     else:
         read_bad = v & batch.is_read & (wts_at > ts)
     if batch.order_free is not None:
@@ -279,23 +295,34 @@ def _commit_watermarks(cfg, state, batch: AccessBatch,
     w_ts = jnp.where(v & batch.is_write, ts, 0)
     flat = _wm_bucket(cfg, batch).reshape(-1)
     rts = state.rts.at[flat].max(r_ts.reshape(-1))
-    wts = state.wts.at[flat].max(w_ts.reshape(-1))
     if not isinstance(state, MVCCState):
-        return TOState(rts=rts, wts=wts)
+        return TOState(rts=rts, wts=state.wts.at[flat].max(w_ts.reshape(-1)))
     # record this epoch's version boundary per written bucket: the ring
     # keeps the last H boundaries (bounded write history); epoch
     # granularity is exact because the table exposes one committed state
     # per epoch
-    epoch_w = jnp.zeros_like(state.wts).at[flat].max(w_ts.reshape(-1))
+    w_flat = w_ts.reshape(-1)
+    epoch_w = jnp.zeros_like(state.wts).at[flat].max(w_flat)
     wrote = epoch_w > 0
+    # (the bucket's watermark needs no scatter of its own: this epoch's
+    # greatest write is in hand, dense)
+    wts = jnp.maximum(state.wts, epoch_w)
     h = state.his.shape[1]
     slot = jnp.arange(h, dtype=jnp.int32)[None, :] == state.pos[:, None]
     his = jnp.where(wrote[:, None] & slot, epoch_w[:, None], state.his)
     pos = jnp.where(wrote, (state.pos + 1) % h, state.pos)
-    return MVCCState(rts=rts, wts=wts, his=his, pos=pos)
+    # a bucket written at two timestamps this epoch (its least committed
+    # write lies under its greatest): the table and the row's ring keep
+    # the later writer's version only
+    big = jnp.int32(jnp.iinfo(jnp.int32).max)
+    epoch_lo = jnp.full_like(state.wts, big).at[flat].min(
+        jnp.where(w_flat > 0, w_flat, big))
+    lossy = jnp.where(epoch_lo < epoch_w,
+                      jnp.maximum(state.lossy, epoch_w), state.lossy)
+    return MVCCState(rts=rts, wts=wts, his=his, pos=pos, lossy=lossy)
 
 
-def _validate_to(cfg, state, batch, inc, mvcc: bool):
+def _validate_to(cfg, state, batch, inc, mvcc: bool, stats=None):
     wm_abort = _watermark_aborts(cfg, state, batch, mvcc)
     live = batch.active & ~wm_abort
     if mvcc:
@@ -318,6 +345,15 @@ def _validate_to(cfg, state, batch, inc, mvcc: bool):
     v = Verdict(commit=commit, abort=batch.active & wm_abort,
                 defer=und | lose, order=order,
                 level=jnp.zeros_like(batch.rank))
+    if stats is not None:
+        # what the backend decided (`workloads/base.MVCC_COUNTERS`; the
+        # served MVCC program hands its stats in): transactions sent
+        # back for a read whose version is out of reach, transactions
+        # that wait behind a writer of their epoch, read-only commits
+        out_of_reach = _history_read_lanes(cfg, state, batch).any(axis=1)
+        for k, m in (("mvcc_history_aborts", wm_abort & out_of_reach),
+                     ("mvcc_waits", v.defer), ("mvcc_ro_commits", live & ro)):
+            stats[k] = stats[k] + m.sum(dtype=jnp.uint32)
     return v, _commit_watermarks(cfg, state, batch, commit)
 
 
@@ -335,5 +371,6 @@ def validate_timestamp(cfg, state, batch: AccessBatch, inc: Incidence):
     return _validate_to(cfg, state, batch, inc, mvcc=False)
 
 
-def validate_mvcc(cfg, state, batch: AccessBatch, inc: Incidence):
-    return _validate_to(cfg, state, batch, inc, mvcc=True)
+def validate_mvcc(cfg, state, batch: AccessBatch, inc: Incidence,
+                  stats=None):
+    return _validate_to(cfg, state, batch, inc, mvcc=True, stats=stats)

@@ -39,8 +39,10 @@ import numpy as np
 #          9 = PR 26 (write_scatter_lanes device counter);
 #         10 = PR 30 (read_gather_lanes device counter);
 #         11 = PR 41 (mc_defer_pass_cnt device counter, where a mesh's
-#              server asks for it).
-SCHEMA_VERSION = 11
+#              server asks for it);
+#         12 = PR 43 (MVCCState.lossy; the MVCC_COUNTERS device counters,
+#              where an MVCC server asks for them).
+SCHEMA_VERSION = 12
 
 
 def save_state(path: str, state) -> None:

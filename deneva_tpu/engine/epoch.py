@@ -310,7 +310,10 @@ def epoch_core(cfg: Config, wl, be, db, cc_state, stats, queries, batch, *,
             # DGCC takes the stats dict (repair-engine contract): its
             # wave/fallback/edge counters come from inside the wave
             # assignment, where the lane graph is in hand
-            kw = {"stats": stats} if be.alg == CCAlg.DGCC else {}
+            # (and MVCC where the stats carry its counters: the served
+            # MVCC program's, `workloads/base.MVCC_COUNTERS`)
+            kw = {"stats": stats} if be.alg == CCAlg.DGCC or (
+                be.alg == CCAlg.MVCC and "mvcc_waits" in stats) else {}
             verdict, cc_state = be.validate(cfg, cc_state, batch, inc, **kw)
         if stale is not None:
             verdict = dataclasses.replace(verdict,

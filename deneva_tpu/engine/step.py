@@ -39,7 +39,8 @@ from deneva_tpu.engine.epoch import (access_batch, count_verdict,
                                      observe_audit, plan_owner, run_levels)
 from deneva_tpu.engine.pool import PoolState, TxnPool
 from deneva_tpu.ops import forwarding_applies
-from deneva_tpu.workloads.base import APPEND_COUNTERS, EXEC_COUNTERS
+from deneva_tpu.workloads.base import (APPEND_COUNTERS, EXEC_COUNTERS,
+                                       MVCC_COUNTERS)
 
 LAT_BUCKETS = 64
 RETRY_BUCKETS = 8      # per-txn restart/wait counts at commit (clipped)
@@ -65,7 +66,8 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
                       level_passes: bool = False,
                       append_lanes: bool = False,
                       recon_defers: bool = False,
-                      mc_defer_passes: bool = False) -> dict:
+                      mc_defer_passes: bool = False,
+                      mvcc_counters: bool = False) -> dict:
     """``level_passes``: add ``level_pass_cnt`` and ``narrow_pass_cnt``,
     which `engine/epoch.run_levels` counts where it finds them (its
     passes, and those run under the batch's width) — asked for by
@@ -80,7 +82,9 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
     likewise ``mc_defer_pass_cnt``, the shard-epochs in which
     `workloads/ycsb.YCSBWorkload.execute_mc` RAN its capacity-defer pass
     (a slice with an owner's block over `ops.mc_pair_cap`) — asked for
-    by the server of a forwarding backend on a mesh."""
+    by the server of a forwarding backend on a mesh.  ``mvcc_counters``:
+    likewise `workloads/base.MVCC_COUNTERS`, what MVCC decides — asked
+    for by an MVCC server on one device."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z(), "narrow_pass_cnt": z()}
@@ -88,6 +92,7 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
         **({"recon_defer_cnt": z()} if recon_defers else {}),
         **({"mc_defer_pass_cnt": z()} if mc_defer_passes else {}),
         **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
+        **({k: z() for k in MVCC_COUNTERS} if mvcc_counters else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the
         # contention-adaptive router's input).  Always present so the

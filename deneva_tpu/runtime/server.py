@@ -53,7 +53,8 @@ from deneva_tpu.runtime.telemetry import (ST_ADMIT, ST_BATCH, ST_HOLD,
 from deneva_tpu.runtime.native import NativeTransport
 from deneva_tpu.runtime.stages import StageClock, span as stage_span
 from deneva_tpu.stats import Stats
-from deneva_tpu.workloads.base import APPEND_COUNTERS, EXEC_COUNTERS
+from deneva_tpu.workloads.base import (APPEND_COUNTERS, EXEC_COUNTERS,
+                                       MVCC_COUNTERS)
 
 _TAG_MASK = np.int64((1 << 40) - 1)
 
@@ -207,7 +208,11 @@ class ServerNode:
             # the owner exchange of a mesh (`wl.execute_mc`): the
             # shard-epochs that ran its capacity-defer pass
             mc_defer_passes=(cfg.device_parts > 1 and not self.vote_mode
-                             and forwarding_applies(self.be, self.wl)))
+                             and forwarding_applies(self.be, self.wl)),
+            # what MVCC decides (`workloads/base.MVCC_COUNTERS`), in the
+            # group program of one device
+            mvcc_counters=(cfg.cc_alg == CCAlg.MVCC and not self.vote_mode
+                           and cfg.device_parts == 1))
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -2972,6 +2977,10 @@ class ServerNode:
             # both (a read-only transaction changes no digest)
             self.info["read_checksum"] = int(final["read_checksum"])
             self.info["run_defer_cnt"] = int(final["defer_cnt"])
+            # ... and, of an MVCC server, how often the mechanism ran
+            for k in MVCC_COUNTERS:
+                if k in final:
+                    self.info[f"run_{k[:-1]}_cnt"] = int(final[k])
         st = self.stats
         st.set("total_runtime", end - self._t_meas)
         st.set("epoch_cnt", float(epochs_run))
@@ -2988,6 +2997,9 @@ class ServerNode:
                   "mc_defer_pass_cnt"):
             if k in final:      # (where this server's stats carry them)
                 st.set(k, float(final[k] - measured[k]))
+        for k in MVCC_COUNTERS:     # `<x>s` -> `<x>_cnt`, likewise
+            if k in final:
+                st.set(k[:-1] + "_cnt", float(final[k] - measured[k]))
         by_type = final["commit_by_type"] - measured["commit_by_type"]
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):

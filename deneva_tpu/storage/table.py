@@ -317,8 +317,12 @@ class VersionRing:
         trash = jnp.int32(self.wts.shape[0] // h - 1)
         sl = jnp.where(mask, slots, trash)
         p = jnp.argmin(vw, axis=-1)
+        # (a masked lane writes 0 = empty: the trash row stays what it
+        # was at load, whichever of its lanes the scatter keeps, so the
+        # ring hashes the same on every backend)
         return VersionRing(
-            wts=self.wts.at[sl * h + p].set(wts.astype(jnp.int32)),
+            wts=self.wts.at[sl * h + p].set(
+                jnp.where(mask, wts.astype(jnp.int32), 0)),
             depth=self.depth)
 
     def push(self, slots: jax.Array, wts: jax.Array, mask: jax.Array

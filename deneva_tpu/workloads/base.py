@@ -65,6 +65,20 @@ EXEC_COUNTERS = (
 APPEND_COUNTERS = ("append_window_lanes", "append_scatter_lanes")
 
 
+# What served YCSB under MVCC decides, counted where the ``stats`` dict
+# carries them: reads served a version other than the live one
+# (`workloads/ycsb.YCSBWorkload.execute`), and — in
+# `cc/timestamp.validate_mvcc` — transactions sent back for a read whose
+# version is out of reach, transactions that wait behind a writer of
+# their epoch, read-only commits.  NOT part of ``EXEC_COUNTERS``: an MVCC
+# server on one device asks for them
+# (`engine/step.init_device_stats(mvcc_counters=True)`), so every other
+# program's stats pytree is what it was.  `[summary]` reads ``<x>s`` as
+# ``<x>_cnt``.
+MVCC_COUNTERS = ("mvcc_old_version_reads", "mvcc_history_aborts",
+                 "mvcc_waits", "mvcc_ro_commits")
+
+
 def partition_owned(key: jax.Array, n_parts: int, me: int) -> jax.Array:
     """bool mask: does this node own ``key`` under modulo striping
     (reference GET_NODE_ID, `system/global.h:294`)?"""

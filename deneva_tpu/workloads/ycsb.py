@@ -735,16 +735,27 @@ class YCSBWorkload:
                 # fixed ~ms-scale pass on v5e; see VersionRing.rows).  Raw
                 # slots: write-lane rows are garbage for select (masked by
                 # rmask downstream) and exactly what push needs.
-                ver_rows = ver.rows(slots)
-                vstar, has = ver.version_from(
-                    ver_rows, jnp.broadcast_to(ver_ts[:, None], slots.shape))
-                if full:
-                    vals = jnp.where(has[..., None],
-                                     _field_bytes(q.keys, vstar,
-                                                  self.cfg.tup_size), vals)
-                else:
-                    vals = jnp.where(has, _field_fingerprint(q.keys, vstar),
-                                     vals)
+                # `ep.version` (the innermost scope is an op's own): the
+                # ring's gather, the select and the old bytes' law
+                with jax.named_scope("ep.version"):
+                    ver_rows = ver.rows(slots)
+                    vstar, has = ver.version_from(
+                        ver_rows,
+                        jnp.broadcast_to(ver_ts[:, None], slots.shape))
+                    if full:
+                        vals = jnp.where(has[..., None],
+                                         _field_bytes(q.keys, vstar,
+                                                      self.cfg.tup_size),
+                                         vals)
+                    else:
+                        vals = jnp.where(
+                            has, _field_fingerprint(q.keys, vstar), vals)
+                    if "mvcc_old_version_reads" in stats:
+                        # reads served a version other than the live one
+                        # (the served MVCC program counts them:
+                        # `workloads/base.MVCC_COUNTERS`)
+                        _count(stats, mvcc_old_version_reads=(
+                            rmask & has).sum(dtype=jnp.uint32))
             rm = rmask[..., None] if full else rmask
             stats["read_checksum"] = stats["read_checksum"] + jnp.sum(
                 jnp.where(rm, vals, 0), dtype=jnp.uint32)
@@ -766,8 +777,10 @@ class YCSBWorkload:
                 # record each winning overwrite's commit ts (one winner per
                 # row per epoch, so each row advances at most one ring slot);
                 # no value bytes — reads reconstruct via f(key, v*)
-                db[VER_TABLE] = ver.push_rows(
-                    ver_rows.reshape(-1, ver.depth), wslots, worder, win)
+                with jax.named_scope("ep.version"):
+                    db[VER_TABLE] = ver.push_rows(
+                        ver_rows.reshape(-1, ver.depth), wslots, worder,
+                        win)
             wkeys = q.keys.reshape(-1)
             if full:
                 # the winners alone reach the row scatter, compacted, and

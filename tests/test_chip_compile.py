@@ -459,6 +459,47 @@ def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
     assert re.search(rf"f32\[{b},{b}\]\S* fusion\(", hlo)
 
 
+# ---- the MVCC cell (PR 43): ycsb_fullrow_mvcc.medium ----------------------
+
+def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
+                                                         monkeypatch):
+    """The MVCC cell's group program at its served size, on the chip's
+    own HLO: 6.29M full rows, the ring of ten timestamps a row
+    (`int32[62,915,200]`, flat: 252 MB) and the watermark tables fit
+    beside two groups in flight; the ring's gather, select and push
+    carry `ep.version`; deciding an epoch scatters into the `int32[2^20]`
+    watermark tables three times — the reads' watermark, the epoch's
+    greatest and least write of a bucket — as the parent's did (the
+    writes' watermark is a dense max since PR 43)."""
+    from deneva_tpu.engine.step import init_device_stats
+    cfg = _cell_cfg("ycsb_fullrow_mvcc.medium")
+    assert (cfg.cc_alg, cfg.mvcc_his_len, cfg.epoch_batch,
+            cfg.watermark_buckets) == ("MVCC", 10, 1024, 1 << 20)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    state["stats"] = jax.eval_shape(
+        lambda: init_device_stats(2, mvcc_counters=True))
+    state, feed = _with_sharding((state, feed), one_chip)
+    compiled, secs = _compile(group, state["db"], state["cc_state"],
+                              state["stats"], *feed)
+    need = _report("mvcc_cell_group", compiled, secs)
+    table = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"]))
+    ring = state["db"]["MAIN_TABLE.F0.ver"].wts
+    assert ring.shape == (62_915_200,) and table > 6_500_000_000
+    assert table + cfg.pipeline_groups * (need - table) < HBM_BYTES
+    hlo = compiled.as_text()
+    named = [ln for ln in hlo.splitlines() if re.search(
+        r'op_name="[^"]*/ep\.version/', ln)]
+    assert len(named) > 50
+    scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
+    by_shape = [re.search(r"= (\w+\[[\d,]*\])", ln).group(1)
+                for ln in scatters]
+    assert set(by_shape) == {"s32[1048576]", "s32[62915200]",
+                             "u8[6291520,100]"}
+    assert (by_shape.count("s32[1048576]"),
+            by_shape.count("s32[62915200]")) == (3, 1)
+
+
 # ---- the TPC-C cell (PR 36): tpcc_fullschema_tpubatch.mixed --------------
 
 CELL_TPCC = "tpcc_fullschema_tpubatch.mixed"
