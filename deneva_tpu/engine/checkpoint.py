@@ -41,8 +41,10 @@ import numpy as np
 #         11 = PR 41 (mc_defer_pass_cnt device counter, where a mesh's
 #              server asks for it);
 #         12 = PR 43 (MVCCState.lossy; the MVCC_COUNTERS device counters,
-#              where an MVCC server asks for them).
-SCHEMA_VERSION = 12
+#              where an MVCC server asks for them);
+#         13 = PR 45 (VersionRing stored by rows, uint8[R, 4*H], and the
+#              ring_push_lanes device counter among MVCC_COUNTERS).
+SCHEMA_VERSION = 13
 
 
 def save_state(path: str, state) -> None:

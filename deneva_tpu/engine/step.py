@@ -83,8 +83,9 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
     `workloads/ycsb.YCSBWorkload.execute_mc` RAN its capacity-defer pass
     (a slice with an owner's block over `ops.mc_pair_cap`) — asked for
     by the server of a forwarding backend on a mesh.  ``mvcc_counters``:
-    likewise `workloads/base.MVCC_COUNTERS`, what MVCC decides — asked
-    for by an MVCC server on one device."""
+    likewise `workloads/base.MVCC_COUNTERS`, what MVCC decides and the
+    lanes its version ring's row write is handed — asked for by an MVCC
+    server on one device."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z(), "narrow_pass_cnt": z()}

@@ -17,7 +17,9 @@ import os
 import shutil
 import struct
 import subprocess
+import tempfile
 import threading
+import zlib
 
 import numpy as np
 
@@ -178,8 +180,18 @@ def _load() -> C.CDLL:
 
 def ipc_endpoints(n_nodes: int, run_id: str, base_dir: str = "/tmp") -> str:
     """Endpoint table for same-host IPC runs (`ifconfig.txt` +
-    `ipc://node_N.ipc`, `transport/transport.cpp:132-133`)."""
+    `ipc://node_N.ipc`, `transport/transport.cpp:132-133`): one socket a
+    node in ``base_dir``, or in the temp dir where ``base_dir`` is too
+    deep for a socket's address."""
     paths = [f"{base_dir}/dt_{run_id}_n{i}.sock" for i in range(n_nodes)]
+    if len(paths[-1]) > 100:
+        # a deep run directory (pytest's ``tmp_path`` under a long
+        # TMPDIR, a run id that holds a pid of six digits): the sockets
+        # move up into the temp dir, named after the run directory too —
+        # whether a launch starts must not hang on a path's last byte
+        tag = f"{run_id}_{zlib.crc32(base_dir.encode()):08x}"
+        paths = [f"{tempfile.gettempdir()}/dt_{tag}_n{i}.sock"
+                 for i in range(n_nodes)]
     if len(paths[-1]) > 100:
         # sockaddr_un.sun_path holds 108 bytes; a longer path would be
         # cut to the same prefix for every node

@@ -72,6 +72,7 @@ import os
 import struct
 import time
 
+import jax
 import numpy as np
 
 from deneva_tpu.config import Config
@@ -314,6 +315,13 @@ def follower_boot(cfg: Config, primary: int):
     return fcfg, wl, step, db, be.init_state(fcfg), dev_stats
 
 
+@jax.jit
+def _ring_push(ring, slots, boundary, mask):
+    """`GeoFollower._push_ring`'s push of one applied group, compiled."""
+    return ring.push_rows(ring.rows(slots), slots,
+                          jax.numpy.full(slots.shape, boundary), mask)
+
+
 class GeoFollower:
     """Replaying state machine behind a geo replica.
 
@@ -415,13 +423,19 @@ class GeoFollower:
     def _push_ring(self, rows: np.ndarray, boundary: int) -> None:
         import jax.numpy as jnp
 
-        if not len(rows):
+        n = len(rows)
+        if not n:
             return
-        slots = jnp.asarray(rows.astype(np.int32))
-        self._ring = self._ring.push_rows(
-            self._ring.rows(slots), slots,
-            jnp.full(len(rows), boundary, jnp.int32),
-            jnp.ones(len(rows), bool))
+        # one compiled push a power of two of written rows, the padding
+        # masked off: the ring's row write is a loop inside a conditional
+        # (`VersionRing.push_rows`), which run op by op is traced and
+        # compiled anew at every call
+        cap = 1 << (n - 1).bit_length()
+        slots = np.zeros(cap, np.int32)
+        slots[:n] = rows
+        self._ring = _ring_push(self._ring, jnp.asarray(slots),
+                                jnp.int32(boundary),
+                                jnp.asarray(np.arange(cap) < n))
 
     def tick(self) -> bool:
         """Apply the next group iff every one of its records arrived;

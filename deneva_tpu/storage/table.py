@@ -237,11 +237,11 @@ class VersionRing:
     (reference `row_mvcc.{h,cpp}`: HIS_RECYCLE_LEN-deep write history per
     row, `row_mvcc.cpp:172-196,303-321`).
 
-    Entry ``(r, i)`` (stored flat at ``r*H + i``) holds the serialization
-    timestamp of a committed overwrite of row r; 0 = empty.  The ring is
-    FIFO without a cursor: commit timestamps increase monotonically, so
-    the oldest entry is simply the row's MINIMUM and each push overwrites
-    it (argmin — empties first, since 0 sorts below every real ts >= 1).
+    Entry ``(r, i)`` holds the serialization timestamp of a committed
+    overwrite of row r; 0 = empty.  The ring is FIFO without a cursor:
+    commit timestamps increase monotonically, so the oldest entry is
+    simply the row's MINIMUM and each push overwrites it (argmin —
+    empties first, since 0 sorts below every real ts >= 1).
 
     The ring stores NO value bytes (round-5; round 3-4 stored the
     overwritten payload per entry).  In this framework every committed
@@ -250,11 +250,24 @@ class VersionRing:
     — so the version a reader at t needs is reconstructed from timestamps
     alone: it was written at ``v* = max(entry ts <= t, default 0)`` (0 =
     the load-time base version), value ``f(key, v*)``.  ``select_version``
-    returns (v*, has_newer); the workload turns v* into bytes.  Dropping
-    the value array cut the ring from 600 MB to 268 MB at 16M rows and —
-    since a batched scatter on TPU costs a full copy of its operand every
-    epoch — removed two of the three whole-array copies from the MVCC
-    epoch.
+    returns (v*, has_newer); the workload turns v* into bytes.
+
+    STORAGE (PR 45): the unit of transfer is a ROW.  ``wts`` is
+    ``uint8[rows, 4*H]`` — row r's H int32 timestamps as their 4*H
+    little-endian bytes, so the leaf's bytes are those of
+    ``int32[rows, H]`` row-major — the shape class of a full-row table
+    column (``uint8[rows, width]``, which the chip tiles rows-minor and
+    does not pad at a width that is a multiple of 8): a lane reads its
+    row's whole history in ONE row gather (`rows`) and the words are
+    bit-cast in registers; the push (`push_rows`) hands the epoch's
+    winners alone, compacted, to the table's own in-place row write
+    (`ops.scatter.scatter_winner_rows`), ordered behind the gather by a
+    data dependence, so the compiled epoch holds no copy of the ring and
+    writes neither its trash row nor its padding.  (Why not the other
+    forms: flat ``int32[rows*H]`` costs H scalar gathers a lane, 14.8 ns
+    a word on v5e, and its scatter copies the array each epoch;
+    ``int32[rows, H]`` is tiled rows-minor too, its H words padded to a
+    multiple of 8 — 1.6x the bytes at H = 10: PERF.md section 6, PR 45.)
 
     Retention/GC is the bucket boundary ring in `cc/timestamp.MVCCState`:
     a read COMMITS only when ``ts >= min(bucket boundaries)``, and at most
@@ -265,27 +278,22 @@ class VersionRing:
     one); this ring is exact per row.
     """
 
-    wts: jax.Array   # int32[R*H]   (flat [row, ring slot], row-major)
+    wts: jax.Array   # uint8[R, 4*H]: row r's H int32 ts, little-endian
     depth: int       # H (static)
 
     @classmethod
     def create(cls, nrows: int, depth: int) -> "VersionRing":
-        # FLAT storage, entry (r, i) at index r*H + i: 2D-indexed
-        # ``at[sl, p].set`` scatters lower to fully serialized XLA while
-        # loops on TPU (~1.3 us/lane measured — the 24 ms/epoch that made
-        # round-4 MVCC the floor of every sweep); the same updates
-        # against a flat buffer take the 1D fast path
-        return cls(wts=jnp.zeros((nrows * depth,), jnp.int32), depth=depth)
+        return cls(wts=jnp.zeros((nrows, 4 * depth), jnp.uint8), depth=depth)
 
     def rows(self, slots: jax.Array) -> jax.Array:
-        """Gather the H ring entries of many rows at once: int32[..., H].
-        A gather against the big flat array costs ~0.3-1.5 ms per OP on
-        v5e regardless of lane count, so callers that both read versions
-        and push overwrites in one epoch fetch ONE combined row set and
-        feed it to `version_from` / `push_rows`."""
-        h = self.depth
-        base = slots[..., None] * h + jnp.arange(h, dtype=jnp.int32)
-        return jnp.take(self.wts, base, axis=0)
+        """The H ring entries of many rows at once, int32[..., H]: ONE
+        row gather (a lane moves its row's 4*H bytes), the words bit-cast
+        from them.  Callers that both read versions and push overwrites
+        in one epoch fetch ONE combined row set and feed it to
+        `version_from` / `push_rows`."""
+        b = jnp.take(self.wts, slots, axis=0, mode="clip")
+        return jax.lax.bitcast_convert_type(
+            b.reshape(*slots.shape, self.depth, 4), jnp.int32)
 
     @staticmethod
     def version_from(vw: jax.Array, ts: jax.Array
@@ -305,25 +313,41 @@ class VersionRing:
         return self.version_from(self.rows(slots), ts)
 
     def push_rows(self, vw: jax.Array, slots: jax.Array, wts: jax.Array,
-                  mask: jax.Array) -> "VersionRing":
-        """Record committed overwrites (flat lanes; masked lanes land on
-        the trash row) given pre-gathered rows ``vw`` (int32[N, H], from
-        `rows(slots)` — the RAW slots, unmasked: a masked lane's ring
-        position is garbage steered onto the trash row anyway).  Callers
-        pre-resolve duplicate slots (one winner per row per epoch), so
-        each row advances at most one ring slot.  FIFO slot = argmin of
-        the row (0-empties first; real ts are monotone)."""
+                  mask: jax.Array, stats: dict | None = None
+                  ) -> "VersionRing":
+        """Record committed overwrites (flat lanes) given pre-gathered
+        rows ``vw`` (int32[N, H], from `rows(slots)`).  Callers
+        pre-resolve duplicate slots (``mask`` holds one winner per row
+        per epoch), so each row advances at most one ring slot: FIFO slot
+        = argmin of the row (0-empties first; real ts are monotone), the
+        new row computed from the row already gathered.  Only the masked
+        lanes reach the write, compacted, as whole rows and in place
+        (`ops.scatter.scatter_winner_rows`, which orders the write behind
+        ``vw``'s gather); the last row (the ring's trash) is never
+        written, so trash and padding stay 0 as loaded.
+
+        ``stats`` — a device-counter dict: where it carries
+        ``ring_push_lanes`` (`workloads/base.MVCC_COUNTERS`) the lanes
+        handed to the row write are counted there."""
         h = self.depth
-        trash = jnp.int32(self.wts.shape[0] // h - 1)
-        sl = jnp.where(mask, slots, trash)
-        p = jnp.argmin(vw, axis=-1)
-        # (a masked lane writes 0 = empty: the trash row stays what it
-        # was at load, whichever of its lanes the scatter keeps, so the
-        # ring hashes the same on every backend)
-        return VersionRing(
-            wts=self.wts.at[sl * h + p].set(
-                jnp.where(mask, wts.astype(jnp.int32), 0)),
-            depth=self.depth)
+
+        def new_rows(lane):
+            # (the lanes that won, by their carried index: no [N, H]
+            # array is sorted)
+            row = jnp.take(vw, lane, axis=0, mode="clip")
+            ts = jnp.take(wts.astype(jnp.int32), lane, mode="clip")
+            fifo = jnp.arange(h) == jnp.argmin(row, axis=-1)[:, None]
+            return jax.lax.bitcast_convert_type(
+                jnp.where(fifo, ts[:, None], row), jnp.uint8
+            ).reshape(lane.shape[0], 4 * h)
+
+        lane = jnp.arange(slots.shape[0], dtype=jnp.int32)
+        ring, lanes, _ = scatter_winner_rows(
+            self.wts, slots, mask, (lane,), new_rows,
+            self.wts.shape[0] - 1, after=vw)
+        if stats is not None and "ring_push_lanes" in stats:
+            stats["ring_push_lanes"] = stats["ring_push_lanes"] + lanes
+        return VersionRing(wts=ring, depth=h)
 
     def push(self, slots: jax.Array, wts: jax.Array, mask: jax.Array
              ) -> "VersionRing":

@@ -70,13 +70,16 @@ APPEND_COUNTERS = ("append_window_lanes", "append_scatter_lanes")
 # (`workloads/ycsb.YCSBWorkload.execute`), and — in
 # `cc/timestamp.validate_mvcc` — transactions sent back for a read whose
 # version is out of reach, transactions that wait behind a writer of
-# their epoch, read-only commits.  NOT part of ``EXEC_COUNTERS``: an MVCC
+# their epoch, read-only commits — and the lanes handed to the version
+# ring's row write (`storage/table.VersionRing.push_rows`: the epoch's
+# winners, in whole chunks, as ``write_scatter_lanes`` is for the table's
+# column).  NOT part of ``EXEC_COUNTERS``: an MVCC
 # server on one device asks for them
 # (`engine/step.init_device_stats(mvcc_counters=True)`), so every other
 # program's stats pytree is what it was.  `[summary]` reads ``<x>s`` as
 # ``<x>_cnt``.
 MVCC_COUNTERS = ("mvcc_old_version_reads", "mvcc_history_aborts",
-                 "mvcc_waits", "mvcc_ro_commits")
+                 "mvcc_waits", "mvcc_ro_commits", "ring_push_lanes")
 
 
 def partition_owned(key: jax.Array, n_parts: int, me: int) -> jax.Array:

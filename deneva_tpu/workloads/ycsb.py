@@ -731,10 +731,9 @@ class YCSBWorkload:
                 big = jnp.int32(jnp.iinfo(jnp.int32).max)
                 ver_ts = jnp.where(order > 0, order, big)
                 # ONE row gather serves both the version select here and the
-                # push below (each gather against the big ring array costs a
-                # fixed ~ms-scale pass on v5e; see VersionRing.rows).  Raw
-                # slots: write-lane rows are garbage for select (masked by
-                # rmask downstream) and exactly what push needs.
+                # push below (VersionRing.rows: a lane's whole history is one
+                # row).  Raw slots: write-lane rows are garbage for select
+                # (masked by rmask downstream) and exactly what push needs.
                 # `ep.version` (the innermost scope is an op's own): the
                 # ring's gather, the select and the old bytes' law
                 with jax.named_scope("ep.version"):
@@ -776,11 +775,13 @@ class YCSBWorkload:
             if ver is not None:
                 # record each winning overwrite's commit ts (one winner per
                 # row per epoch, so each row advances at most one ring slot);
-                # no value bytes — reads reconstruct via f(key, v*)
+                # no value bytes — reads reconstruct via f(key, v*).  The
+                # winners alone, compacted, as rows, in place behind the
+                # ring's gather — as F0's below
                 with jax.named_scope("ep.version"):
                     db[VER_TABLE] = ver.push_rows(
                         ver_rows.reshape(-1, ver.depth), wslots, worder,
-                        win)
+                        win, stats)
             wkeys = q.keys.reshape(-1)
             if full:
                 # the winners alone reach the row scatter, compacted, and

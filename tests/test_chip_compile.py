@@ -464,13 +464,29 @@ def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
 def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
                                                          monkeypatch):
     """The MVCC cell's group program at its served size, on the chip's
-    own HLO: 6.29M full rows, the ring of ten timestamps a row
-    (`int32[62,915,200]`, flat: 252 MB) and the watermark tables fit
-    beside two groups in flight; the ring's gather, select and push
-    carry `ep.version`; deciding an epoch scatters into the `int32[2^20]`
-    watermark tables three times — the reads' watermark, the epoch's
-    greatest and least write of a bucket — as the parent's did (the
-    writes' watermark is a dense max since PR 43)."""
+    own HLO: 6.29M full rows, the ring of ten timestamps a row and the
+    watermark tables fit beside two groups in flight; the ring's gather,
+    select and push carry `ep.version`; deciding an epoch scatters into
+    the `int32[2^20]` watermark tables three times — the reads'
+    watermark, the epoch's greatest and least write of a bucket — as the
+    parent's did (the writes' watermark is a dense max since PR 43).
+
+    **The ring's storage form (PR 45): `uint8[6,291,520, 40]`**, a row
+    its ten int32 timestamps as bytes — 251,660,800 B, nothing padded
+    (the chip tiles it `{0,1:T(8,128)(4,1)}`, rows minor, as it does
+    TPC-C's narrow string columns).  A lane's history is ONE row gather
+    of 40 B slices (10,240 lanes an epoch, no gather of words out of the
+    ring), the push the table's own winners-only row write
+    (`ops.scatter.scatter_winner_rows`: a loop of 160-lane scatters, or
+    one sorted scatter, in the two branches of a conditional), and the
+    compiled program holds NO copy and no second buffer of the ring:
+    nothing but parameters, tuple elements and those two scatters has its
+    shape.  The forms not taken, compiled here for the same chip
+    (PERF.md section 6, PR 45): `int32[6,291,520, 10]` is tiled
+    `{0,1:T(8,128)}`, rows minor with the ten words padded to sixteen —
+    402,657,280 B, 1.6x, and a "row" is ten strided words again —; the
+    flat `int32[62,915,200]` of PRs 22-43 gathered 102,400 scalars and
+    its scatter of every lane copied the array each epoch."""
     from deneva_tpu.engine.step import init_device_stats
     cfg = _cell_cfg("ycsb_fullrow_mvcc.medium")
     assert (cfg.cc_alg, cfg.mvcc_his_len, cfg.epoch_batch,
@@ -485,19 +501,41 @@ def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
     table = sum(x.size * x.dtype.itemsize
                 for x in jax.tree.leaves(state["db"]))
     ring = state["db"]["MAIN_TABLE.F0.ver"].wts
-    assert ring.shape == (62_915_200,) and table > 6_500_000_000
+    assert (ring.shape, ring.dtype) == ((6_291_520, 40), np.uint8) \
+        and table > 6_500_000_000
     assert table + cfg.pipeline_groups * (need - table) < HBM_BYTES
     hlo = compiled.as_text()
+    # as the chip lays it out: its bytes, not a padded tile's
+    shape = _hlo_shape(ring)
+    (layout,) = set(re.findall(re.escape(shape) + r"(\{[^}]*\})", hlo))
+    minor = int(re.match(r"\{(\d),", layout).group(1))
+    tile = [int(x) for x in re.search(r"T\((\d+),(\d+)\)", layout).groups()]
+    pad = lambda n, t: -(-n // t) * t  # noqa: E731
+    laid = pad(ring.shape[minor], tile[1]) * pad(
+        ring.shape[1 - minor], tile[0]) * ring.dtype.itemsize
+    assert ring.size == 251_660_800 and laid <= 1.1 * 251_660_800
     named = [ln for ln in hlo.splitlines() if re.search(
         r'op_name="[^"]*/ep\.version/', ln)]
     assert len(named) > 50
+    # one row gather a lane, of the whole 40 B, under the scope; no words
+    row_gathers = [ln for ln in hlo.splitlines()
+                   if " gather(" in ln and "slice_sizes={1,40}" in ln]
+    assert len(row_gathers) == 1 and "/ep.version/" in row_gathers[0] \
+        and "u8[1024,10,40]" in row_gathers[0]
+    lanes = cfg.epoch_batch * cfg.req_per_query
+    assert f"s32[{lanes * cfg.mvcc_his_len}]" not in hlo
+    # no copy, no second buffer: what has the ring's shape is the state
+    # passed along and the two forms of the in-place row write
+    makers = set(re.findall(
+        r"= " + re.escape(shape) + r"\{[^}]*\} ([\w\-]+)\(", hlo))
+    assert makers == {"parameter", "get-tuple-element", "fusion", "scatter"}
+    assert _column_copies(hlo, shape) == []
     scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
     by_shape = [re.search(r"= (\w+\[[\d,]*\])", ln).group(1)
                 for ln in scatters]
-    assert set(by_shape) == {"s32[1048576]", "s32[62915200]",
-                             "u8[6291520,100]"}
-    assert (by_shape.count("s32[1048576]"),
-            by_shape.count("s32[62915200]")) == (3, 1)
+    assert set(by_shape) == {"s32[1048576]", shape, "u8[6291520,100]"}
+    assert (by_shape.count("s32[1048576]"), by_shape.count(shape)) == (3, 2)
+    assert _scatter_lanes(hlo, shape) == [lanes // 64, lanes]
 
 
 # ---- the TPC-C cell (PR 36): tpcc_fullschema_tpubatch.mixed --------------

@@ -1,8 +1,9 @@
 """What served MVCC counts and names (PR 43), and what it must leave alone.
 
-* the four device counters of `workloads/base.MVCC_COUNTERS` — reads
+* the device counters of `workloads/base.MVCC_COUNTERS` — reads
   served a version other than the live one, transactions sent back for a
-  read whose version is out of reach, waits, read-only commits — against
+  read whose version is out of reach, waits, read-only commits, and
+  (PR 45) the lanes handed to the version ring's row write — against
   a plain numpy restatement of the rule on seeded toys, verdict by
   verdict (the toys also hold the fault this PR mends: a reader that
   waited and came back between two writers of one epoch is sent back,
@@ -29,6 +30,8 @@ from deneva_tpu.cc.base import AccessBatch
 from deneva_tpu.config import CCAlg, Config, WorkloadKind
 from deneva_tpu.engine.step import init_device_stats
 from deneva_tpu.ops import bucket_hash, combine_key
+from deneva_tpu.ops.scatter import _CHUNKS, _ROWS_PER_LANE
+from deneva_tpu.storage.table import padded_rows
 from deneva_tpu.workloads import get_workload
 from deneva_tpu.workloads.base import MVCC_COUNTERS
 
@@ -114,7 +117,18 @@ class Model:
             self.his[k, self.pos[k]] = top[k]
             self.pos[k] = (self.pos[k] + 1) % H
             self.ring[k, int(np.argmin(self.ring[k]))] = top[k]
+        c["ring_push_lanes"] += _row_write_lanes(
+            np.count_nonzero(top), keys.size, padded_rows(256))
         return commit, abort, defer
+
+
+def _row_write_lanes(winners: int, n: int, rows: int) -> int:
+    """Lanes `ops.scatter.scatter_winner_rows` is handed for ``winners``
+    rows of an epoch of ``n`` lanes on a column of ``rows``: whole
+    chunks of n / 64 while that beats one pass, else all n."""
+    chunk = -(-n // _CHUNKS)
+    lanes = -(-winners // chunk) * chunk
+    return lanes if lanes * _ROWS_PER_LANE < rows + 6 * n else n
 
 
 class Device:
@@ -173,7 +187,7 @@ def toy_keys():
 
 
 @pytest.mark.parametrize("seed", [1, 2, 3, 6])
-def test_the_four_counters_equal_a_numpy_count(seed, toy_keys):
+def test_the_mvcc_counters_equal_a_numpy_count(seed, toy_keys):
     """Sixty epochs of eight transactions over forty hot keys: a waiting
     transaction comes back with its timestamp two epochs later (so it
     meets writers of later timestamps: old versions, lost versions, a

@@ -301,3 +301,33 @@ def test_sharded_io_threads_full_mesh(lib):
     finally:
         for t in nodes:
             t.close()
+
+
+@pytest.mark.parametrize("pid", [9_876, 14_978, 123_456, 4_194_304])
+def test_ipc_endpoints_fit_a_socket_address_whatever_the_run_dir(
+        pid, monkeypatch):
+    """`benchmark/run.py` names a launch's sockets after its pid and puts
+    them in the run directory; a test hands it pytest's `tmp_path`.
+    Under a TMPDIR of 15 characters such a path is 100 characters with a
+    pid of five digits and 101 with six — the limit — so whether a
+    launch started hung on the pid's digits (two tier-1 tests failed so
+    in one whole run and passed in the next: PR 45).  A deep directory's
+    sockets move up into the temp dir, one name a run directory."""
+    import tempfile
+    tmp = "/tmp/" + "x" * 10            # (only names are made: none is bound)
+    monkeypatch.setattr(tempfile, "tempdir", tmp)
+    deep = [f"{tmp}/pytest-of-root/pytest-0/popen-gw{w}/"
+            f"test_served_launch_of_256_lane0" for w in (0, 1)]
+    assert len(deep[0]) == 15 + 66
+    tables = [ipc_endpoints(3, f"b{pid}v", d) for d in deep]
+    paths = [ln.split()[2] for t in tables for ln in t.splitlines()]
+    assert len(set(paths)) == 6 and all(len(p) <= 100 for p in paths)
+    short = len(f"{deep[0]}/dt_b{pid}v_n2.sock") <= 100
+    assert all(p.startswith(deep[0] + "/" if short else tmp + "/dt_")
+               for p in paths[:3])
+    # a short run directory keeps its sockets, as it always did
+    assert ipc_endpoints(1, "x", "/tmp") == "0 ipc /tmp/dt_x_n0.sock\n"
+    # and a temp dir that is itself too deep is still an error by name
+    monkeypatch.setattr(tempfile, "tempdir", "/tmp/" + "y" * 90)
+    with pytest.raises(ValueError, match="IPC socket path too long"):
+        ipc_endpoints(3, f"b{pid}v", deep[0] + "z" * 20)
