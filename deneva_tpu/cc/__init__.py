@@ -45,6 +45,12 @@ class CCBackend:
     needs_incidence: bool = True
     chained: bool = False      # engine executes commit levels as sub-rounds
     fresh_ts_on_restart: bool = True   # WAIT_DIE keeps its birth ts
+    # the device counter whose presence in the epoch's ``stats`` means
+    # "hand ``validate`` the stats dict": the backend counts what it
+    # decides where a server asked for its counters
+    # (`workloads/base.MVCC_COUNTERS` / `LOCK_COUNTERS`), and is called
+    # without them everywhere else
+    counts_in: str | None = None
     # single-pass forwarding executor (ops/forward): on blind-write
     # workloads the whole batch commits with reads forwarded in-batch —
     # no conflict matrix at all; chained path is the fallback otherwise
@@ -87,11 +93,11 @@ _REGISTRY: dict[CCAlg, CCBackend] = {
     # them within the window (row_lock.cpp:86-151) where epoch-snapshot
     # validation used to admit a single winner and abort-storm the rest
     CCAlg.NO_WAIT: CCBackend(CCAlg.NO_WAIT, validate_no_wait, _NO_STATE,
-                             exempt_order_free=True,
+                             counts_in="lock_die", exempt_order_free=True,
                              repair_rule=_twopl.repair_frontier),
     CCAlg.WAIT_DIE: CCBackend(CCAlg.WAIT_DIE, validate_wait_die, _NO_STATE,
                               fresh_ts_on_restart=False,
-                              exempt_order_free=True,
+                              counts_in="lock_die", exempt_order_free=True,
                               repair_rule=_twopl.repair_frontier),
     CCAlg.OCC: CCBackend(CCAlg.OCC, validate_occ, _NO_STATE,
                          exempt_order_free=True,
@@ -102,7 +108,7 @@ _REGISTRY: dict[CCAlg, CCBackend] = {
                                repair_rule=_tsmod.repair_frontier_timestamp),
     CCAlg.MVCC: CCBackend(CCAlg.MVCC, validate_mvcc, init_mvcc_state,
                           commit_state=commit_to_state,
-                          exempt_order_free=True,
+                          counts_in="mvcc_waits", exempt_order_free=True,
                           repair_rule=_tsmod.repair_frontier_mvcc),
     CCAlg.MAAT: CCBackend(CCAlg.MAAT, validate_maat, _NO_STATE,
                           exempt_order_free=True,

@@ -108,17 +108,32 @@ def _lock_edges(cfg, batch: AccessBatch, inc: Incidence):
     return e
 
 
-def validate_no_wait(cfg, state, batch: AccessBatch, inc: Incidence):
+def _count_locks(stats, die, wait, leftover) -> None:
+    """The sweep's three kinds of loser into the device counters, where
+    the ``stats`` dict carries them (the served 2PL program's:
+    `workloads/base.LOCK_COUNTERS`).  The host sees deaths as aborts and
+    waits and leftovers as one defer, so the split is counted here."""
+    if stats is None or "lock_die" not in stats:
+        return
+    for k, m in (("lock_die", die), ("lock_wait", wait),
+                 ("lock_leftover", leftover)):
+        stats[k] = stats[k] + m.sum(dtype=jnp.uint32)
+
+
+def validate_no_wait(cfg, state, batch: AccessBatch, inc: Incidence,
+                     stats=None):
     e = _lock_edges(cfg, batch, inc)
     if e is None:
         return validate_nocc(cfg, state, batch, inc)
     win, lose, und = greedy_first_fit(e, batch.active, rounds=cfg.sweep_rounds)
+    _count_locks(stats, lose, jnp.zeros_like(lose), und)
     v = Verdict(commit=win, abort=lose, defer=und,
                 order=batch.rank, level=jnp.zeros_like(batch.rank))
     return v, state
 
 
-def validate_wait_die(cfg, state, batch: AccessBatch, inc: Incidence):
+def validate_wait_die(cfg, state, batch: AccessBatch, inc: Incidence,
+                      stats=None):
     e = _lock_edges(cfg, batch, inc)
     if e is None:
         return validate_nocc(cfg, state, batch, inc)
@@ -128,6 +143,7 @@ def validate_wait_die(cfg, state, batch: AccessBatch, inc: Incidence):
     big = jnp.iinfo(jnp.int32).max
     min_owner_ts = jnp.where(blockers, batch.ts[None, :], big).min(axis=1)
     waits = lose & (batch.ts < min_owner_ts)   # older than every owner -> wait
+    _count_locks(stats, lose & ~waits, waits, und)
     v = Verdict(commit=win, abort=lose & ~waits, defer=und | waits,
                 order=batch.rank, level=jnp.zeros_like(batch.rank))
     return v, state

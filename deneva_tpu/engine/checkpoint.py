@@ -43,8 +43,10 @@ import numpy as np
 #         12 = PR 43 (MVCCState.lossy; the MVCC_COUNTERS device counters,
 #              where an MVCC server asks for them);
 #         13 = PR 45 (VersionRing stored by rows, uint8[R, 4*H], and the
-#              ring_push_lanes device counter among MVCC_COUNTERS).
-SCHEMA_VERSION = 13
+#              ring_push_lanes device counter among MVCC_COUNTERS);
+#         14 = PR 46 (the LOCK_COUNTERS device counters, where a 2PL
+#              server asks for them).
+SCHEMA_VERSION = 14
 
 
 def save_state(path: str, state) -> None:

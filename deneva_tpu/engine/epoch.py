@@ -310,10 +310,11 @@ def epoch_core(cfg: Config, wl, be, db, cc_state, stats, queries, batch, *,
             # DGCC takes the stats dict (repair-engine contract): its
             # wave/fallback/edge counters come from inside the wave
             # assignment, where the lane graph is in hand
-            # (and MVCC where the stats carry its counters: the served
-            # MVCC program's, `workloads/base.MVCC_COUNTERS`)
-            kw = {"stats": stats} if be.alg == CCAlg.DGCC or (
-                be.alg == CCAlg.MVCC and "mvcc_waits" in stats) else {}
+            # (and a backend that counts what it decides, where the
+            # stats carry its counters: the served MVCC and 2PL programs',
+            # `CCBackend.counts_in`)
+            kw = {"stats": stats} if be.alg == CCAlg.DGCC \
+                or stats.get(be.counts_in) is not None else {}
             verdict, cc_state = be.validate(cfg, cc_state, batch, inc, **kw)
         if stale is not None:
             verdict = dataclasses.replace(verdict,

@@ -40,7 +40,7 @@ from deneva_tpu.engine.epoch import (access_batch, count_verdict,
 from deneva_tpu.engine.pool import PoolState, TxnPool
 from deneva_tpu.ops import forwarding_applies
 from deneva_tpu.workloads.base import (APPEND_COUNTERS, EXEC_COUNTERS,
-                                       MVCC_COUNTERS)
+                                       LOCK_COUNTERS, MVCC_COUNTERS)
 
 LAT_BUCKETS = 64
 RETRY_BUCKETS = 8      # per-txn restart/wait counts at commit (clipped)
@@ -67,7 +67,8 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
                       append_lanes: bool = False,
                       recon_defers: bool = False,
                       mc_defer_passes: bool = False,
-                      mvcc_counters: bool = False) -> dict:
+                      mvcc_counters: bool = False,
+                      lock_counters: bool = False) -> dict:
     """``level_passes``: add ``level_pass_cnt`` and ``narrow_pass_cnt``,
     which `engine/epoch.run_levels` counts where it finds them (its
     passes, and those run under the batch's width) — asked for by
@@ -85,7 +86,10 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
     by the server of a forwarding backend on a mesh.  ``mvcc_counters``:
     likewise `workloads/base.MVCC_COUNTERS`, what MVCC decides and the
     lanes its version ring's row write is handed — asked for by an MVCC
-    server on one device."""
+    server on one device.  ``lock_counters``: likewise
+    `workloads/base.LOCK_COUNTERS`, the lock family's deaths, waits and
+    sweep-budget leftovers — asked for by a NO_WAIT / WAIT_DIE server on
+    one device."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z(), "narrow_pass_cnt": z()}
@@ -94,6 +98,7 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
         **({"mc_defer_pass_cnt": z()} if mc_defer_passes else {}),
         **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
         **({k: z() for k in MVCC_COUNTERS} if mvcc_counters else {}),
+        **({k: z() for k in LOCK_COUNTERS} if lock_counters else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the
         # contention-adaptive router's input).  Always present so the

@@ -69,6 +69,9 @@ OWNER: dict[str, str] = {
     "_resume_epoch": DISPATCH, "_inflight": DISPATCH,
     "_t_meas": DISPATCH, "_uniq_meas": DISPATCH, "_retry_meas": DISPATCH,
     "_wait_meas": DISPATCH,
+    # the lock family's host counter (waiters the defer budget restarts)
+    "_counts_locks": DISPATCH, "_lock_forced": DISPATCH,
+    "_lock_forced_meas": DISPATCH,
     # the dispatch loop's stage clock (runtime/stages.py): every
     # boundary call, its window snapshot and the queue's running count
     # are dispatch-thread positions (the retire WORKER opens only a

@@ -54,7 +54,7 @@ from deneva_tpu.runtime.native import NativeTransport
 from deneva_tpu.runtime.stages import StageClock, span as stage_span
 from deneva_tpu.stats import Stats
 from deneva_tpu.workloads.base import (APPEND_COUNTERS, EXEC_COUNTERS,
-                                       MVCC_COUNTERS)
+                                       LOCK_COUNTERS, MVCC_COUNTERS)
 
 _TAG_MASK = np.int64((1 << 40) - 1)
 
@@ -199,6 +199,14 @@ class ServerNode:
         # lanes deferred on a stale one (`cc/base.stale_recon`)
         counts_recon = self._counts_levels \
             and getattr(self.wl, "recon", None) is not None
+        # what the lock family decides, in the group program of one
+        # device (`workloads/base.LOCK_COUNTERS`: deaths, waits, sweep
+        # leftovers) and, on the host, the waiters the defer budget
+        # sends back as aborts (`lock_forced_restart_cnt`)
+        self._counts_locks = (
+            cfg.cc_alg in (CCAlg.NO_WAIT, CCAlg.WAIT_DIE)
+            and not self.vote_mode and cfg.device_parts == 1)
+        self._lock_forced = 0
         self.dev_stats = init_device_stats(
             len(getattr(self.wl, "txn_type_names", ("txn",))),
             level_passes=self._counts_levels,
@@ -212,7 +220,8 @@ class ServerNode:
             # what MVCC decides (`workloads/base.MVCC_COUNTERS`), in the
             # group program of one device
             mvcc_counters=(cfg.cc_alg == CCAlg.MVCC and not self.vote_mode
-                           and cfg.device_parts == 1))
+                           and cfg.device_parts == 1),
+            lock_counters=self._counts_locks)
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -2291,6 +2300,11 @@ class ServerNode:
                 stuck = df & (dfc[:n] >= self.defer_budget)
                 ab = ab | stuck
                 df = df & ~stuck
+                if self._counts_locks:
+                    # a waiter past its budget restarts as an abort (with
+                    # its timestamp): no death of the rule, so it is
+                    # counted apart from the device's `lock_die`
+                    self._lock_forced += int(stuck.sum())
             # exact unique-txn aborts (stats.h:60-61): first abort of a
             # txn is the one whose retry counter is still zero
             self._uniq_aborts += int((ab & (abort_cnt == 0)).sum())
@@ -2809,6 +2823,7 @@ class ServerNode:
                 self._t_meas = time.monotonic()
                 self._compiles_meas = self._compiles.snapshot()[0]
                 self._uniq_meas = self._uniq_aborts
+                self._lock_forced_meas = self._lock_forced
                 self._retry_meas = self._retry_hist.copy()
                 self._wait_meas = self._wait_hist.copy()
                 self._rep_meas = self._rep_salvaged
@@ -2981,6 +2996,12 @@ class ServerNode:
             for k in MVCC_COUNTERS:
                 if k in final:
                     self.info[f"run_{k[:-1]}_cnt"] = int(final[k])
+            # ... and, of a 2PL server, its deaths, waits and leftovers
+            # and the waiters the host's defer budget sent back
+            if self._counts_locks:
+                for k in LOCK_COUNTERS:
+                    self.info[f"run_{k}_cnt"] = int(final[k])
+                self.info["run_lock_forced_restart_cnt"] = self._lock_forced
         st = self.stats
         st.set("total_runtime", end - self._t_meas)
         st.set("epoch_cnt", float(epochs_run))
@@ -3000,6 +3021,11 @@ class ServerNode:
         for k in MVCC_COUNTERS:     # `<x>s` -> `<x>_cnt`, likewise
             if k in final:
                 st.set(k[:-1] + "_cnt", float(final[k] - measured[k]))
+        if self._counts_locks:      # a 2PL server's, `<x>` -> `<x>_cnt`
+            for k in LOCK_COUNTERS:
+                st.set(k + "_cnt", float(final[k] - measured[k]))
+            st.set("lock_forced_restart_cnt", float(
+                self._lock_forced - getattr(self, "_lock_forced_meas", 0)))
         by_type = final["commit_by_type"] - measured["commit_by_type"]
         for i, nm in enumerate(getattr(self.wl, "txn_type_names", ())):
             for fam in ("commit", "abort"):

@@ -82,6 +82,21 @@ MVCC_COUNTERS = ("mvcc_old_version_reads", "mvcc_history_aborts",
                  "mvcc_waits", "mvcc_ro_commits", "ring_push_lanes")
 
 
+# What the lock family decides (`cc/twopl.validate_no_wait` /
+# `validate_wait_die`), counted where the ``stats`` dict carries them:
+# losers that DIE (aborted: refused a lock and, under WAIT_DIE, not older
+# than every owner), losers that WAIT (WAIT_DIE: older than every owner,
+# deferred with their timestamp) and the sweep budget's LEFTOVERS (lanes
+# `sweep_rounds` left undecided, deferred: neither granted nor refused).
+# The host sees deaths as aborts and both other kinds as one defer, so
+# the split is counted where it is decided.  NOT part of
+# ``EXEC_COUNTERS``: a 2PL server on one device asks for them
+# (`engine/step.init_device_stats(lock_counters=True)`), so every other
+# program's stats pytree is what it was.  `[summary]` reads ``<x>`` as
+# ``<x>_cnt``.
+LOCK_COUNTERS = ("lock_die", "lock_wait", "lock_leftover")
+
+
 def partition_owned(key: jax.Array, n_parts: int, me: int) -> jax.Array:
     """bool mask: does this node own ``key`` under modulo striping
     (reference GET_NODE_ID, `system/global.h:294`)?"""

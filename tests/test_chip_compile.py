@@ -459,6 +459,52 @@ def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
     assert re.search(rf"f32\[{b},{b}\]\S* fusion\(", hlo)
 
 
+# ---- the WAIT_DIE cell (PR 46): ycsb_fullrow_waitdie.medium ----------------
+
+def test_waitdie_cell_group_fits_the_chip_and_sweeps_under_validate(
+        one_chip, monkeypatch):
+    """The WAIT_DIE cell's group program at its served size, with the
+    lock counters its server asks for, on the chip's own HLO: it fits
+    beside two groups in flight; the lock table — the [B, B] compare of
+    the exact keys, the sweep's matvecs and the age test's masked min —
+    carries `ep.validate`; nothing of an arena's size anywhere, and the
+    program's only scatters are the row write's."""
+    from deneva_tpu.engine.step import init_device_stats
+    cfg = _cell_cfg("ycsb_fullrow_waitdie.medium")
+    b, k = cfg.epoch_batch, cfg.conflict_buckets
+    assert (cfg.cc_alg, cfg.isolation_level, b, cfg.sweep_rounds,
+            cfg.defer_rounds_max) == ("WAIT_DIE", "SERIALIZABLE", 1024,
+                                      24, 8)
+    group, state, feed = _group_program(cfg, monkeypatch)
+    state["stats"] = jax.eval_shape(
+        lambda: init_device_stats(1, lock_counters=True))
+    assert {"lock_die", "lock_wait", "lock_leftover"} <= set(state["stats"])
+    state, feed = _with_sharding((state, feed), one_chip)
+    compiled, secs = _compile(group, state["db"], state["cc_state"],
+                              state["stats"], *feed)
+    need = _report("waitdie_cell_group", compiled, secs)
+    table = sum(x.size * x.dtype.itemsize
+                for x in jax.tree.leaves(state["db"]))
+    assert table + cfg.pipeline_groups * (need - table) < HBM_BYTES
+    hlo = compiled.as_text()
+    assert f"[{b},{k}]" not in hlo and f"[{b * k}]" not in hlo
+    validate = [ln for ln in hlo.splitlines() if re.search(
+        r'op_name="[^"]*/ep\.validate/', ln)]
+    assert len(validate) > 100
+    assert any(" compare(" in ln for ln in validate)
+    # the sweep's rounds: a loop of [B, B] x [B] products under the scope
+    assert any(re.search(r" (dot|convolution)\(", ln) or " while(" in ln
+               for ln in validate)
+    f0 = state["db"]["MAIN_TABLE"].columns["F0"]
+    scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
+    assert scatters and all(
+        f"= u8[{f0.shape[0]},{f0.shape[1]}]" in ln for ln in scatters)
+    # the matrix itself: ONE fusion of A x A compares, handed on twice —
+    # as f32 to the sweep's products, as pred to the age test
+    assert re.search(rf"= \(f32\[{b},{b}\]\S*, pred\[{b},{b}\]\S*\) "
+                     r"fusion\(", hlo)
+
+
 # ---- the MVCC cell (PR 43): ycsb_fullrow_mvcc.medium ----------------------
 
 def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
