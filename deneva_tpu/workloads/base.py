@@ -49,8 +49,8 @@ EXEC_COUNTERS = (
     # count it says how far the winner compaction engages
     "write_scatter_lanes",
     # lanes handed to YCSB's F0 gather: under a forwarding plan with
-    # full rows the unforwarded reads, in whole sixteenths of the
-    # plan (ops/gather.checksum_needed_rows), else every lane
+    # full rows the unforwarded reads, in whole calls of the loop
+    # (ops/gather.checksum_needed_rows), else every lane
     "read_gather_lanes",
 )
 

@@ -123,9 +123,10 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     benchmark numbers measure reference-width HBM traffic.
 
     The read half.  Full rows: only the reads that nothing forwards to
-    (``is_read & (fwd < 0)``) reach the row gather, compacted first —
-    write lanes, forwarded reads and a shard's padding lanes never do,
-    and a lane costs the gather the same whatever it reads
+    (``is_read & (fwd < 0)``) reach the row gather, compacted first and
+    then gathered in a loop of short calls — write lanes, forwarded
+    reads and a shard's padding lanes never do, and a lane costs the
+    gather by the lane count of its call, whatever it reads
     (`ops.gather.checksum_needed_rows`); the forwarded reads add the
     bytes of f(key, writer rank).  The checksum is the per-lane
     gather's, to the bit.  Fingerprints (4.9 ns a uint32 lane): every

@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import PHASES, served_cfg
 from deneva_tpu.config import Config
+from deneva_tpu.ops import gather as G
 
 HBM_BYTES = 16 * 1024 ** 3      # one v5e chip (Google Cloud "TPU v5e")
 
@@ -158,6 +159,27 @@ def _row_gathers(hlo: str, width: int) -> set[int]:
         r"= u8\[(\d+)," + str(width) + r"\]\S* gather\(", hlo)}
 
 
+def _looped_row_gathers(hlo: str, width: int) -> list[tuple[int, list[str]]]:
+    """(lanes, scopes of its op_name) of every row gather of ``width``-
+    byte rows; each has to sit in a `while`'s body — itself, or the
+    fusion that calls its computation."""
+    bodies = set(re.findall(r" while\(.*?body=(%[\w.\-]+)", hlo))
+    where, caller, found = None, {}, []
+    for ln in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(", ln)
+        if head:
+            where = head.group(1)
+        for called in re.findall(r"calls=(%[\w.\-]+)", ln):
+            caller[called] = where
+        m = re.search(r"= u8\[(\d+)," + str(width) + r"\]\S* gather\(", ln)
+        if m:
+            name = re.search(r'op_name="([^"]*)"', ln).group(1)
+            found.append((int(m.group(1)), name.split("/"), where, ln))
+    for _, _, where, ln in found:
+        assert where in bodies or caller.get(where) in bodies, ln
+    return [f[:2] for f in found]
+
+
 def _scatter_lanes(hlo: str, shape: str) -> list[int]:
     """Lanes of every scatter into an array of ``shape``: the rows of
     the scatter's index operand."""
@@ -181,11 +203,12 @@ def test_full_row_column_is_written_in_place_inside_the_epoch_scan(
     is the cure).  The only copies of the column are the entry
     computation's two relayouts, once a GROUP, as at the parent.
 
-    Since PR 30 the forwarding executor's reads come out of a loop too
-    (`ops.gather.checksum_needed_rows`) ahead of that write are
-    gathers of one to sixteen sixteenths of the plan's lanes inside the
-    branches of a conditional: the same pin holds (OCC's masked path
-    has no plan and gathers its 10,240 lanes in one call, as it did)."""
+    Since PR 47 the forwarding executor's reads come out of a loop too
+    (`ops.gather.checksum_needed_rows`), ahead of that write: ONE row
+    gather of N / 64 lanes in a `while` body under `ep.read`, which
+    closes over the column — the same pin holds with both loops in the
+    program (OCC's masked path has no plan and gathers its 10,240 lanes
+    in one call, as it did)."""
     cfg = served_cfg(sim_full_row="true", synth_table_size=1 << 21, **over)
     group, state, feed = _group_program(cfg, monkeypatch)
     state, feed = _with_sharding((state, feed), one_chip)
@@ -195,10 +218,14 @@ def test_full_row_column_is_written_in_place_inside_the_epoch_scan(
     hlo = compiled.as_text()
     copies = _column_copies(hlo, f"u8[{f0.shape[0]},{f0.shape[1]}]")
     assert copies and set(copies) == {"entry"}, copies
+    lanes = cfg.epoch_batch * cfg.req_per_query
     if cfg.cc_alg == "TPU_BATCH":
-        lanes = cfg.epoch_batch * cfg.req_per_query
-        assert _row_gathers(hlo, f0.shape[1]) == {
-            k * lanes // 16 for k in range(1, 17)}
+        ((got, scopes),) = _looped_row_gathers(hlo, f0.shape[1])
+        assert got == -(-lanes // G._CHUNKS)
+        assert scopes[scopes.index("ep.read"):][1:3] == ["while", "body"]
+    else:
+        assert re.findall(r"= u8\[([\d,]+),100\]\S* gather\(", hlo) == [
+            f"{cfg.epoch_batch},{cfg.req_per_query}"]
 
 
 def test_ycsb_loader_compiles_for_v5e(one_chip):
@@ -403,8 +430,9 @@ def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
     """The one-chip pin (`test_full_row_column_is_written_in_place_
     inside_the_epoch_scan`) on the four-chip cell: no chip copies its
     629 MB shard of the column inside an epoch — the two relayouts in
-    the entry computation stay, once a group — and of a shard's 81,920
-    plan lanes a whole number of sixteenths reaches the row gather."""
+    the entry computation stay, once a group — and a shard's 81,920
+    plan lanes reach the row gather 1,280 a call, in a `while` body
+    under `ep.read` (PR 47)."""
     cfg, _, state, compiled, _ = dp4_cell
     f0 = state["db"]["MAIN_TABLE"].columns["F0"]
     rows, width = f0.sharding.shard_shape(f0.shape)
@@ -415,8 +443,9 @@ def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
     lanes = 4 * mc_pair_cap(cfg.epoch_batch, cfg.req_per_query, 4,
                             cfg.mc_plan_capacity)
     assert lanes == 81_920
-    assert _row_gathers(hlo, width) == {k * lanes // 16
-                                        for k in range(1, 17)}
+    ((got, scopes),) = _looped_row_gathers(hlo, width)
+    assert got == -(-lanes // G._CHUNKS)
+    assert scopes[scopes.index("ep.read"):][1:3] == ["while", "body"]
 
 
 # ---- the OCC cell (PR 32): ycsb_fullrow_occ.medium ------------------------

@@ -71,13 +71,13 @@ def _column(rows: int, rng):
 
 def _lanes(cnt: int, n: int) -> tuple[int, int]:
     """What the gather is handed for ``cnt`` needed lanes of ``n``: whole
-    sixteenths covering them while the sort pays, else every lane (the
-    sixteenths rounded up); and whether it compacted."""
-    rung = -(-n // G._RUNGS)
-    need = rung * -(-cnt // rung)
+    chunks of ``ceil(n / 64)`` lanes covering them while the sort pays,
+    else every lane (the chunks rounded up); and whether it compacted."""
+    chunk = -(-n // G._CHUNKS)
+    need = chunk * -(-cnt // chunk)
     if need + n // G._SORT_PER_LANES < n:
         return need, True
-    return rung * G._RUNGS, False
+    return chunk * -(-n // chunk), False
 
 
 _EPOCHS = [(k, m) for k in ("0.0", "0.6", "0.9", "one_key")
@@ -92,8 +92,8 @@ def test_compacted_read_is_the_per_lane_checksum_and_table(keys_of, mix,
     """`_forward_execute_f0` with full rows against the per-lane gather:
     the same `read_checksum` to the bit and the same table, at every
     skew, with all reads / no reads / the `hot` mix, with masked lanes
-    and a shard's `big` padding; the gather is handed whole sixteenths
-    of the plan covering the unforwarded reads, or every lane where the
+    and a shard's `big` padding; the gather is handed whole chunks of
+    the plan covering the unforwarded reads, or every lane where the
     sort cannot pay."""
     rng = np.random.default_rng(list(f"{keys_of}/{mix}/{lanes}".encode()))
     n, tab = 2048, 3000
@@ -141,39 +141,86 @@ def test_a_txn_that_reads_and_writes_one_key_reads_the_epochs_row():
         assert int(cks) == want
 
 
-_COUNTS = ["none", "one", "two_rungs", "two_rungs_and_one", "edge",
+_COUNTS = ["none", "one", "a_chunk", "a_chunk_and_one", "edge",
            "edge_and_one", "all"]
 
 
-@pytest.mark.parametrize("n", [1024, 1000], ids=["whole_rungs",
-                                                 "ragged_last_rung"])
-@pytest.mark.parametrize("count", _COUNTS)
-def test_checksum_needed_rows_follows_the_count(count, n):
-    """`checksum_needed_rows` alone: the sum of the needed lanes' rows
-    (a row needed twice counts twice) whatever their number — none, not
-    a multiple of a sixteenth, just below and just above where the
-    `lax.cond` stops compacting, every lane — and the lanes it reports
-    are what its branch issued: whole sixteenths covering the needed
-    lanes, or every lane of the plan."""
-    rng = np.random.default_rng(list(f"{count}/{n}".encode()))
-    rows = 5000
-    rung = -(-n // G._RUNGS)
-    edge = (n - n // G._SORT_PER_LANES - 1) // rung
-    cnt = {"none": 0, "one": 1, "two_rungs": 2 * rung,
-           "two_rungs_and_one": 2 * rung + 1, "edge": edge * rung,
-           "edge_and_one": edge * rung + 1, "all": n}[count]
+def _count_case(count: str, n: int, rows: int, rng):
+    """(cnt, slots, need, col) of one count case over ``n`` lanes."""
+    chunk = -(-n // G._CHUNKS)
+    edge = (n - n // G._SORT_PER_LANES - 1) // chunk
+    assert 1 < edge < G._CHUNKS
+    cnt = {"none": 0, "one": 1, "a_chunk": chunk,
+           "a_chunk_and_one": chunk + 1, "edge": edge * chunk,
+           "edge_and_one": edge * chunk + 1, "all": n}[count]
     slots = rng.integers(0, rows, n).astype(np.int32)   # rows repeat
     need = np.zeros(n, bool)
     need[rng.choice(n, cnt, replace=False)] = True
-    col = _column(rows, rng)
+    return cnt, slots, need, _column(rows, rng)
+
+
+def _per_row_sum(col, slots, need) -> int:
+    per_row = np.asarray(col).astype(np.uint64).sum(axis=1)
+    return int(per_row[slots[need]].sum() % (1 << 32))
+
+
+@pytest.mark.parametrize("n", [1024, 1000], ids=["whole_chunks",
+                                                 "ragged_last_chunk"])
+@pytest.mark.parametrize("count", _COUNTS)
+def test_checksum_needed_rows_follows_the_count(count, n):
+    """`checksum_needed_rows` alone, under `jax.jit`: the sum of the
+    needed lanes' rows (a row needed twice counts twice) whatever their
+    number — none, one, exactly a chunk, a chunk and one, just below and
+    just above where the `lax.cond` stops compacting, every lane — and
+    the lanes it reports are what its loop issued: whole chunks covering
+    the needed lanes (at most a chunk over them), or every lane of the
+    plan."""
+    rng = np.random.default_rng(list(f"{count}/{n}".encode()))
+    cnt, slots, need, col = _count_case(count, n, 5000, rng)
     got, lanes = jax.jit(checksum_needed_rows)(col, jnp.asarray(slots),
                                                jnp.asarray(need))
-    per_row = np.asarray(col).astype(np.uint64).sum(axis=1)
-    assert int(got) == int(per_row[slots[need]].sum() % (1 << 32))
+    assert int(got) == _per_row_sum(col, slots, need)
     want_lanes, compacted = _lanes(cnt, n)
     assert compacted == (count not in ("edge_and_one", "all"))
-    assert int(lanes) == want_lanes
-    assert 0 < edge < G._RUNGS
+    chunk = -(-n // G._CHUNKS)
+    assert int(lanes) == want_lanes and want_lanes % chunk == 0
+    assert not compacted or cnt <= want_lanes < cnt + chunk
+
+
+@pytest.mark.parametrize("n", [1024, 1000], ids=["whole_chunks",
+                                                 "ragged_last_chunk"])
+@pytest.mark.parametrize("count", _COUNTS)
+def test_four_shards_each_loop_over_their_own_count(count, n):
+    """The same cases on a CPU mesh of four against one device: every
+    shard gathers from its own block of the column with its own count —
+    shard 0 the case's, the others none, every lane and the case's
+    neighbour — so the four loops run different numbers of trips side by
+    side; sum and lanes of each are what one device gives for that
+    shard's lanes alone."""
+    from jax.sharding import PartitionSpec as P
+    from deneva_tpu.parallel import make_mesh
+    from deneva_tpu.parallel.mesh import AXIS
+    rng = np.random.default_rng(list(f"mesh/{count}/{n}".encode()))
+    rows = 1250
+    i = _COUNTS.index(count)
+    shards = [_count_case(c, n, rows, rng) for c in (
+        count, "none", "all", _COUNTS[(i + 1) % len(_COUNTS)])]
+    col = jnp.concatenate([s[3] for s in shards])
+    slots = jnp.asarray(np.stack([s[1] for s in shards]))
+    need = jnp.asarray(np.stack([s[2] for s in shards]))
+
+    def shard(col, slots, need):
+        got, lanes = checksum_needed_rows(col, slots[0], need[0])
+        return got[None], lanes[None]
+
+    got, lanes = jax.jit(jax.shard_map(
+        shard, mesh=make_mesh(4), in_specs=(P(AXIS), P(AXIS), P(AXIS)),
+        out_specs=(P(AXIS), P(AXIS))))(col, slots, need)
+    for d, (cnt, s, nd, c) in enumerate(shards):
+        one, one_lanes = checksum_needed_rows(c, jnp.asarray(s),
+                                              jnp.asarray(nd))
+        assert int(got[d]) == int(one) == _per_row_sum(c, s, nd)
+        assert int(lanes[d]) == int(one_lanes) == _lanes(cnt, n)[0]
 
 
 def test_the_sum_wraps_like_the_per_lane_sum():
@@ -240,3 +287,30 @@ def test_execute_mc_on_a_mesh_of_four_reads_what_one_device_reads(theta):
     lanes = 6 * 256 * 4
     assert 0 < int(s4["read_gather_lanes"]) < 4 * lanes // 2
     assert 0 < int(s1["read_gather_lanes"]) < lanes
+
+
+def test_the_chip_tool_rehearses_on_the_cpu():
+    """`tools/gather_calls.py` (the micro-run behind `_CHUNKS`) in a
+    process of its own, on the CPU at a toy column: every form it times
+    passed its own check against the per-lane sum, and the loop was
+    handed whole calls covering the needed lanes at both plans."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "gather_calls.py"),
+         "--platform", "cpu", "--rows", "20000", "--reps", "1",
+         "--lanes", "1280", "5120"],
+        capture_output=True, text=True, timeout=300, check=True).stdout
+    plans = [json.loads(ln.split(" ", 1)[1]) for ln in out.splitlines()
+             if ln.startswith("[gather_calls] ")]
+    assert [(p["plan_lanes"], p["needed"]) for p in plans] == [
+        (163_840, 90_000), (81_920, 24_000)]
+    for p in plans:
+        assert p["device"]["platform"] == "cpu"
+        assert len(p["one_call"]) == 2
+        for lanes, r in p["loop"].items():
+            assert p["needed"] <= r["handed"] < p["needed"] + int(lanes)
+            assert r["handed"] % int(lanes) == 0
