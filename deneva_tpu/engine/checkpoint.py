@@ -45,8 +45,10 @@ import numpy as np
 #         13 = PR 45 (VersionRing stored by rows, uint8[R, 4*H], and the
 #              ring_push_lanes device counter among MVCC_COUNTERS);
 #         14 = PR 46 (the LOCK_COUNTERS device counters, where a 2PL
-#              server asks for them).
-SCHEMA_VERSION = 14
+#              server asks for them);
+#         15 = PR 48 (the write_row_groups device counter, where a
+#              server that writes full rows asks for it).
+SCHEMA_VERSION = 15
 
 
 def save_state(path: str, state) -> None:

@@ -40,7 +40,8 @@ from deneva_tpu.engine.epoch import (access_batch, count_verdict,
 from deneva_tpu.engine.pool import PoolState, TxnPool
 from deneva_tpu.ops import forwarding_applies
 from deneva_tpu.workloads.base import (APPEND_COUNTERS, EXEC_COUNTERS,
-                                       LOCK_COUNTERS, MVCC_COUNTERS)
+                                       LOCK_COUNTERS, MVCC_COUNTERS,
+                                       ROW_GROUP_COUNTER)
 
 LAT_BUCKETS = 64
 RETRY_BUCKETS = 8      # per-txn restart/wait counts at commit (clipped)
@@ -68,7 +69,8 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
                       recon_defers: bool = False,
                       mc_defer_passes: bool = False,
                       mvcc_counters: bool = False,
-                      lock_counters: bool = False) -> dict:
+                      lock_counters: bool = False,
+                      row_groups: bool = False) -> dict:
     """``level_passes``: add ``level_pass_cnt`` and ``narrow_pass_cnt``,
     which `engine/epoch.run_levels` counts where it finds them (its
     passes, and those run under the batch's width) — asked for by
@@ -89,7 +91,11 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
     server on one device.  ``lock_counters``: likewise
     `workloads/base.LOCK_COUNTERS`, the lock family's deaths, waits and
     sweep-budget leftovers — asked for by a NO_WAIT / WAIT_DIE server on
-    one device."""
+    one device.  ``row_groups``: likewise
+    `workloads/base.ROW_GROUP_COUNTER`, the tile groups the row write's
+    kernel writes back — asked for by the server of a workload that
+    writes full rows through `ops.scatter.scatter_winner_rows` (YCSB
+    under ``sim_full_row``)."""
     z = lambda: jnp.zeros((), jnp.uint32)  # noqa: E731
     return {
         **({"level_pass_cnt": z(), "narrow_pass_cnt": z()}
@@ -99,6 +105,7 @@ def init_device_stats(n_txn_types: int = 1, n_parts: int = 1,
         **({k: z() for k in APPEND_COUNTERS} if append_lanes else {}),
         **({k: z() for k in MVCC_COUNTERS} if mvcc_counters else {}),
         **({k: z() for k in LOCK_COUNTERS} if lock_counters else {}),
+        **({ROW_GROUP_COUNTER: z()} if row_groups else {}),
         # per-partition observed-conflict density (cc/base.
         # conflict_density; the metrics bus's contention signal and the
         # contention-adaptive router's input).  Always present so the

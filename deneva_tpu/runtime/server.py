@@ -54,7 +54,8 @@ from deneva_tpu.runtime.native import NativeTransport
 from deneva_tpu.runtime.stages import StageClock, span as stage_span
 from deneva_tpu.stats import Stats
 from deneva_tpu.workloads.base import (APPEND_COUNTERS, EXEC_COUNTERS,
-                                       LOCK_COUNTERS, MVCC_COUNTERS)
+                                       LOCK_COUNTERS, MVCC_COUNTERS,
+                                       ROW_GROUP_COUNTER)
 
 _TAG_MASK = np.int64((1 << 40) - 1)
 
@@ -221,7 +222,10 @@ class ServerNode:
             # group program of one device
             mvcc_counters=(cfg.cc_alg == CCAlg.MVCC and not self.vote_mode
                            and cfg.device_parts == 1),
-            lock_counters=self._counts_locks)
+            lock_counters=self._counts_locks,
+            # the tile groups the row write's kernel writes back
+            # (`workloads/base.ROW_GROUP_COUNTER`)
+            row_groups=getattr(self.wl, "writes_row_groups", False))
         jax.block_until_ready(self.db)
         self.info["load_s"] = round(time.monotonic() - t_load, 3)
 
@@ -3018,6 +3022,9 @@ class ServerNode:
                   "mc_defer_pass_cnt"):
             if k in final:      # (where this server's stats carry them)
                 st.set(k, float(final[k] - measured[k]))
+        if ROW_GROUP_COUNTER in final:      # `<x>s` -> `<x>_cnt`, likewise
+            st.set(ROW_GROUP_COUNTER[:-1] + "_cnt", float(
+                final[ROW_GROUP_COUNTER] - measured[ROW_GROUP_COUNTER]))
         for k in MVCC_COUNTERS:     # `<x>s` -> `<x>_cnt`, likewise
             if k in final:
                 st.set(k[:-1] + "_cnt", float(final[k] - measured[k]))

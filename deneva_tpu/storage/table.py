@@ -132,12 +132,13 @@ class DeviceTable:
         (`deneva_tpu.ops.scatter.scatter_winner_rows`: values computed
         from ``carry`` after compaction; the trash row is never written;
         ``after`` = what was read from the column, ordered first).
-        Returns (table, lanes handed to the scatter, after)."""
-        col, lanes, after = scatter_winner_rows(
+        Returns (table, lanes handed to the row write, tile groups its
+        kernel wrote back, after)."""
+        col, lanes, groups, after = scatter_winner_rows(
             self.columns[name], slots, win, carry, value_fn, self.capacity,
             after)
         return (self._replace(columns={**self.columns, name: col}), lanes,
-                after)
+                groups, after)
 
     def scatter_add(self, slots: jax.Array, updates: dict[str, jax.Array],
                     mask: jax.Array | None = None) -> "DeviceTable":
@@ -342,7 +343,7 @@ class VersionRing:
             ).reshape(lane.shape[0], 4 * h)
 
         lane = jnp.arange(slots.shape[0], dtype=jnp.int32)
-        ring, lanes, _ = scatter_winner_rows(
+        ring, lanes, _, _ = scatter_winner_rows(
             self.wts, slots, mask, (lane,), new_rows,
             self.wts.shape[0] - 1, after=vw)
         if stats is not None and "ring_push_lanes" in stats:

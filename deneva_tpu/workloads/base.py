@@ -55,6 +55,19 @@ EXEC_COUNTERS = (
 )
 
 
+# The tile groups the row write's kernel writes back
+# (`ops/scatter.scatter_winner_rows`: 32 rows of a byte column are one
+# group; a group goes back once a call, whatever its winners) — against
+# ``write_scatter_lanes`` the share of lanes that merged into a
+# neighbour's group, times a group's bytes the kernel's traffic.  NOT
+# part of ``EXEC_COUNTERS``: the server of a workload whose executor
+# writes full rows through it asks for it
+# (`engine/step.init_device_stats(row_groups=True)`), so every other
+# program's stats pytree is what it was.  `[summary]` reads it as
+# ``write_row_group_cnt``.
+ROW_GROUP_COUNTER = "write_row_groups"
+
+
 # What `storage/table.DeviceTable.append` counts where the ``stats`` dict
 # it is handed carries them: live lanes written through a window, and
 # lanes of calls that fell back to the scatter (more lanes than the table

@@ -105,9 +105,9 @@ class McTableView:
 
     def scatter_winners(self, name, slots, win, carry, value_fn, after):
         loc, _ = self._loc(slots)
-        local, lanes, after = self.local.scatter_winners(
+        local, lanes, groups, after = self.local.scatter_winners(
             name, loc, win, carry, value_fn, after)
-        return self._with(local), lanes, after
+        return self._with(local), lanes, groups, after
 
     def scatter_add(self, slots, updates, mask=None) -> "McTableView":
         loc, _ = self._loc(slots)

@@ -138,10 +138,16 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     follows the plan's sorted key order and masked lanes steer to a
     trash at/above the top): `ops.scatter.scatter_winner_rows` — the
     final writers are compacted to the front and only they reach the
-    row scatter, in chunks whose number follows the epoch's winner
-    count (on v5e a row lane costs 71 ns one by one, or the whole column
-    a 4 ms pass with the sorted promise, whatever the lanes: PERF.md
-    section 6, PR 26); the trash row is never written.  Fingerprints
+    row write, in chunks whose number follows the epoch's winner
+    count: a chunk's rows go through ONE Pallas kernel that moves
+    32-row tile groups between HBM and VMEM, 256 in flight, on the TPU
+    where a call holds 2,560 lanes (the hot cell), and through XLA's
+    scatter elsewhere (on v5e XLA writes a row lane in 71 ns one by
+    one, or the whole column in a 4 ms pass with the sorted promise,
+    whatever the lanes; the kernel writes a lane in 40-45 ns in calls
+    of 2,560, whatever the skew, in 66 in calls of 1,280 and in 90 or
+    more in calls of 160: PERF.md section 6, PRs 26 and 48);
+    the trash row is never written.  Fingerprints
     under ``mono``: every lane is issued with MONOTONE, pre-sorted
     indices — ``cummax`` carries the latest winner's slot into following
     lanes and two head-propagation scans carry its (key, rank), so the
@@ -150,8 +156,10 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
     inside a scatter's lowering (~0.6 ms at 655k lanes on v5e), and a
     uint32 lane costs the same 4.9 ns either way (BASELINE.md).  The
     legacy trash-steered scatter remains for non-monotone slot maps
-    (mono=False).  Returns the lanes handed to the scatter as a fourth
-    value (`stats["write_scatter_lanes"]`).
+    (mono=False).  Returns the lanes handed to the row write as a fourth
+    value (`stats["write_scatter_lanes"]`) and the tile groups its
+    kernel wrote back for them as a sixth (`stats["write_row_groups"]`,
+    where the server asked for it; 0 on the two other forms).
 
     The two halves carry the epoch's `ep.read` / `ep.write` scopes
     (metadata: `engine/epoch.make_epoch_body`)."""
@@ -171,9 +179,9 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
                              _field_fingerprint(p.keys, p.fwd), vals)
             cks = jnp.sum(jnp.where(p.is_read, vals, 0), dtype=jnp.uint32)
     with jax.named_scope("ep.write"):
-        lanes = jnp.uint32(slots.shape[0])
+        lanes, groups = jnp.uint32(slots.shape[0]), jnp.uint32(0)
         if mono and f0.ndim == 2:
-            f0, lanes, cks = scatter_winner_rows(
+            f0, lanes, groups, cks = scatter_winner_rows(
                 f0, slots, p.win, (p.keys, p.rank),
                 lambda k, r: _field_bytes(k, r, nbytes), n_rows=trash,
                 after=cks)
@@ -188,7 +196,15 @@ def _forward_execute_f0(f0: jax.Array, p, slots: jax.Array, trash,
                 else _field_fingerprint(p.keys, p.rank).astype(f0.dtype)
             f0 = f0.at[jnp.where(p.win, slots, trash)].set(wvals)
         wcnt = p.is_write.sum(dtype=jnp.uint32)
-    return f0, cks, wcnt, lanes, rlanes
+    return f0, cks, wcnt, lanes, rlanes, groups
+
+
+def _count_row_groups(stats: dict, groups) -> None:
+    """The tile groups the row write's kernel wrote back, where the
+    server asked for the counter
+    (`engine/step.init_device_stats(row_groups=True)`)."""
+    if "write_row_groups" in stats:     # workloads/base.ROW_GROUP_COUNTER
+        stats["write_row_groups"] = stats["write_row_groups"] + groups
 
 
 def _count(stats: dict, **add) -> None:
@@ -220,6 +236,10 @@ class YCSBWorkload:
                       for i in range(cfg.field_per_tuple))
             + "INDEX=MAIN_INDEX\n\tMAIN_TABLE,0\n")
         self.n_rows = cfg.synth_table_size
+        # full rows are written through `ops.scatter.scatter_winner_rows`:
+        # a server counts the tile groups its kernel writes back
+        # (`workloads/base.ROW_GROUP_COUNTER`)
+        self.writes_row_groups = bool(cfg.sim_full_row)
         # partitioned deployment (reference `key % g_part_cnt` node
         # ownership, ycsb_wl.cpp:70-74 / global.h:294): this node stores
         # only keys ≡ node_id (mod part_cnt); the strided index steers
@@ -605,17 +625,18 @@ class YCSBWorkload:
             # mono holds per shard: plan keys are sorted with non-owned
             # lanes already masked to the big sentinel, so slots ascend
             # toward the block-local trash at the top
-            f0, cks, wcnt, lanes, rlanes = _forward_execute_f0(
+            f0, cks, wcnt, lanes, rlanes, groups = _forward_execute_f0(
                 f0, p, slots, trash, mono=True)
             return (f0, jax.lax.psum(cks, AXIS), jax.lax.psum(wcnt, AXIS),
                     jax.lax.psum(lanes, AXIS), jax.lax.psum(rlanes, AXIS),
-                    ran, dfr)
+                    jax.lax.psum(groups, AXIS), ran, dfr)
 
         with jax.named_scope("ep.exchange"):
-            f0, cks, wcnt, lanes, rlanes, passes, dfr = jax.shard_map(
+            (f0, cks, wcnt, lanes, rlanes, groups, passes,
+             dfr) = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(AXIS), P(), P(), P(), P(), P()),
-                out_specs=(P(AXIS), P(), P(), P(), P(), P(),
+                out_specs=(P(AXIS), P(), P(), P(), P(), P(), P(),
                            P(AXIS) if pair_cap else P()))(
                     tab.columns["F0"], batch.keys, batch.rank, batch.ts,
                     batch.is_write, valid)
@@ -630,6 +651,7 @@ class YCSBWorkload:
             # its scope from these consumers)
             _count(stats, read_checksum=cks, write_cnt=wcnt,
                    write_scatter_lanes=lanes, read_gather_lanes=rlanes)
+            _count_row_groups(stats, groups)
             if "mc_defer_pass_cnt" in stats:
                 # shard-epochs that RAN the defer pass (asked for by the
                 # server of a mesh: `engine/step.init_device_stats`)
@@ -698,11 +720,12 @@ class YCSBWorkload:
             # under part_cnt striping (or an elastic mask at n_parts>1)
             # non-owned keys hit miss_slot INTERLEAVED between owned
             # slots — not monotone
-            f0, cks, wcnt, lanes, rlanes = _forward_execute_f0(
+            f0, cks, wcnt, lanes, rlanes, groups = _forward_execute_f0(
                 tab.columns["F0"], p, slots, tab.capacity,
                 mono=self.n_parts == 1)
             _count(stats, read_checksum=cks, write_cnt=wcnt,
                    write_scatter_lanes=lanes, read_gather_lanes=rlanes)
+            _count_row_groups(stats, groups)
             db = dict(db)
             db[TABLE] = tab._replace(columns={**tab.columns, "F0": f0})
             return db
@@ -788,11 +811,12 @@ class YCSBWorkload:
                 # the winners alone reach the row scatter, compacted, and
                 # the trash row is never written; the gather above (all
                 # of it is in the checksum) comes first (ops/scatter)
-                db[TABLE], lanes, stats["read_checksum"] = \
+                db[TABLE], lanes, groups, stats["read_checksum"] = \
                     tab.scatter_winners(
                         "F0", wslots, win, (wkeys, worder),
                         lambda k, o: _field_bytes(k, o, self.cfg.tup_size),
                         after=stats["read_checksum"])
+                _count_row_groups(stats, groups)
             else:
                 db[TABLE] = tab.scatter(
                     wslots, {"F0": _field_fingerprint(wkeys, worder)},

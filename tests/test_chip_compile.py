@@ -58,6 +58,14 @@ def _with_sharding(tree, sharding):
         tree)
 
 
+def _counts_row_groups(cfg: Config) -> bool:
+    """Whether the cell's server counts the row write's tile groups
+    (PR 48: one whose workload writes full rows through
+    `ops.scatter.scatter_winner_rows` — YCSB under ``sim_full_row``)."""
+    from deneva_tpu.workloads import get_workload
+    return getattr(get_workload(cfg), "writes_row_groups", False)
+
+
 def _group_program(cfg: Config, monkeypatch):
     """(jitted C-epoch group, abstract args) exactly as `ServerNode`
     builds them, state and feed described instead of allocated."""
@@ -76,7 +84,8 @@ def _group_program(cfg: Config, monkeypatch):
     state = {"db": jax.eval_shape(wl.load),
              "cc_state": jax.eval_shape(lambda: be.init_state(cfg)),
              "stats": jax.eval_shape(lambda: init_device_stats(
-                 len(getattr(wl, "txn_type_names", ("txn",)))))}
+                 len(getattr(wl, "txn_type_names", ("txn",))),
+                 row_groups=_counts_row_groups(cfg)))}
     n = cfg.pipeline_epochs * cfg.epoch_batch
     feed = (jax.ShapeDtypeStruct((n,), np.bool_),
             jax.ShapeDtypeStruct((n,), np.int32),
@@ -190,6 +199,56 @@ def _scatter_lanes(hlo: str, shape: str) -> list[int]:
         hlo))
 
 
+def _row_write_kernels(hlo: str, shape: str) -> list[list[str]]:
+    """Scopes of the `op_name` of every Pallas kernel that returns an
+    array of ``shape``.  Each has to sit in a `while`'s body and alias
+    its result to its operand of that shape (the column is written in
+    place)."""
+    bodies = set(re.findall(r" while\(.*?body=(%[\w.\-]+)", hlo))
+    where, found = None, []
+    for ln in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(", ln)
+        if head:
+            where = head.group(1)
+        if 'custom_call_target="tpu_custom_call"' not in ln or not \
+                re.search(r"= " + re.escape(shape) + r"\S* custom-call\(", ln):
+            continue
+        assert where in bodies, ln[:300]
+        operands = re.findall(r"[a-z]+\d+\[[\d,]*\]", re.search(
+            r"operand_layout_constraints=\{(.*?\})\}", ln).group(1))
+        aliased = int(re.search(
+            r"output_to_operand_aliasing=\{\{\}: \((\d+), \{\}\)\}",
+            ln).group(1))
+        assert operands[aliased] == shape, ln[:300]
+        found.append(re.search(r'op_name="([^"]*)"', ln).group(1).split("/"))
+    return found
+
+
+def _one_row_write_kernel(hlo: str, shape: str, lanes: int,
+                          scope: str = "ep.write") -> None:
+    """The row write of the hot cell's program since PR 48: ONE Pallas
+    kernel in the loop of the conditional's `few` side under ``scope``,
+    and no scatter into the column but the flagged whole pass of all
+    ``lanes`` (XLA's one-row-at-a-time scatter of N / 64 lanes a trip
+    went); the column is copied nowhere but in the entry computation."""
+    (scopes,) = _row_write_kernels(hlo, shape)
+    assert scopes[scopes.index(scope):][1:] == [
+        "cond", "branch_1_fun", "while", "body", "pallas_call"], scopes
+    assert _scatter_lanes(hlo, shape) == [lanes]
+    # ... and the kernel's row-major operand costs no copy in the epoch
+    assert set(_column_copies(hlo, shape)) <= {"entry"}
+
+
+def _xlas_row_write_loop(hlo: str, shape: str, lanes: int) -> None:
+    """The row write of a served program whose calls are shorter than
+    `ops.scatter._MIN_CALL_LANES` (the medium cells: 160 lanes; a shard
+    of four: 1,280), as at PRs 26-47: XLA's scatter of N / 64 lanes in
+    the loop, the whole pass's of every lane, and no kernel."""
+    assert _row_write_kernels(hlo, shape) == []
+    assert _scatter_lanes(hlo, shape) == [lanes // 64, lanes]
+    assert set(_column_copies(hlo, shape)) <= {"entry"}
+
+
 @pytest.mark.parametrize("over", [
     dict(), dict(cc_alg="OCC", epoch_batch=1024, max_txn_in_flight=1 << 17,
                  client_batch_size=1024)], ids=["tpu_batch", "occ"])
@@ -208,7 +267,16 @@ def test_full_row_column_is_written_in_place_inside_the_epoch_scan(
     gather of N / 64 lanes in a `while` body under `ep.read`, which
     closes over the column — the same pin holds with both loops in the
     program (OCC's masked path has no plan and gathers its 10,240 lanes
-    in one call, as it did)."""
+    in one call, as it did).
+
+    Since PR 48 a trip of the forwarding executor's write loop (calls of
+    2,560 lanes) is ONE Pallas kernel (`ops.scatter.
+    write_rows_by_group`) under `ep.write` that aliases the column: no
+    scatter of N / 64 lanes is left, only the whole pass's of every
+    lane, and the column's copies are still the entry's two.  OCC's
+    calls of 160 lanes keep XLA's scatter
+    (`ops.scatter._MIN_CALL_LANES`: the kernel is compiled in at the one
+    call length the chip has served it through)."""
     cfg = served_cfg(sim_full_row="true", synth_table_size=1 << 21, **over)
     group, state, feed = _group_program(cfg, monkeypatch)
     state, feed = _with_sharding((state, feed), one_chip)
@@ -219,6 +287,11 @@ def test_full_row_column_is_written_in_place_inside_the_epoch_scan(
     copies = _column_copies(hlo, f"u8[{f0.shape[0]},{f0.shape[1]}]")
     assert copies and set(copies) == {"entry"}, copies
     lanes = cfg.epoch_batch * cfg.req_per_query
+    shape = f"u8[{f0.shape[0]},{f0.shape[1]}]"
+    if cfg.cc_alg == "TPU_BATCH":
+        _one_row_write_kernel(hlo, shape, lanes)
+    else:
+        _xlas_row_write_loop(hlo, shape, lanes)
     if cfg.cc_alg == "TPU_BATCH":
         ((got, scopes),) = _looped_row_gathers(hlo, f0.shape[1])
         assert got == -(-lanes // G._CHUNKS)
@@ -309,7 +382,8 @@ def dp4_cell(topo):
         # the server of a forwarding backend on a mesh counts the
         # shard-epochs that ran the exchange's defer pass (PR 41)
         state["stats"] = jax.eval_shape(
-            lambda: init_device_stats(1, mc_defer_passes=True))
+            lambda: init_device_stats(1, mc_defer_passes=True,
+                                      row_groups=True))
         state = _with_sharding(state, M.state_shardings(mesh, state))
         feed = _with_sharding(feed, NamedSharding(mesh, P()))
         with M.use_mesh(mesh):
@@ -432,7 +506,12 @@ def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
     629 MB shard of the column inside an epoch — the two relayouts in
     the entry computation stay, once a group — and a shard's 81,920
     plan lanes reach the row gather 1,280 a call, in a `while` body
-    under `ep.read` (PR 47)."""
+    under `ep.read` (PR 47); its winners reach the row write 1,280 a
+    call, XLA's scatter as at the parent — the kernel of PR 48 is not
+    compiled in below calls of 2,560 lanes (`ops.scatter.
+    _MIN_CALL_LANES`: no call of 1,280 was priced or served on a chip),
+    though the shard's server counts its groups (0) like every YCSB
+    server."""
     cfg, _, state, compiled, _ = dp4_cell
     f0 = state["db"]["MAIN_TABLE"].columns["F0"]
     rows, width = f0.sharding.shard_shape(f0.shape)
@@ -443,6 +522,7 @@ def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
     lanes = 4 * mc_pair_cap(cfg.epoch_batch, cfg.req_per_query, 4,
                             cfg.mc_plan_capacity)
     assert lanes == 81_920
+    _xlas_row_write_loop(hlo, f"u8[{rows},{width}]", lanes)
     ((got, scopes),) = _looped_row_gathers(hlo, width)
     assert got == -(-lanes // G._CHUNKS)
     assert scopes[scopes.index("ep.read"):][1:3] == ["while", "body"]
@@ -453,12 +533,13 @@ def test_dp4_cell_group_reads_and_writes_each_shard_in_place(dp4_cell):
 def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
                                                             monkeypatch):
     """The OCC cell's group program on the chip's own HLO: no scatter
-    but the row scatter (the four `access_incidence` scatter-adds of
-    10,240 lanes into `bf16[1024 x 8192]` arenas went, 0.45 ms an epoch
-    at PR 31), no convolution under `ep.validate` (the two bucket
-    matmuls, 0.18 ms) — the U-vs-W conflict matrix is `ops.conflict.
-    key_overlap`'s compare — nothing of an arena's size anywhere in the
-    program, and it still fits the chip."""
+    but the row write's two (the four `access_incidence` scatter-adds
+    of 10,240 lanes into `bf16[1024 x 8192]` arenas went, 0.45 ms an
+    epoch at PR 31; the loop's scatter of 160 lanes a trip stays XLA's,
+    below the row write kernel's `_MIN_CALL_LANES`: PR 48), no
+    convolution under `ep.validate` (the two bucket matmuls, 0.18 ms) — the U-vs-W conflict matrix is
+    `ops.conflict.key_overlap`'s compare — nothing of an arena's size
+    anywhere in the program, and it still fits the chip."""
     cfg = _cell_cfg("ycsb_fullrow_occ.medium")
     b, k = cfg.epoch_batch, cfg.conflict_buckets
     assert (cfg.cc_alg, b, k, cfg.conflict_exact) == ("OCC", 1024, 8192,
@@ -484,6 +565,8 @@ def test_occ_cell_group_validates_without_arenas_or_matmuls(one_chip,
     scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
     assert scatters and all(
         f"= u8[{f0.shape[0]},{f0.shape[1]}]" in ln for ln in scatters)
+    _xlas_row_write_loop(hlo, f"u8[{f0.shape[0]},{f0.shape[1]}]",
+                         b * cfg.req_per_query)
     # the matrix itself: one [B, B] result of A x A fused compares
     assert re.search(rf"f32\[{b},{b}\]\S* fusion\(", hlo)
 
@@ -497,7 +580,8 @@ def test_waitdie_cell_group_fits_the_chip_and_sweeps_under_validate(
     beside two groups in flight; the lock table — the [B, B] compare of
     the exact keys, the sweep's matvecs and the age test's masked min —
     carries `ep.validate`; nothing of an arena's size anywhere, and the
-    program's only scatters are the row write's."""
+    program's only scatters are the row write's (XLA's loop of 160
+    lanes a trip: below the kernel's `_MIN_CALL_LANES`, PR 48)."""
     from deneva_tpu.engine.step import init_device_stats
     cfg = _cell_cfg("ycsb_fullrow_waitdie.medium")
     b, k = cfg.epoch_batch, cfg.conflict_buckets
@@ -506,8 +590,9 @@ def test_waitdie_cell_group_fits_the_chip_and_sweeps_under_validate(
                                       24, 8)
     group, state, feed = _group_program(cfg, monkeypatch)
     state["stats"] = jax.eval_shape(
-        lambda: init_device_stats(1, lock_counters=True))
-    assert {"lock_die", "lock_wait", "lock_leftover"} <= set(state["stats"])
+        lambda: init_device_stats(1, lock_counters=True, row_groups=True))
+    assert {"lock_die", "lock_wait", "lock_leftover",
+            "write_row_groups"} <= set(state["stats"])
     state, feed = _with_sharding((state, feed), one_chip)
     compiled, secs = _compile(group, state["db"], state["cc_state"],
                               state["stats"], *feed)
@@ -528,6 +613,8 @@ def test_waitdie_cell_group_fits_the_chip_and_sweeps_under_validate(
     scatters = [ln for ln in hlo.splitlines() if " scatter(" in ln]
     assert scatters and all(
         f"= u8[{f0.shape[0]},{f0.shape[1]}]" in ln for ln in scatters)
+    _xlas_row_write_loop(hlo, f"u8[{f0.shape[0]},{f0.shape[1]}]",
+                         b * cfg.req_per_query)
     # the matrix itself: ONE fusion of A x A compares, handed on twice —
     # as f32 to the sweep's products, as pred to the age test
     assert re.search(rf"= \(f32\[{b},{b}\]\S*, pred\[{b},{b}\]\S*\) "
@@ -554,7 +641,8 @@ def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
     ring), the push the table's own winners-only row write
     (`ops.scatter.scatter_winner_rows`: a loop of 160-lane scatters, or
     one sorted scatter, in the two branches of a conditional), and the
-    compiled program holds NO copy and no second buffer of the ring:
+    compiled program holds NO copy and no second buffer of the ring
+    (neither goes through PR 48's kernel):
     nothing but parameters, tuple elements and those two scatters has its
     shape.  The forms not taken, compiled here for the same chip
     (PERF.md section 6, PR 45): `int32[6,291,520, 10]` is tiled
@@ -568,7 +656,7 @@ def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
             cfg.watermark_buckets) == ("MVCC", 10, 1024, 1 << 20)
     group, state, feed = _group_program(cfg, monkeypatch)
     state["stats"] = jax.eval_shape(
-        lambda: init_device_stats(2, mvcc_counters=True))
+        lambda: init_device_stats(2, mvcc_counters=True, row_groups=True))
     state, feed = _with_sharding((state, feed), one_chip)
     compiled, secs = _compile(group, state["db"], state["cc_state"],
                               state["stats"], *feed)
@@ -611,6 +699,21 @@ def test_mvcc_cell_group_fits_the_chip_and_names_its_ring(one_chip,
     assert set(by_shape) == {"s32[1048576]", shape, "u8[6291520,100]"}
     assert (by_shape.count("s32[1048576]"), by_shape.count(shape)) == (3, 2)
     assert _scatter_lanes(hlo, shape) == [lanes // 64, lanes]
+    # neither column's winners go through the row write's kernel
+    # (PR 48): calls of 160 lanes are below its `_MIN_CALL_LANES` (F0's
+    # 2,580 winners cost it 0.30 ms an epoch for XLA's 0.24 when forced
+    # through it, and the cell served 3% less: my chip runs, PR 48),
+    # and the ring could not
+    # at any length — the kernel's operand is row-major, the chip lays
+    # 40 B rows rows-minor, and forced through the kernel this program
+    # relayouts the ring twice inside every epoch (two `inner` copies,
+    # temporaries 0.81 -> 2.44 GB: my compile for v5e, PR 48)
+    _xlas_row_write_loop(hlo, "u8[6291520,100]", lanes)
+    assert _row_write_kernels(hlo, shape) == []
+    from deneva_tpu.ops.scatter import _by_group
+    assert _by_group((6_291_520, 100), np.uint8, 2560) \
+        and not _by_group((6_291_520, 100), np.uint8, lanes // 64) \
+        and not _by_group(ring.shape, ring.dtype, 2560)
 
 
 # ---- the TPC-C cell (PR 36): tpcc_fullschema_tpubatch.mixed --------------

@@ -93,7 +93,7 @@ def _parent_execute_mc(wl, db, batch, stats):
         p = forward_plan_flat(bk, br, bw)
         trash = jnp.int32(f0.shape[0] - 1)
         slots = jnp.where(p.keys != big, p.keys // d_parts, trash)
-        f0, cks, wcnt, lanes, rlanes = _forward_execute_f0(
+        f0, cks, wcnt, lanes, rlanes, _ = _forward_execute_f0(
             f0, p, slots, trash, mono=True)
         return (f0, jax.lax.psum(cks, AXIS), jax.lax.psum(wcnt, AXIS),
                 jax.lax.psum(lanes, AXIS), jax.lax.psum(rlanes, AXIS), dfr)

@@ -309,9 +309,14 @@ _YCSB_TOY = dict(synth_table_size=1024, conflict_buckets=256)
 _LEVELS = ["level_pass_cnt", "narrow_pass_cnt"]
 # configuration -> (toy sizes, stats leaves beyond _STATS, [summary] keys
 # beyond _SUMMARY)
+# (PR 48: a server that writes full rows through the row write's kernel
+# counts the tile groups it writes back — YCSB's, no other's)
+_GROUPS = (["write_row_groups"], ["write_row_group_cnt"])
 PINNED = {
-    "ycsb-fullrow-tpubatch": (_YCSB_TOY, [], _types("ycsb", ("ro", "rw"))),
-    "ycsb-fullrow-occ": (_YCSB_TOY, [], _types("ycsb", ("ro", "rw"))),
+    "ycsb-fullrow-tpubatch": (_YCSB_TOY, _GROUPS[0],
+                              _GROUPS[1] + _types("ycsb", ("ro", "rw"))),
+    "ycsb-fullrow-occ": (_YCSB_TOY, _GROUPS[0],
+                         _GROUPS[1] + _types("ycsb", ("ro", "rw"))),
     "tpcc-fullschema-tpubatch": (
         dict(num_wh=2),
         _LEVELS + ["append_scatter_lanes", "append_window_lanes"],

@@ -348,10 +348,10 @@ def test_forward_execute_mono_scatter_matches_legacy():
     big = jnp.int32(np.iinfo(np.int32).max)
     slots = jnp.where(p.keys != big, p.keys, tab)     # identity index
     f0 = jnp.asarray(rng.integers(0, 2**32, tab + 1, dtype=np.uint32))
-    a_f0, a_cks, a_w, a_l, a_r = _forward_execute_f0(f0, p, slots, tab,
-                                                     mono=False)
-    b_f0, b_cks, b_w, b_l, b_r = _forward_execute_f0(f0, p, slots, tab,
-                                                     mono=True)
+    a_f0, a_cks, a_w, a_l, a_r, _ = _forward_execute_f0(
+        f0, p, slots, tab, mono=False)
+    b_f0, b_cks, b_w, b_l, b_r, _ = _forward_execute_f0(
+        f0, p, slots, tab, mono=True)
     # trash slot may differ (legacy parks losers there); data rows must not
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
@@ -374,10 +374,10 @@ def test_forward_execute_mono_scatter_matches_legacy_full_row():
                           jnp.asarray(w))
     slots = p.keys
     f0 = jnp.asarray(rng.integers(0, 256, (tab + 1, width), dtype=np.uint8))
-    a_f0, a_cks, _, a_l, _ = _forward_execute_f0(f0, p, slots, tab,
-                                                 mono=False)
-    b_f0, b_cks, _, b_l, _ = _forward_execute_f0(f0, p, slots, tab,
-                                                 mono=True)
+    a_f0, a_cks, _, a_l, *_ = _forward_execute_f0(f0, p, slots, tab,
+                                                  mono=False)
+    b_f0, b_cks, _, b_l, *_ = _forward_execute_f0(f0, p, slots, tab,
+                                                  mono=True)
     np.testing.assert_array_equal(np.asarray(a_f0)[:tab],
                                   np.asarray(b_f0)[:tab])
     assert int(a_cks) == int(b_cks)
@@ -511,8 +511,8 @@ def test_scatter_winner_rows_matches_the_trash_steered_scatter(
         slots, win, keys, order = slots[o], win[o], keys[o], order[o]
     col = jnp.asarray(rng.integers(0, 256, (rows, width), dtype=np.uint8))
     value = lambda k, o: _field_bytes(k, o, width)      # noqa: E731
-    got, lanes, read = jax.jit(sc.scatter_winner_rows,
-                               static_argnums=(4, 5))(
+    got, lanes, _groups, read = jax.jit(sc.scatter_winner_rows,
+                                        static_argnums=(4, 5))(
         col, jnp.asarray(slots), jnp.asarray(win),
         (jnp.asarray(keys), jnp.asarray(order)), value, cap,
         col[cap].sum(dtype=jnp.uint32))

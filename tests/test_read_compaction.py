@@ -103,7 +103,7 @@ def test_compacted_read_is_the_per_lane_checksum_and_table(keys_of, mix,
         **(dict(invalid=0.05, pad=512) if lanes != "whole" else {}))
     p, slots = _plan(keys, rank, w, tab)
     f0 = _column(rows, rng)
-    got_f0, cks, wcnt, _, rlanes = jax.jit(
+    got_f0, cks, wcnt, _, rlanes, _ = jax.jit(
         lambda f0, p, slots: _forward_execute_f0(f0, p, slots, tab,
                                                  mono=True))(f0, p, slots)
     assert int(cks) == int(_per_lane(f0, p, slots, tab))
@@ -240,7 +240,8 @@ def test_fingerprint_columns_keep_the_per_lane_gather():
     keys, rank, w = _epoch("0.9", "hot", 1024, 300, rng)
     p, slots = _plan(keys, rank, w, 300)
     f0 = jnp.asarray(rng.integers(0, 2**32, 309, dtype=np.uint32))
-    _, cks, _, _, rlanes = _forward_execute_f0(f0, p, slots, 300, mono=True)
+    _, cks, _, _, rlanes, _ = _forward_execute_f0(f0, p, slots, 300,
+                                                  mono=True)
     assert int(rlanes) == 1024
     vals = np.where(np.asarray(p.fwd) >= 0,
                     np.asarray(_field_fingerprint(p.keys, p.fwd)),
