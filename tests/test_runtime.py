@@ -218,6 +218,122 @@ def test_client_load_rate_throttles():
     assert cl["sent_cnt"] <= 2000 * cl["total_runtime"]         + 2 * cfg.client_batch_size
 
 
+@pytest.fixture(scope="module")
+def admission_node():
+    """One single-server node per backend, shared by the admission cases
+    (construction loads the table; the cases only drive the queues)."""
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    from deneva_tpu.runtime.native import ipc_endpoints
+    from deneva_tpu.runtime.server import ServerNode
+
+    nodes: dict = {}
+
+    def get(alg):
+        if alg not in nodes:
+            cfg = small_cfg(node_cnt=1, part_cnt=1, client_node_cnt=0,
+                            cc_alg=alg)
+            nodes[alg] = ServerNode(
+                cfg, ipc_endpoints(1, f"adm_{alg.name}"), "cpu")
+        node = nodes[alg]
+        node.pending.clear()
+        node.retry.items.clear()
+        node._queue_txns = 0
+        return node
+
+    yield get
+    for node in nodes.values():
+        node.close()
+
+
+def _four_txns(tag0=0):
+    from deneva_tpu.runtime import wire
+    return wire.QueryBlock(
+        keys=np.arange(16, dtype=np.int32).reshape(4, 4) + 1,
+        types=np.ones((4, 4), np.int8),
+        scalars=np.zeros((4, 0), np.int32),
+        tags=np.arange(4, dtype=np.int64) + tag0)
+
+
+@pytest.mark.parametrize("alg, aborted, keeps", [
+    (CCAlg.WAIT_DIE, True, True),     # fresh_ts_on_restart = False
+    (CCAlg.OCC, True, False),         # an aborted restart is re-stamped
+    (CCAlg.TIMESTAMP, False, True),   # a deferred waiter keeps its own
+])
+def test_admission_birth_ts_rule(admission_node, alg, aborted, keeps):
+    """The stamping rule of the served admission path, driven directly:
+    WAIT_DIE keeps a restart's birth timestamp (its starvation-freedom;
+    reference worker_thread.cpp:492-508), a fresh-ts backend re-stamps
+    an ABORTED restart, and a deferred (waiting) txn keeps its own like
+    the in-process pool and the reference's parked requests."""
+    node = admission_node(alg)
+    birth = np.array([7, 9, 11, 13], np.int64)
+    node.retry.push(_four_txns(), np.full(4, int(aborted), np.int32),
+                    birth, epoch=0, aborted=np.full(4, aborted, bool))
+    fs = node._feed_acquire()
+    block, cnt, ts, dfc = node._contribution_into(5, fs, 0)
+    assert len(block) == len(cnt) == len(ts) == len(dfc) == 4
+    if keeps:
+        assert (ts == birth).all()
+    else:
+        # epoch-anchored: (epoch + 1) * b_merged + position
+        assert (ts == 6 * node.b_merged + np.arange(4)).all()
+    assert fs["active"][0, :4].all() and not fs["active"][0, 4:].any()
+    assert (fs["ts"][0, :4] == ts).all()
+
+
+def test_admission_reused_feed_buffer_tail_reads_zero(admission_node):
+    """Unfilled lanes of a REUSED feed buffer are zero and inactive, so
+    every node builds the same feed (and log) bytes whatever the buffer
+    held before: fill row 0 from the clients' queue, recycle the set,
+    then admit four txns into it."""
+    node = admission_node(CCAlg.OCC)
+    b = node.b_loc
+    rng = np.random.default_rng(0)
+    from deneva_tpu.runtime import wire
+    full = wire.QueryBlock(
+        keys=rng.integers(1, 4096, (b, 4)).astype(np.int32),
+        types=np.full((b, 4), 2, np.int8),
+        scalars=np.zeros((b, 0), np.int32),
+        tags=np.arange(b, dtype=np.int64) + 1)
+    node.pending.append((1, full))
+    node._queue_txns = b
+    fs = node._feed_acquire()
+    blk, _, ts, _ = node._contribution_into(0, fs, 0)
+    assert len(blk) == b and (blk.tags >> 40 == 1).all()
+    assert (ts == node.b_merged + np.arange(b)).all()
+    assert fs["keys"][0].all() and fs["active"][0].all()
+    node._feed_free.append(fs)
+    again = node._feed_acquire()
+    assert again is fs
+    node.pending.append((1, _four_txns(tag0=1000)))
+    node._queue_txns = 4
+    blk, _, ts, _ = node._contribution_into(1, again, 0)
+    assert len(blk) == 4
+    for name in ("keys", "types", "scal", "tags", "ts"):
+        assert not again[name][0, 4:].any(), name
+    assert again["active"][0, :4].all()
+    assert not again["active"][0, 4:].any()
+    assert node._queue_txns == 0
+
+
+@pytest.mark.parametrize("epoch, birth, what", [
+    (2**31 // 128, 7, "horizon exceeded"),   # a stamp past 2^31
+    (5, 0, "below 1"),                       # ts 0 is MVCC's sentinel
+])
+def test_admission_stamp_invariants_raise(admission_node, epoch, birth,
+                                          what):
+    """Both stamping invariants fire: the 2^31 birth-timestamp horizon,
+    and ts >= 1 (a restart that kept a birth ts of 0)."""
+    node = admission_node(CCAlg.WAIT_DIE)
+    assert node.b_merged == 128
+    node.retry.push(_four_txns(), np.ones(4, np.int32),
+                    np.full(4, birth, np.int64), epoch=0,
+                    aborted=np.ones(4, bool))
+    with pytest.raises(RuntimeError, match=what):
+        node._contribution_into(epoch, node._feed_acquire(), 0)
+
+
 @pytest.mark.slow
 def test_wait_die_preserves_birth_ts_across_restarts():
     """WAIT_DIE starvation-freedom: a restarted txn must keep its birth
@@ -428,14 +544,25 @@ def test_maat_vote_detects_cross_node_write_skew():
     assert ab.sum() == 1 and commit_g.sum() == 1
 
 
-def _drive_overlap_run(tmp_path, overlap: bool) -> dict:
+# epochs a VOTE run of `_drive_overlap_run` lasts: TIMESTAMP's waiters
+# restart free and its aborts back off 1, 2, 4, ... epochs, which drains
+# the 256 txns inside it (NO_WAIT's synchronised restarts want ~1000)
+_VOTE_EPOCHS = 96
+
+
+def _drive_overlap_run(tmp_path, overlap: bool, vote: bool = False) -> dict:
     """One deterministic single-server cluster run (+ 1 replica, with the
     test posing as the client): every query batch is delivered BEFORE the
     INIT_DONE barrier (per-link FIFO puts them all in the server's
     pending queue ahead of epoch 0) and warmup/done are zero, so the
     measure/stop epochs pin to the 3C group boundary — admission, epochs
     and verdicts are a pure function of the config, which is what makes
-    the overlap-on and overlap-off runs byte-comparable."""
+    the overlap-on and overlap-off runs byte-comparable.
+
+    ``vote`` runs the server under the VOTE protocol instead (C = K = 1,
+    a synchronous host round trip an epoch): no log and no replica (a
+    vote run's log is not its replay), and the stop epoch rides in with
+    the batches, far enough out that every restart has its turn."""
     import os
     import threading
     import time as _time
@@ -451,11 +578,13 @@ def _drive_overlap_run(tmp_path, overlap: bool) -> dict:
     from deneva_tpu.workloads import get_workload
 
     log_dir = str(tmp_path / f"logs_overlap_{overlap}")
-    cfg = small_cfg(node_cnt=1, client_node_cnt=1, cc_alg=CCAlg.NO_WAIT,
+    cfg = small_cfg(node_cnt=1, client_node_cnt=1,
+                    cc_alg=CCAlg.TIMESTAMP if vote else CCAlg.NO_WAIT,
                     zipf_theta=0.9, synth_table_size=512, epoch_batch=64,
-                    pipeline_epochs=2, pipeline_groups=2, logging=True,
-                    replica_cnt=1, log_dir=log_dir, warmup_secs=0.0,
-                    done_secs=0.0,
+                    pipeline_epochs=2, pipeline_groups=2, logging=not vote,
+                    replica_cnt=0 if vote else 1, log_dir=log_dir,
+                    dist_protocol="vote" if vote else "auto",
+                    warmup_secs=0.0, done_secs=0.0,
                     host_overlap="on" if overlap else "off",
                     # arm the thread-ownership runtime asserts on BOTH
                     # sides: with overlap on, the wire/retire workers run
@@ -464,7 +593,8 @@ def _drive_overlap_run(tmp_path, overlap: bool) -> dict:
                     # on==off byte-compare doubles as proof the guards
                     # themselves change nothing
                     owner_check=True)
-    eps = ipc_endpoints(3, uuid.uuid4().hex[:8])
+    n_all = 2 if vote else 3
+    eps = ipc_endpoints(n_all, uuid.uuid4().hex[:8])
     wl = get_workload(cfg)
     batches = []
     for s in range(4):          # 256 txns, distinct tag ranges
@@ -477,7 +607,9 @@ def _drive_overlap_run(tmp_path, overlap: bool) -> dict:
     def run_server():
         node = ServerNode(cfg.replace(node_id=0, part_cnt=1), eps, "cpu")
         try:
-            assert node._overlap == (overlap and True)
+            # the workers exist where asked for, never under VOTE
+            assert node._overlap == (overlap and not vote)
+            assert node.vote_mode == vote
             node.run()
             out["digest"] = state_digest(node.db)
             out["commits"] = int(jax.device_get(
@@ -497,20 +629,24 @@ def _drive_overlap_run(tmp_path, overlap: bool) -> dict:
     ts_srv = threading.Thread(target=run_server)
     ts_rep = threading.Thread(target=run_replica)
     ts_srv.start()
-    ts_rep.start()
-    cl = NativeTransport(1, eps, 3)
+    if not vote:
+        ts_rep.start()
+    cl = NativeTransport(1, eps, n_all)
     cl.start()
     acked: list[int] = []
     try:
         for tags, k, t, sc in batches:
             cl.sendv(0, "CL_QRY_BATCH", wire.qry_block_parts(tags, k, t, sc))
+        if vote:
+            cl.send(0, "SHUTDOWN", wire.encode_shutdown(_VOTE_EPOCHS))
         cl.flush()
 
         def on_other(src, rtype, payload):
             if rtype == "CL_RSP":
                 acked.extend(wire.decode_cl_rsp(payload).tolist())
 
-        wire.run_barrier(cl, 1, 3, on_other, "overlap-test client", 300.0)
+        wire.run_barrier(cl, 1, n_all, on_other, "overlap-test client",
+                         300.0)
         t0 = _time.monotonic()
         stopped = False
         while not stopped and _time.monotonic() - t0 < 300:
@@ -524,14 +660,18 @@ def _drive_overlap_run(tmp_path, overlap: bool) -> dict:
         assert stopped, "server never announced SHUTDOWN"
     finally:
         ts_srv.join(timeout=300)
-        ts_rep.join(timeout=60)
+        if not vote:
+            ts_rep.join(timeout=60)
         cl.close()
     assert "err" not in out, out["err"]
+    out["acked"] = sorted(acked)
+    out["sent"] = sorted(int(t) for b in batches for t in b[0])
+    if vote:
+        return out
     with open(os.path.join(log_dir, "node0.log.bin"), "rb") as f:
         out["log"] = f.read()
     with open(os.path.join(log_dir, "replica2.log.bin"), "rb") as f:
         out["rlog"] = f.read()
-    out["acked"] = sorted(acked)
     return out
 
 
@@ -553,6 +693,21 @@ def test_host_overlap_bit_identical(tmp_path):
     assert on["digest"] == off["digest"]
     assert on["commits"] == off["commits"] > 0
     assert on["acked"] == off["acked"] and len(on["acked"]) > 0
+
+
+def test_vote_mode_served_loop_in_thread(tmp_path):
+    """The VOTE protocol through the served loop, in tier 1: one server
+    (so the vote exchange has no peer to wait for, and the loop, the
+    admission, the feed and the retirement are what runs), asked for
+    worker threads and given none — its epoch is a synchronous host
+    round trip.  It commits, every sent tag is acked exactly once, and
+    a second run of the same batches ends on the same table."""
+    a = _drive_overlap_run(tmp_path, True, vote=True)
+    b = _drive_overlap_run(tmp_path, True, vote=True)
+    assert a["commits"] > 0
+    assert a["acked"] == a["sent"]
+    assert (a["digest"], a["commits"], a["acked"]) == \
+        (b["digest"], b["commits"], b["acked"])
 
 
 @pytest.mark.slow
