@@ -79,14 +79,6 @@ class Config:
     client_node_cnt: int = 1
     part_cnt: int = 1              # keyspace partitions (== node_cnt in reference)
     core_cnt: int = 8
-    thread_cnt: int = 1            # host codec worker threads (reference
-    #                                THREAD_CNT, main.cpp:196-310): >1 runs
-    #                                the cluster loop's per-epoch blob
-    #                                encode + feed assembly through a
-    #                                thread pool (numpy codecs release the
-    #                                GIL, so a multi-core host overlaps
-    #                                admit work with itself; this 1-core
-    #                                box measures it ~neutral)
     rem_thread_cnt: int = 1        # native receiver IO threads (reference
     #                                REM_THREAD_CNT): peers shard src % n
     send_thread_cnt: int = 1       # native sender IO threads (reference
@@ -312,40 +304,35 @@ class Config:
     #                                reference's sequencer-vs-worker thread
     #                                decoupling, system/calvin_thread.cpp:102).
     #                                1 = retire synchronously.
-    host_overlap: str = "auto"     # cluster merged mode: run the host half
-    #                                of each epoch OFF the dispatch thread
-    #                                (the host-path pipeline).  A single
-    #                                ordered wire worker carries blob
-    #                                encode+broadcast, log-record packing +
-    #                                logger append + replica LOG_MSG sends
-    #                                (per-link FIFO preserved — one worker,
-    #                                program order); a retire worker
-    #                                prefetches each group's verdict planes
-    #                                (d2h wait + unpackbits + CL_RSP
-    #                                payloads) so retirement K groups later
-    #                                finds them ready; the device feed is
-    #                                assembled zero-copy (contributions and
-    #                                peer blobs land directly in reusable
-    #                                flat feed buffers, sends go out as
-    #                                scatter-gather parts via dt_sendv).
-    #                                "off" = the pre-pipeline serial loop:
-    #                                same admission policy, same stamping,
-    #                                same record bytes — bit-identical
-    #                                verdicts and logs (tested).  "auto"
+    host_overlap: str = "auto"     # which thread runs the served loop's
+    #                                pure host bodies (the loop, its feed
+    #                                buffers and its bytes are the same
+    #                                either way — tested bit-identical).
+    #                                "on": one ordered wire worker carries
+    #                                each epoch's blob broadcast and the
+    #                                group's log records + replica LOG_MSG
+    #                                sends (per-link FIFO preserved — one
+    #                                worker, program order), and a retire
+    #                                worker turns each group's verdict
+    #                                planes into ack payloads (d2h wait +
+    #                                unpackbits + CL_RSP splits) so
+    #                                retirement K groups later finds them
+    #                                ready.  "off": the dispatch thread
+    #                                calls the same bodies inline at the
+    #                                same loop positions.  "auto"
     #                                (default) = on unless this box's
     #                                process count (servers + clients +
     #                                replicas, the single-box launcher
     #                                rig) oversubscribes its cores by
-    #                                more than one: overlap threads can
+    #                                more than one: worker threads can
     #                                only overlap DEVICE time if a spare
     #                                cycle exists — measured on the
     #                                2-core box, on wins at N<=2 procs+1
     #                                and loses 29% at 5 procs (BASELINE
     #                                round-7).  Multi-host fleets set
-    #                                on/off explicitly.  Vote mode
-    #                                ignores it (its epoch is a
-    #                                synchronous host round trip by
-    #                                construction).
+    #                                on/off explicitly.  Vote mode runs
+    #                                inline whatever this says (its epoch
+    #                                is a synchronous host round trip).
     dist_protocol: str = "auto"    # cluster coordination for non-deterministic
     #                                backends (reference 2PC,
     #                                system/txn.cpp:498-606):
@@ -1133,9 +1120,8 @@ class Config:
                f"bad deploy {self.deploy!r}")
         _check(self.pipeline_epochs >= 1 and self.pipeline_groups >= 1,
                "pipeline_epochs/pipeline_groups must be >= 1")
-        _check(self.send_thread_cnt >= 1 and self.rem_thread_cnt >= 1
-               and self.thread_cnt >= 1,
-               "send/rem/worker thread counts must be >= 1")
+        _check(self.send_thread_cnt >= 1 and self.rem_thread_cnt >= 1,
+               "send/rem thread counts must be >= 1")
         _check(self.client_batch_size >= 64,
                "client_batch_size must be >= 64 (the client skips sends "
                "smaller than one minimal message, client.py)")

@@ -358,16 +358,11 @@ def network_sweep(quick: bool) -> list[Config]:
         warmup_secs=0.5, done_secs=1.5 if quick else 5.0)
     delays = (0, 1000) if quick else (0, 100, 1000, 10000)
     pts = [base.replace(net_delay_us=float(d)) for d in delays]
-    # round-5 host thread axes (reference THREAD_CNT / SEND_THREAD_CNT /
-    # REM_THREAD_CNT, main.cpp:196-310): codec workers + sharded native
-    # IO threads, swept at zero injected delay.  On this 1-core box the
-    # sweep documents the axes' cost-neutrality; on multi-core hosts the
-    # codec pool overlaps the admit/retire work the round-4 decomposition
-    # measured as the cluster loop's binding term.
+    # round-5 host thread axis (reference SEND_THREAD_CNT /
+    # REM_THREAD_CNT, main.cpp:196-310): sharded native IO threads, at
+    # zero injected delay (one IO thread each is the first point above)
     if not quick:
-        pts += [base.replace(thread_cnt=t, send_thread_cnt=io,
-                             rem_thread_cnt=io)
-                for t, io in ((2, 1), (2, 2), (4, 2))]
+        pts.append(base.replace(send_thread_cnt=2, rem_thread_cnt=2))
     return pts
 
 

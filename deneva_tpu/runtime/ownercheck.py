@@ -1,14 +1,16 @@
 """Thread-ownership declarations + debug-mode runtime asserts for the
 server's shared state (our substitute for the broken TSAN on this box).
 
-The host-path pipeline (PR 3) runs three thread roles inside a server
-process — the DISPATCH thread (the epoch loop: admission, feed build,
-device dispatch, retirement, all state mutation), ONE ordered WIRE
-worker (blob encode+broadcast, log pack/append, replica sends), and ONE
-RETIRE worker (verdict d2h wait + pure unpacking) — plus the CODEC pool
-(thread_cnt > 1: blob bcast + feed fill closures).  The bit-identity
-contract is that workers stage PURE work and every state mutation stays
-at the dispatch thread's serial-loop positions.
+Up to three thread roles run inside a server process — the DISPATCH
+thread (the epoch loop: admission, feed build, device dispatch,
+retirement, all state mutation) and, where ``host_overlap`` gives the
+loop's pure bodies threads of their own, ONE ordered WIRE worker
+(``_bcast_views`` / ``_log_group_views`` / ``tp.flush``: blob broadcast,
+log pack/append, replica sends) and ONE RETIRE worker
+(``_prefetch_retire``: verdict d2h wait + pure unpacking and ack
+splits).  Without workers the dispatch thread calls the same bodies
+inline.  The bit-identity contract is that these bodies are PURE and
+every state mutation stays at the dispatch thread's loop positions.
 
 This module is the single source of truth for who owns what:
 
@@ -36,7 +38,6 @@ from collections import deque
 DISPATCH = "dispatch"   # the epoch loop thread (owns all state mutation)
 WIRE = "wire"           # ordered wire worker (host_overlap)
 RETIRE = "retire"       # verdict prefetch worker (host_overlap)
-CODEC = "codec"         # codec pool closures (thread_cnt > 1)
 SHARED = "shared"       # internally synchronized (lock / thread-safe impl)
 
 # ---- ServerNode attribute -> owning role ------------------------------
@@ -154,16 +155,15 @@ OWNER: dict[str, str] = {
     "logger": SHARED,        # EpochLogger: queue + writer thread
     "_sent_blobs": SHARED,   # deque guarded by _sent_lock (REJOIN resend)
     "_sent_lock": SHARED,
-    "codec_pool": SHARED, "wire_pool": SHARED, "retire_pool": SHARED,
+    "wire_pool": SHARED, "retire_pool": SHARED,
 }
 
 # worker role -> function names whose call graphs run on that role
-# (_bcast_views/_log_group_views submit to wire_pool; _prefetch_retire to
-# retire_pool; _bcast/_fill are the codec-pool closures inside run())
+# (`_wire` submits _bcast_views/_log_group_views to wire_pool; run()
+# submits _prefetch_retire to retire_pool)
 WORKER_ENTRY: dict[str, tuple[str, ...]] = {
     WIRE: ("_bcast_views", "_log_group_views"),
     RETIRE: ("_prefetch_retire",),
-    CODEC: ("_bcast", "_fill"),
 }
 
 # method names that mutate their receiver (the static checker flags

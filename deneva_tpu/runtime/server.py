@@ -38,6 +38,7 @@ import os
 import threading
 import time
 from collections import deque
+from concurrent.futures import Future, ThreadPoolExecutor
 
 import numpy as np
 
@@ -523,32 +524,21 @@ class ServerNode:
             self.tp.set_fault(cfg.fault_drop_prob, cfg.fault_dup_prob,
                               cfg.fault_delay_jitter_us,
                               seed=cfg.fault_seed + 7919 * cfg.node_id)
-        # host codec workers (reference THREAD_CNT, main.cpp:196-310):
-        # the admit path's per-epoch blob encode+broadcast and the group
-        # feed assembly run through this pool when thread_cnt > 1 —
-        # numpy codecs and socket sends release the GIL, so multi-core
-        # hosts overlap the codec work that binds the 1-core cluster loop
-        from concurrent.futures import ThreadPoolExecutor
-        self.codec_pool = None
-        if cfg.thread_cnt > 1:
-            self.codec_pool = ThreadPoolExecutor(
-                max_workers=cfg.thread_cnt,
-                thread_name_prefix=f"srv{self.me}-codec")
-        # host-path pipeline (host_overlap, default auto): the host half of
-        # each epoch leaves the dispatch thread.  ONE ordered wire worker
-        # carries blob encode+broadcast and log pack/append/replica sends
-        # — a single thread consuming in program order is what preserves
-        # per-link FIFO; ONE retire worker prefetches each dispatched
-        # group's verdict planes (d2h wait + unpackbits + ack payloads)
-        # so retirement K groups later collects a finished result.  All
-        # state mutation (retry queue, dedup sets, held acks) stays on
-        # the dispatch thread at the exact loop positions of the serial
-        # path, so overlap on/off produce bit-identical verdict planes
-        # and log bytes (tested).  Vote mode is excluded: its epoch needs
-        # a synchronous host round trip (prepare -> vote -> decide).
+        # host_overlap decides ONE thing: which thread runs the loop's pure
+        # bodies.  With workers, ONE ordered wire worker carries each
+        # epoch's blob broadcast, the group's flush and its log records (a
+        # single thread consuming in program order is what preserves
+        # per-link FIFO) and ONE retire worker turns each dispatched
+        # group's verdict planes into ack payloads, so retirement K groups
+        # later collects a finished result.  Without them the dispatch
+        # thread calls the same bodies at the same loop positions.  Every
+        # state mutation (retry queue, dedup sets, held acks) is the
+        # dispatch thread's either way, so both give identical verdict
+        # planes and log bytes (tested).  A VOTE epoch is a synchronous
+        # host round trip with nothing to overlap: inline.
         ov = cfg.host_overlap
         if ov == "auto":
-            # overlap threads only overlap DEVICE time if a spare cycle
+            # worker threads only overlap DEVICE time if a spare cycle
             # exists: on the single-box launcher rig, more processes
             # than cores+1 means they would steal dispatch cycles
             # instead (measured: +5-10% at <=3 procs on 2 cores, -29%
@@ -571,7 +561,7 @@ class ServerNode:
         self._feed_free: list[dict] = []
         # d2h overlap accounting: how many groups' verdict prefetches
         # were already finished when their retirement turn came, and the
-        # serial wait the misses cost (the "mesh" trace track's ledger)
+        # wait the misses cost (the "mesh" trace track's ledger)
         self._prefetch_polls = 0
         self._prefetch_hits = 0
         self._prefetch_wait_s = 0.0
@@ -807,15 +797,11 @@ class ServerNode:
                     e0 = wire.peek_blob_epoch(payload)
                     if e0 > self._blob_seen_from.get(src, -1):
                         self._blob_seen_from[src] = e0
-            if self._overlap:
-                # keep the raw payload: collect decodes it STRAIGHT into
-                # the stacked feed slice (decode_epoch_blob_into) instead
-                # of allocating arrays here and copying again at fill
-                epoch = wire.peek_blob_epoch(payload)
-                self.blob_buf.setdefault(epoch, {})[src] = payload
-            else:
-                epoch, blk, ts = wire.decode_epoch_blob(payload)
-                self.blob_buf.setdefault(epoch, {})[src] = (blk, ts)
+            # keep the raw payload: collect decodes it STRAIGHT into the
+            # stacked feed slice (decode_epoch_blob_into), no arrays
+            # allocated here and copied again there
+            epoch = wire.peek_blob_epoch(payload)
+            self.blob_buf.setdefault(epoch, {})[src] = payload
         elif rtype == "VOTE":
             epoch, c, a, bnd = wire.decode_vote(payload)
             self.vote_buf.setdefault(epoch, {})[src] = (c, a, bnd)
@@ -1157,91 +1143,19 @@ class ServerNode:
         self.tp.flush()
         os._exit(self._FD.FENCED_EXIT)
 
-    # -- admission (client_thread + new_txn_queue + abort_queue) ---------
-    def _contribution(self, epoch: int
-                      ) -> tuple[wire.QueryBlock, np.ndarray, np.ndarray]:
-        """Up to b_loc txns: ready retries first, then fresh arrivals.
-
-        Fresh arrivals get the home client's transport id packed into the
-        tag high bits (client << 40 | tag) and an epoch-anchored birth
-        timestamp ``(epoch+1)*b_merged + me*b_loc + position``: unique
-        across nodes AND monotone with epochs, so a (re)stamped txn always
-        exceeds every watermark the T/O family persisted in earlier epochs
-        — per-node counters would let a slow node starve behind a fast
-        node's watermarks.  Retried blocks keep their packed tags, and
-        keep their birth ts unless the backend wants restarts re-stamped
-        (CCBackend.fresh_ts_on_restart — WAIT_DIE preserves age, which is
-        its starvation-freedom) — and even then only entries whose last
-        verdict was an ABORT: deferred (waiting) txns keep their birth ts
-        like the in-process pool and the reference's parked requests.
-        Returns (block, abort_cnt, ts, defer_cnt)."""
-        blocks, counts, tss, abms, dfcs = self.retry.pop_ready(
-            epoch, self.b_loc)
-        if self.be.fresh_ts_on_restart:
-            # mark aborted retries for re-stamping (-1 = stamp me below)
-            tss = [np.where(ab, np.int64(-1), ts)
-                   for ts, ab in zip(tss, abms)]
-        n = sum(len(b) for b in blocks)
-        n_retry = n
-        while self.pending and n < self.b_loc:
-            src, blk = self.pending[0]
-            room = self.b_loc - n
-            if len(blk) <= room:
-                self.pending.popleft()
-                use = blk
-            else:
-                self.pending[0] = (src, blk.slice(room, len(blk)))
-                use = blk.slice(0, room)
-            packed = (np.int64(src) << 40) | (use.tags & _TAG_MASK)
-            blocks.append(wire.QueryBlock(use.keys, use.types, use.scalars,
-                                          packed))
-            counts.append(np.zeros(len(use), np.int32))
-            tss.append(np.full(len(use), -1, np.int64))   # -1 = stamp me
-            dfcs.append(np.zeros(len(use), np.int32))
-            n += len(use)
-        self._queue_txns -= n
-        if self.adm is not None and n > n_retry:
-            # admission-queue delay ledger: these fresh rows just left
-            # the bounded queue for epoch formation
-            self.adm.on_pop(n - n_retry, time.monotonic_ns() // 1000)
-        if not blocks:
-            blocks = [wire.QueryBlock.empty(self._width, self._n_scalars)]
-            counts = [np.zeros(0, np.int32)]
-            tss = [np.zeros(0, np.int64)]
-            dfcs = [np.zeros(0, np.int32)]
-        block = wire.QueryBlock.concat(blocks)
-        ts = np.concatenate(tss)
-        base = np.int64(epoch + 1) * self.b_merged + self.me * self.b_loc
-        stamped = base + np.arange(len(ts), dtype=np.int64)
-        if len(ts) and stamped[-1] >= 2**31:
-            raise RuntimeError(
-                "birth-timestamp horizon exceeded (2^31; ~2^31/epoch_batch "
-                "epochs); restart the run — the reference's 64-bit ts has "
-                "the same finite-horizon caveat at larger scale")
-        # fresh arrivals and (for fresh-ts backends) aborted restarts
-        # carry the -1 sentinel; deferred waiters keep their birth ts
-        ts = np.where(ts < 0, stamped, ts)
-        if len(ts) and ts.min() < 1:
-            # ts==0 is reserved as the MVCC read-only serialization
-            # sentinel (cc/timestamp.py order, ycsb.py ver_ts): a real
-            # txn stamped 0 would be misrouted to the live snapshot
-            raise RuntimeError(
-                f"birth timestamp below 1 (min={ts.min()}): the ts>=1 "
-                "stamping invariant is broken")
-        return block, np.concatenate(counts), ts, np.concatenate(dfcs)
-
-    # -- host-path pipeline (host_overlap): zero-copy assembly + staged
-    # host work.  Everything here is either PURE given its inputs (blob
-    # parts, record packing, plane unpacking) or runs at the exact loop
-    # position of the serial path — which is why overlap on/off produce
-    # bit-identical verdict planes and log bytes. ----------------------
+    # -- the host path of an epoch group, on ONE reusable set of flat feed
+    # buffers.  `_bcast_views`, `_log_group_views` and `_prefetch_retire`
+    # are PURE given their inputs, so `host_overlap` may hand them to a
+    # worker thread; the rest is the dispatch thread's, at its loop
+    # position. ----------------------------------------------------------
     def _feed_acquire(self) -> dict:
         """One reusable flat feed-buffer set [C, b, ...].  Only the
         active plane is re-zeroed here: every other lane is covered by
         exactly one per-server slice region, which its filler either
         overwrites or tail-zeroes (_contribution_into/_collect_into) —
-        so unfilled lanes still match the serial path's fresh np.zeros
-        buffers byte for byte without a full-buffer memset per group."""
+        so unfilled lanes of a reused buffer are zero, and every node
+        builds the same feed and log bytes, without a full-buffer memset
+        per group."""
         if self._feed_free:
             fs = self._feed_free.pop()
             fs["active"].fill(False)
@@ -1257,12 +1171,33 @@ class ServerNode:
             "active": np.zeros((C, b), bool),
         }
 
+    @staticmethod
+    def _zero_lanes(fs: dict, i: int, lo: int, hi: int) -> None:
+        """Unfilled lanes of a reused buffer read zero (and inactive), so
+        every node builds the same feed and log bytes."""
+        for k in ("keys", "types", "scal", "tags", "ts"):
+            fs[k][i, lo:hi] = 0
+
+    # admission (client_thread + new_txn_queue + abort_queue)
     def _contribution_into(self, epoch: int, fs: dict, i: int
                            ) -> tuple[wire.QueryBlock, np.ndarray,
                                       np.ndarray, np.ndarray]:
-        """``_contribution``'s admission policy (identical order and
-        stamping), writing each piece STRAIGHT into this node's slice of
-        feed row ``i`` — no ``QueryBlock.concat``, no second fill pass.
+        """Up to b_loc txns — ready retries first, then fresh arrivals —
+        written STRAIGHT into this node's slice of feed row ``i`` (no
+        ``QueryBlock.concat``, no second fill pass).
+
+        Fresh arrivals get the home client's transport id packed into the
+        tag high bits (client << 40 | tag) and an epoch-anchored birth
+        timestamp ``(epoch+1)*b_merged + me*b_loc + position``: unique
+        across nodes AND monotone with epochs, so a (re)stamped txn always
+        exceeds every watermark the T/O family persisted in earlier epochs
+        — per-node counters would let a slow node starve behind a fast
+        node's watermarks.  Retried blocks keep their packed tags, and
+        keep their birth ts unless the backend wants restarts re-stamped
+        (CCBackend.fresh_ts_on_restart — WAIT_DIE preserves age, which is
+        its starvation-freedom) — and even then only entries whose last
+        verdict was an ABORT: deferred (waiting) txns keep their birth ts
+        like the in-process pool and the reference's parked requests.
         Returns (view block, abort_cnt, birth-ts view, defer_cnt)."""
         lo = self.me * self.b_loc
         keys_r, types_r = fs["keys"][i], fs["types"][i]
@@ -1270,8 +1205,7 @@ class ServerNode:
         blocks, counts, tss, abms, dfcs = self.retry.pop_ready(
             epoch, self.b_loc)
         if self.be.fresh_ts_on_restart:
-            # re-stamp aborted retries only (deferred waiters keep their
-            # birth ts, exactly like _contribution)
+            # mark aborted retries for re-stamping (-1 = stamp me below)
             tss = [np.where(ab, np.int64(-1), ts)
                    for ts, ab in zip(tss, abms)]
         n = 0
@@ -1306,16 +1240,11 @@ class ServerNode:
             n += m
         self._queue_txns -= n
         if self.adm is not None and n > n_retry:
-            # same admission-delay ledger position as _contribution
+            # admission-queue delay ledger: these fresh rows just left
+            # the bounded queue for epoch formation
             self.adm.on_pop(n - n_retry, time.monotonic_ns() // 1000)
-        # zero the unfilled tail of my slice (reused buffer: these lanes
-        # must read as the serial path's np.zeros padding)
-        tail = slice(lo + n, lo + self.b_loc)
-        keys_r[tail] = 0
-        types_r[tail] = 0
-        scal_r[tail] = 0
-        tags_r[tail] = 0
-        ts_r[tail] = 0
+        # the unfilled tail of my slice (reused buffer)
+        self._zero_lanes(fs, i, lo + n, lo + self.b_loc)
         sl = slice(lo, lo + n)
         base = np.int64(epoch + 1) * self.b_merged + lo
         stamped = base + np.arange(n, dtype=np.int64)
@@ -1324,8 +1253,13 @@ class ServerNode:
                 "birth-timestamp horizon exceeded (2^31; ~2^31/epoch_batch "
                 "epochs); restart the run — the reference's 64-bit ts has "
                 "the same finite-horizon caveat at larger scale")
+        # fresh arrivals and (for fresh-ts backends) aborted restarts
+        # carry the -1 sentinel; deferred waiters keep their birth ts
         np.copyto(ts_r[sl], stamped, where=ts_r[sl] < 0)
         if n and ts_r[sl].min() < 1:
+            # ts==0 is reserved as the MVCC read-only serialization
+            # sentinel (cc/timestamp.py order, ycsb.py ver_ts): a real
+            # txn stamped 0 would be misrouted to the live snapshot
             raise RuntimeError(
                 f"birth timestamp below 1 (min={ts_r[sl].min()}): the "
                 "ts>=1 stamping invariant is broken")
@@ -1336,9 +1270,18 @@ class ServerNode:
         dfc = np.concatenate(dfcs) if dfcs else np.zeros(0, np.int32)
         return block, cnt, ts_r[sl], dfc
 
+    def _wire(self, fn, *args) -> Future:
+        """Run a wire body — on the wire worker or, without one, here
+        and now: its future either way (read at the group's retirement)."""
+        if self.wire_pool is not None:
+            return self.wire_pool.submit(fn, *args)
+        done: Future = Future()
+        done.set_result(fn(*args))
+        return done
+
     def _bcast_views(self, e: int, block: wire.QueryBlock,
                      birth_ts: np.ndarray) -> None:
-        """Wire-worker body: broadcast this node's contribution as
+        """Wire body: broadcast this node's contribution as
         scatter-gather parts (``dt_sendv``) — zero Python-side payload
         copies; the native layer frames header + ts + columns in one
         pass.  Failover mode materializes the bytes instead: the
@@ -1372,18 +1315,13 @@ class ServerNode:
             self._wait_blobs(e)
             t0 = time.monotonic()
             if self._elastic and self._contrib_gone:
-                # a retired contributor's slice must read as the serial
-                # path's np.zeros padding (reused buffer hygiene AND
-                # cross-node feed determinism)
+                # a retired contributor's slice reads zero and inactive
+                # on every node (reused buffer hygiene AND cross-node
+                # feed determinism)
                 for p, ge in self._contrib_gone.items():
                     if ge <= e:
                         o = p * self.b_loc
-                        hi = o + self.b_loc
-                        fs["keys"][i, o:hi] = 0
-                        fs["types"][i, o:hi] = 0
-                        fs["scal"][i, o:hi] = 0
-                        fs["tags"][i, o:hi] = 0
-                        fs["ts"][i, o:hi] = 0
+                        self._zero_lanes(fs, i, o, o + self.b_loc)
             for s, payload in self.blob_buf.pop(e, {}).items():
                 o = s * self.b_loc
                 hi = o + self.b_loc
@@ -1392,23 +1330,18 @@ class ServerNode:
                     fs["keys"][i, o:hi], fs["types"][i, o:hi],
                     fs["scal"][i, o:hi])
                 fs["active"][i, o:o + m] = True
-                if m < self.b_loc:
-                    # reused buffer: the short contribution's tail must
-                    # read as the serial path's np.zeros padding
-                    fs["keys"][i, o + m:hi] = 0
-                    fs["types"][i, o + m:hi] = 0
-                    fs["scal"][i, o + m:hi] = 0
-                    fs["tags"][i, o + m:hi] = 0
-                    fs["ts"][i, o + m:hi] = 0
+                # reused buffer: a short contribution's tail
+                self._zero_lanes(fs, i, o + m, hi)
             decode_s += time.monotonic() - t0
         return decode_s
 
     def _log_group_views(self, fs: dict, eps) -> None:
-        """Wire-worker body: one-pass framed record per epoch straight
-        from the merged feed row (``pack_record_views``), appended
-        locally and shipped to my replicas — identical bytes by
-        construction (one packing, two destinations), identical to the
-        serial path's ``pack_record(encode_epoch_blob(...))`` bytes."""
+        """Wire body: one-pass framed record per epoch straight from the
+        merged feed row (``pack_record_views``), appended locally and
+        shipped to my replicas — identical bytes by construction (one
+        packing, two destinations), and the bytes of
+        ``logger.pack_record(wire.encode_epoch_blob(...))``, which the
+        replica's packer and the replay read (fuzz-tested)."""
         from deneva_tpu.runtime.logger import pack_record_views
         for i, (e, _blk, _cnt, _ts, _dfc) in enumerate(eps):
             framed = pack_record_views(e, fs["ts"][i], fs["tags"][i],
@@ -1422,20 +1355,28 @@ class ServerNode:
                 self._fenced_send(r, "LOG_MSG", framed)
 
     def _prefetch_retire(self, group: dict):
-        """Retire-worker body: wait out the verdict d2h copy, unpack the
-        bit planes and precompute the PURE per-epoch retirement pieces
-        (committed tags, per-client ack splits, histogram increments).
-        The dispatch thread's _retire is left with state mutation and
-        sends only — at the same loop position as the serial path."""
+        """Retire body: wait out the verdict d2h copy, unpack the planes
+        and precompute the PURE per-epoch retirement pieces (committed
+        tags, per-client ack splits, histogram increments); `_retire` is
+        left with state mutation and sends.  Returns ``(done, abort,
+        defer, rep, acks)`` and the seconds the split took: on the
+        dispatch thread they are work inside its wait for the device."""
         import jax
 
         with stage_span("prefetch", group["eps"][0][0]):
-            pk = np.asarray(jax.device_get(group["masks"]))
-            planes = np.unpackbits(pk, axis=-1, bitorder="little")
-        bools = planes[:, :, :self._plane_n].astype(bool)
-        done, abort, defer = bools[0], bools[1], bools[2]
-        rep = bools[3] if self._repair else None
-        lo = self._plane_lo
+            masks = jax.device_get(group["masks"])
+            if group["packed"]:
+                # uint8 bit-planes [3 (+1 repaired), C, pb/8]; the d2h
+                # copy was started asynchronously at dispatch
+                planes = np.unpackbits(np.asarray(masks), axis=-1,
+                                       bitorder="little")
+                masks = planes[:, :, :self._plane_n].astype(bool)
+        t0 = time.monotonic()
+        done, abort, defer = masks[0], masks[1], masks[2]
+        # a VOTE epoch's three host masks are this node's slice already,
+        # and no repaired plane rides with them
+        rep = masks[3] if self._repair and group["packed"] else None
+        lo = self._plane_lo if group["packed"] else 0
         acks = []
         for i, (_e, block, abort_cnt, _ts, dfc) in enumerate(group["eps"]):
             n = len(block)
@@ -1443,16 +1384,19 @@ class ServerNode:
             if not my_commit.any():
                 acks.append(None)
                 continue
+            # tag high bits carry the home client's transport id
             tags = block.tags[my_commit]
             clients = tags >> 40
             rsp = [(int(c), tags[clients == c] & _TAG_MASK)
                    for c in np.unique(clients)]
+            # TxnStats analogue: whole-life restart/wait counts of each
+            # committed txn (clipped to the 8-bucket family)
             retry_inc = np.bincount(np.minimum(abort_cnt[my_commit], 7),
                                     minlength=8)
             wait_inc = np.bincount(np.minimum(dfc[:n][my_commit], 7),
                                    minlength=8)
             acks.append((tags, rsp, retry_inc, wait_inc))
-        return done, abort, defer, rep, acks
+        return done, abort, defer, rep, acks, time.monotonic() - t0
 
     def _durable_through(self) -> int:
         """Highest epoch that is on disk locally AND acked by every one of
@@ -1965,8 +1909,8 @@ class ServerNode:
                 f"server {self.me}: slot reassignment needs --logging "
                 "(acquired rows are rebuilt by log replay)")
         # records for every epoch < stop_epoch were appended at their
-        # group's dispatch; drain in-flight wire submissions (overlap
-        # rides the wire worker) before waiting out the flush
+        # group's dispatch; drain in-flight wire submissions (they may
+        # ride the wire worker) before waiting out the flush
         for g in getattr(self, "_inflight", ()):
             for f in g.get("wire_futs", ()):
                 f.result()
@@ -2164,41 +2108,30 @@ class ServerNode:
         CL_RSP acks, retry/backoff routing, exact unique-abort counts."""
         import jax
 
-        # blocked on the device's verdicts (the prefetch future or the
-        # d2h itself): the host waiting for the device
+        # blocked on the device's verdicts (the retire worker's future,
+        # or the same body called here): the host waiting for the device
         epoch0 = group["eps"][0][0]
         t_wait = self.clk.enter("retire_wait", epoch0)
-        pre = None
-        rep = None
-        if group.get("prefetch") is not None:
-            # host pipeline: the retire worker already waited the d2h,
-            # unpacked the planes and split the ack payloads while later
-            # groups were dispatching — collect the finished result.
-            # A future that is done BEFORE we ask proves the d2h +
-            # unpack genuinely overlapped device execution of the later
-            # groups (the [mesh] line's prefetch_overlap ratio); one
-            # that is not makes this .result() the serial wait the
-            # prefetch was supposed to hide.
-            self._prefetch_polls += 1
-            if group["prefetch"].done():
-                self._prefetch_hits += 1
-            done, abort, defer, rep, pre = group["prefetch"].result()
-        elif group["packed"]:
-            # uint8 bit-planes [3 (+1 repaired), C, pb/8]; the d2h copy
-            # was started asynchronously at dispatch, so this normally
-            # returns fast
-            pk = np.asarray(jax.device_get(group["masks"]))
-            planes = np.unpackbits(pk, axis=-1, bitorder="little")
-            bools = planes[:, :, :self._plane_n].astype(bool)
-            done, abort, defer = bools[0], bools[1], bools[2]
-            if self._repair:
-                rep = bools[3]
+        fut = group["prefetch"]
+        if fut is None:
+            done, abort, defer, rep, acks, split_s = \
+                self._prefetch_retire(group)
         else:
-            done, abort, defer = (np.asarray(m)
-                                  for m in jax.device_get(group["masks"]))
-        # the work after the wait: ack splits, CL_RSP sends, retry routing
+            # the retire worker waited the d2h, unpacked the planes and
+            # split the ack payloads while later groups were dispatching.
+            # A future that is done BEFORE we ask proves the d2h + unpack
+            # genuinely overlapped device execution of the later groups
+            # (the [mesh] line's prefetch_overlap ratio); one that is not
+            # makes this .result() the wait the prefetch was to hide.
+            self._prefetch_polls += 1
+            if fut.done():
+                self._prefetch_hits += 1
+            done, abort, defer, rep, acks, _ = fut.result()
+        # the work after the wait: CL_RSP sends, retry routing
         t_retire = self.clk.enter("retire", epoch0)
-        if group.get("prefetch") is not None:
+        if fut is None:
+            self.clk.shift("retire_wait", "retire", split_s)
+        else:
             # the mesh track's wait ledger: this group's `retire_wait`
             self._prefetch_wait_s += t_retire - t_wait
         dens = None
@@ -2248,31 +2181,10 @@ class ServerNode:
                             self._quorum_hold_t.setdefault(
                                 epoch, time.monotonic())
                         self._held_commit.append((epoch, ids))
-            if pre is not None:
-                if pre[i] is not None:
-                    tags, rsp_split, retry_inc, wait_inc = pre[i]
-                    self._retry_hist += retry_inc
-                    self._wait_hist += wait_inc
-                    if self._dedup_on and self.logger is None:
-                        self._retire_dedup(tags)
-                    for c, masked in rsp_split:
-                        if self.logger is None:
-                            self.tp.sendv(c, "CL_RSP",
-                                          wire.cl_rsp_parts(masked))
-                        else:
-                            if self._geo:
-                                self._quorum_hold_t.setdefault(
-                                    epoch, time.monotonic())
-                            self._held_rsp.append((c, epoch, masked))
-            elif my_commit.any():
-                # TxnStats analogue: whole-life restart/wait counts of
-                # each committed txn (clipped to the 8-bucket family)
-                self._retry_hist += np.bincount(
-                    np.minimum(abort_cnt[my_commit], 7), minlength=8)
-                self._wait_hist += np.bincount(
-                    np.minimum(dfc[:n][my_commit], 7), minlength=8)
-                # tag high bits carry the home client's transport id
-                tags = block.tags[my_commit]
+            if acks[i] is not None:
+                tags, rsp_split, retry_inc, wait_inc = acks[i]
+                self._retry_hist += retry_inc
+                self._wait_hist += wait_inc
                 if self._dedup_on and self.logger is None:
                     # without logging the ack goes out right below; with
                     # logging the committed-set entry (and its re-ack
@@ -2281,18 +2193,16 @@ class ServerNode:
                     # ids at release time, or a resend could extract an
                     # early re-ack for a txn a crash then truncates away
                     self._retire_dedup(tags)
-                clients = tags >> 40
-                for c in np.unique(clients):
-                    rsp = (int(c), epoch, tags[clients == c] & _TAG_MASK)
+                for c, masked in rsp_split:
                     if self.logger is None:
-                        self.tp.send(rsp[0], "CL_RSP",
-                                     wire.encode_cl_rsp(rsp[2]))
+                        self.tp.sendv(c, "CL_RSP",
+                                      wire.cl_rsp_parts(masked))
                     else:
                         # group commit: hold until epoch is durable
                         if self._geo:
                             self._quorum_hold_t.setdefault(
                                 epoch, time.monotonic())
-                        self._held_rsp.append(rsp)
+                        self._held_rsp.append((c, epoch, masked))
             ab = abort[i, lo:lo + n]
             df = defer[i, lo:lo + n]
             if self.defer_budget:
@@ -2373,35 +2283,21 @@ class ServerNode:
                                 defer_cnt=np.where(
                                     ab, 0, dfc[:n] + df)[idx])
         self._flush_held_rsp()
-        # host pipeline: surface wire-worker errors and recycle the feed
-        # buffer set — the mask fetch above proved the device consumed
-        # its inputs, and the drained wire futures prove the blob/log
-        # sends no longer reference the rows
-        for f in group.get("wire_futs", ()):
+        # surface wire-worker errors and recycle the feed buffer set —
+        # the mask fetch above proved the device consumed its inputs, and
+        # the drained wire futures prove the blob/log sends no longer
+        # reference the rows
+        for f in group["wire_futs"]:
             f.result()
-        if group.get("feed") is not None:
+        if group["feed"] is not None:
             self._feed_free.append(group["feed"])
         self.clk.enter("other")
         self.clk.retired(group["t_dispatch"])
 
     # -- the pipelined epoch-group loop ----------------------------------
-    def run(self, progress=None) -> Stats:
-        """Epoch-group pipeline.
-
-        The round-1 loop was fully synchronous — admit, broadcast,
-        collect, device step, fetch masks, respond — paying 2-4
-        host<->device round trips per epoch, each far longer than the
-        device step itself.  Now C = ``pipeline_epochs``
-        merged epochs form ONE device dispatch (`make_dist_group`), K =
-        ``pipeline_groups`` dispatches stay in flight, and a group's
-        commit-mask fetch happens only after the NEXT group is dispatched
-        — so admission, blob exchange, and codec work for epochs e+C..
-        overlap the device execution of epochs e..e+C-1.  This is the
-        reference's sequencer-thread vs worker-thread decoupling
-        (`system/calvin_thread.cpp:102-170`) rebuilt on async dispatch.
-        Retries re-enter up to K*C epochs later than synchronously —
-        the same kind of delay the reference's abort queue imposes.
-        """
+    def _warm(self) -> None:
+        """Before the loop: compile every shape it will use, then meet
+        the peers (the INIT_DONE barrier, or a recovered node's rejoin)."""
         import jax
         import jax.numpy as jnp
 
@@ -2413,7 +2309,7 @@ class ServerNode:
             # tools/graftlint's ownership checker)
             from deneva_tpu.runtime import ownercheck
             ownercheck.install(self)
-        b, C, K = self.b_merged, self.C, self.K
+        b, C = self.b_merged, self.C
         W, S = self._width, self._n_scalars
         # compile before the barrier so no node's first epoch stalls the
         # lockstep (reference: setup/warmup barriers, system/thread.cpp:62-84).
@@ -2468,6 +2364,30 @@ class ServerNode:
                 cfg, [p for p in range(self.n_srv) if p != self.me],
                 time.monotonic())
         self._t_run0 = time.monotonic()
+
+    def run(self, progress=None) -> Stats:
+        """The served loop: a pass per epoch GROUP through the stage
+        clock's stages — drain, admit, collect, feed, dispatch, then
+        retire_wait + retire of the group K back, other.
+
+        C = ``pipeline_epochs`` merged epochs form ONE device dispatch
+        (`make_dist_group`), K = ``pipeline_groups`` dispatches stay in
+        flight, and a group's commit-mask fetch happens only after the
+        NEXT group is dispatched — so admission, blob exchange and codec
+        work for epochs e+C.. overlap the device execution of epochs
+        e..e+C-1, where a synchronous loop pays 2-4 host<->device round
+        trips an epoch, each far longer than the device step.  This is
+        the reference's sequencer-thread vs worker-thread decoupling
+        (`system/calvin_thread.cpp:102-170`) rebuilt on async dispatch.
+        Retries re-enter up to K*C epochs later than synchronously —
+        the same kind of delay the reference's abort queue imposes.
+        """
+        import jax
+        import jax.numpy as jnp
+
+        cfg = self.cfg
+        C, K = self.C, self.K
+        self._warm()
         if self.mbus is not None:
             # re-anchor the critical-path ledger NOW: jit compile +
             # barrier time is setup, not epoch wall
@@ -2499,9 +2419,9 @@ class ServerNode:
                 # that boundary" (torn tails are exercised separately:
                 # recovery truncates them, tests/test_chaos.py).
                 if self.logger is not None and epoch0 > 0:
-                    # under overlap the log records ride the wire
-                    # worker: drain the in-flight groups' submissions so
-                    # the appends exist before waiting on the flush
+                    # the log records may ride the wire worker: drain
+                    # the in-flight groups' submissions so the appends
+                    # exist before waiting on the flush
                     for g in inflight:
                         for f in g.get("wire_futs", ()):
                             f.result()
@@ -2565,80 +2485,32 @@ class ServerNode:
             clk.enter("admit")
             eps: list[tuple[int, wire.QueryBlock, np.ndarray, np.ndarray,
                             np.ndarray]] = []
-
-            def _bcast(e, block, birth_ts):
-                # pure given its inputs; peers key blob_buf by epoch so
-                # cross-epoch arrival order is free, and dt_send is
-                # thread-safe (MPMC queues)
-                blob = wire.encode_epoch_blob(e, block, birth_ts)
-                if self._failover:
-                    # retained for verbatim resend to a rejoining peer
-                    # (raw: a fencing REJOIN resend re-wraps with the
-                    # then-current map version)
-                    with self._sent_lock:
-                        self._sent_blobs.append((e, blob))
-                for p in range(self.n_srv):
-                    if p != self.me:
-                        self._fenced_send(p, "EPOCH_BLOB", blob)
-
-            fs = None
+            # admission writes straight into the reusable flat feed
+            # buffers; each blob goes out (on the ordered wire worker,
+            # where there is one) while the NEXT epoch's admission and,
+            # below, the device group proceed
+            fs = self._feed_acquire()
             wire_futs: list = []
-            if self._overlap:
-                # host pipeline: admission writes straight into the
-                # reusable flat feed buffers; the ordered wire worker
-                # encodes + broadcasts each blob while the NEXT epoch's
-                # admission (and, below, the device group) proceeds
-                fs = self._feed_acquire()
-                for i in range(C):
-                    e = epoch0 + i
-                    if i:
-                        with stage_span("drain", epoch0):
-                            self._drain()
-                    block, abort_cnt, birth_ts, dfc = \
-                        self._contribution_into(e, fs, i)
-                    if self.tel is not None:
-                        # epoch-batch assignment hop (retries re-record
-                        # at their re-entry epoch — the span tree keeps
-                        # the committing pass's batch)
-                        self.tel.record(block.tags, ST_BATCH, epoch=e)
-                    if self.n_srv > 1:
-                        wire_futs.append(self.wire_pool.submit(
-                            self._bcast_views, e, block, birth_ts))
-                    eps.append((e, block, abort_cnt, birth_ts, dfc))
+            for i in range(C):
+                e = epoch0 + i
+                if i:
+                    with stage_span("drain", epoch0):
+                        self._drain()
+                block, abort_cnt, birth_ts, dfc = \
+                    self._contribution_into(e, fs, i)
+                if self.tel is not None:
+                    # epoch-batch assignment hop (retries re-record at
+                    # their re-entry epoch — the span tree keeps the
+                    # committing pass's batch)
+                    self.tel.record(block.tags, ST_BATCH, epoch=e)
                 if self.n_srv > 1:
-                    # peers block on these blobs: push them onto the
-                    # wire behind the group's last bcast (FIFO worker)
-                    wire_futs.append(self.wire_pool.submit(self.tp.flush))
-            else:
-                futs = []
-                try:
-                    for i in range(C):
-                        e = epoch0 + i
-                        if i:
-                            with stage_span("drain", epoch0):
-                                self._drain()
-                        block, abort_cnt, birth_ts, dfc = \
-                            self._contribution(e)
-                        if self.tel is not None:
-                            # same epoch-batch hop, serial path
-                            self.tel.record(block.tags, ST_BATCH, epoch=e)
-                        if self.codec_pool is not None and self.n_srv > 1:
-                            futs.append(self.codec_pool.submit(
-                                _bcast, e, block, birth_ts))
-                        else:
-                            _bcast(e, block, birth_ts)
-                        eps.append((e, block, abort_cnt, birth_ts, dfc))
-                finally:
-                    # drain in-flight _bcast sends before any exception
-                    # can unwind past self.tp teardown (they hold the
-                    # native transport; an abandoned future would race
-                    # the close)
-                    if futs:
-                        from concurrent.futures import wait as _futs_wait
-                        _futs_wait(futs)
-                for f in futs:
-                    f.result()   # surface any _bcast error after the drain
-                self.tp.flush()
+                    wire_futs.append(self._wire(self._bcast_views, e, block,
+                                                birth_ts))
+                eps.append((e, block, abort_cnt, birth_ts, dfc))
+            if self.n_srv > 1:
+                # peers block on these blobs: push them onto the wire
+                # behind the group's last bcast (FIFO worker)
+                wire_futs.append(self._wire(self.tp.flush))
             # ---- stage: collect every peer's contributions (on the
             # `[crit]` ledger everything since the last pass closed —
             # inbound drain, heartbeats, contribution assembly, admission,
@@ -2646,95 +2518,36 @@ class ServerNode:
             # blob-collect wait its wire stage: peer skew + network
             # transit show up exactly here) ------------------------------
             clk.enter("collect")
-            decode_s = 0.0
-            if self._overlap:
-                decode_s = self._collect_into(eps, fs)
-            else:
-                merged_parts = []
-                for e, block, _, birth_ts, _ in eps:
-                    self._wait_blobs(e)
-                    parts = self.blob_buf.pop(e, {})
-                    parts[self.me] = (block, birth_ts)
-                    merged_parts.append(parts)
-            # ---- stage: feed — build the stacked device feed [C, b],
+            decode_s = self._collect_into(eps, fs)
+            # ---- stage: feed — the stacked device feed [C, b] is `fs`,
             # submit the log records ------------------------------------
             clk.enter("feed")
             # decode work is feed building, not network wait
             clk.shift("collect", "feed", decode_s)
-            if self._overlap:
-                keys, types, scal = fs["keys"], fs["types"], fs["scal"]
-                tags, ts_np, active_np = fs["tags"], fs["ts"], fs["active"]
-            else:
-                keys = np.zeros((C, b, self._width), np.int32)
-                types = np.zeros((C, b, self._width), np.int8)
-                scal = np.zeros((C, b, self._n_scalars), np.int32)
-                tags = np.zeros((C, b), np.int64)
-                ts_np = np.zeros((C, b), np.int64)
-                active_np = np.zeros((C, b), bool)
-                def _fill(i, parts):
-                    # disjoint row i of every feed buffer: pool-safe.
-                    # A retired elastic contributor has no part — its
-                    # slice stays the fresh buffer's zeros/inactive.
-                    for s in range(self.n_srv):
-                        if s not in parts:
-                            continue
-                        blk_s, ts_s = parts[s]
-                        o = s * self.b_loc
-                        n = len(blk_s)
-                        keys[i, o:o + n] = blk_s.keys
-                        types[i, o:o + n] = blk_s.types
-                        scal[i, o:o + n] = blk_s.scalars
-                        tags[i, o:o + n] = blk_s.tags
-                        ts_np[i, o:o + n] = ts_s
-                        active_np[i, o:o + n] = True
-
-                if self.codec_pool is not None:
-                    list(self.codec_pool.map(_fill, range(C), merged_parts))
-                else:
-                    for i, parts in enumerate(merged_parts):
-                        _fill(i, parts)
             if self.logger is not None:
                 # command log: the MERGED epoch block + active mask is
                 # the log record — deterministic replay = re-execution
                 # of the full command stream; ship the same record to
                 # my replica (LOG_MSG, SURVEY §5.4).  Logged at
                 # dispatch: verdicts are a pure function of the record.
-                if self._overlap:
-                    # identical bytes, packed once off the dispatch
-                    # thread (pack_record_views == pack_record of the
-                    # encoded blob, fuzz-tested)
-                    wire_futs.append(self.wire_pool.submit(
-                        self._log_group_views, fs, eps))
-                else:
-                    from deneva_tpu.runtime.logger import pack_record
-                    for i in range(C):
-                        e = eps[i][0]
-                        merged = wire.QueryBlock(keys[i], types[i],
-                                                 scal[i], tags[i])
-                        rec = wire.encode_epoch_blob(e, merged, ts_np[i])
-                        # LOG_MSG payload = the framed record verbatim,
-                        # so each replica's log file is byte-identical
-                        # to the primary's by construction (one packing,
-                        # two destinations)
-                        framed = pack_record(e, rec, active_np[i])
-                        self.logger.append(e, rec, active_np[i],
-                                           framed=framed)
-                        for r in self.repl_ids:
-                            self._fenced_send(r, "LOG_MSG", framed)
+                wire_futs.append(self._wire(self._log_group_views, fs, eps))
             # ---- dispatch (async for merged mode; the masks are fetched
             # at retirement, K groups later) ----------------------------
             # stage: dispatch (`device_put` + the group call + the d2h
             # starts; `[crit]` charges feed + dispatch to its device
             # stage: a recompile spike is the jit watchdog's signature)
             t_dispatch = clk.enter("dispatch")
+            # the device's int32 timestamps, in the preallocated shadow
+            np.copyto(fs["ts32"], fs["ts"], casting="unsafe")
             if self.vote_mode:
                 # C == K == 1: the vote exchange is a host round trip
                 # inside the epoch, so this path stays synchronous
-                query = self.wl.from_wire(keys[0], types[0], scal[0])
-                active_j = jnp.asarray(active_np[0])
-                ts_j = jnp.asarray(ts_np[0].astype(np.int32))
+                query = self.wl.from_wire(fs["keys"][0], fs["types"][0],
+                                          fs["scal"][0])
+                active_np = fs["active"][0]
                 commit, abort, defer = self._vote_epoch(
-                    eps[0][0], query, active_np[0], active_j, ts_j, tl)
+                    eps[0][0], query, active_np, jnp.asarray(active_np),
+                    jnp.asarray(fs["ts32"][0]), tl)
                 lo = self.me * self.b_loc
                 mine = slice(lo, lo + self.b_loc)
                 masks = (commit[None, mine], abort[None, mine],
@@ -2752,17 +2565,10 @@ class ServerNode:
                 # jit call makes the transfer part of the dispatch
                 # instead of an async copy that overlaps the feed build
                 # of the next group.
-                if self._overlap:
-                    # preallocated int32 shadow instead of a fresh
-                    # astype allocation per group
-                    np.copyto(fs["ts32"], ts_np, casting="unsafe")
-                    ts32 = fs["ts32"].reshape(-1)
-                else:
-                    ts32 = ts_np.astype(np.int32).reshape(-1)
-                feed = jax.device_put(
-                    (active_np.reshape(-1), ts32,
-                     keys.reshape(-1), types.reshape(-1),
-                     scal.reshape(-1)), self._feed_sharding)
+                feed = jax.device_put(tuple(
+                    fs[k].reshape(-1) for k in (
+                        "active", "ts32", "keys", "types", "scal")),
+                    self._feed_sharding)
                 if self.aud is not None:
                     # audit epoch labels for this group's scan slices
                     feed = feed + (jax.device_put(np.arange(
@@ -2797,15 +2603,19 @@ class ServerNode:
                 if hasattr(masks, "copy_to_host_async"):
                     masks.copy_to_host_async()
             clk.enter("other")
+            # the feed set recycles once a mask fetch has proved that the
+            # device consumed it; a VOTE epoch's masks are the host's and
+            # prove nothing, so its set is not reused
             group = {"eps": eps, "masks": masks, "packed": packed,
-                     "feed": fs, "wire_futs": wire_futs,
+                     "feed": fs if packed else None,
+                     "wire_futs": wire_futs, "prefetch": None,
                      "dens_dev": dens_dev, "aud_dev": aud_dev,
                      "t_dispatch": t_dispatch}
             if self._full_planes and packed:
                 # full-plane retirement needs every slice's packed tags
-                # (copied: overlap feed buffers recycle under the group)
-                group["all_tags"] = tags.copy()
-            if self._overlap:
+                # (copied: the feed buffers recycle under the group)
+                group["all_tags"] = fs["tags"].copy()
+            if self.retire_pool is not None:
                 # hand the verdict-plane fetch to the retire worker now:
                 # by the time this group's turn to retire comes (K groups
                 # later) the planes and ack splits are already unpacked
@@ -2927,6 +2737,17 @@ class ServerNode:
                 break
             epoch0 += C
         clk.end()
+        return self._summarise(clk, measured, epoch0)
+
+    def _summarise(self, clk: StageClock, measured: dict | None,
+                   epoch0: int) -> Stats:
+        """After the loop (its last group began at ``epoch0``): release
+        what is held, tell clients and replicas, and reduce the window's
+        counters (from the ``measured`` snapshot on) to `[summary]`, the
+        `[device]` record and each armed plane's tagged line."""
+        import jax
+
+        cfg, C = self.cfg, self.C
         # the stage clock's WINDOW values and the window's wall on
         # readings of its own (an empty window when the measurement
         # never began, like the counters below)
@@ -2983,7 +2804,7 @@ class ServerNode:
             window_compile_cnt=n_comp - self._compiles_meas,
             run_commit_cnt=int(final["total_txn_commit_cnt"]),
             run_abort_cnt=int(final["total_txn_abort_cnt"]))
-        from deneva_tpu.runtime.logger import state_digests
+        from deneva_tpu.runtime.logger import state_digest, state_digests
         if cfg.logging:
             # the table as the device holds it after the last logged
             # epoch: a replay of the command log on any backend must
@@ -3245,12 +3066,9 @@ class ServerNode:
         return st
 
     def close(self) -> None:
-        if self.codec_pool is not None:
-            # wait: an in-flight _bcast still holds self.tp; destroying
-            # the native transport under it would be a use-after-free
-            self.codec_pool.shutdown(wait=True)
         if self.wire_pool is not None:
-            # same use-after-free hazard: wire-worker sends hold self.tp
+            # wait: an in-flight wire body still holds self.tp; destroying
+            # the native transport under it would be a use-after-free
             self.wire_pool.shutdown(wait=True)
         if self.retire_pool is not None:
             self.retire_pool.shutdown(wait=True)

@@ -28,6 +28,10 @@ def test_config_from_args_roundtrip():
 def test_config_rejects_unknown_and_bad():
     with pytest.raises(ValueError):
         Config.from_args(["--nonsense=1"])
+    # a field that went with its only reader (the host codec pool) fails
+    # like any unknown one, never silently ignored
+    with pytest.raises(ValueError, match="unknown config field"):
+        Config.from_args(["--thread_cnt=2"])
     with pytest.raises(ValueError):
         Config(epoch_batch=1000).validate()  # not a power of two
     with pytest.raises(ValueError):

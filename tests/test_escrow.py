@@ -290,7 +290,7 @@ def test_escrow_timestamp_cross_epoch_watermarks():
     assert np.asarray(v.abort)[0], "delta behind a committed read aborts"
 
 
-# ---- the floor smoke (tier-1 slow marker set; tools/smoke_escrow.sh) ---
+# ---- the floor smoke (tier-1 slow marker set; tools/smoke.sh escrow) ---
 
 @pytest.mark.slow
 @pytest.mark.parametrize("alg", ["NO_WAIT", "TIMESTAMP", "OCC"])

@@ -509,4 +509,4 @@ def test_ownercheck_owner_map_covers_guarded():
     assert set(oc.GUARDED) <= set(oc.OWNER)
     assert all(oc.OWNER[a] == oc.DISPATCH for a in oc.GUARDED)
     for role in oc.WORKER_ENTRY:
-        assert role in (oc.WIRE, oc.RETIRE, oc.CODEC)
+        assert role in (oc.WIRE, oc.RETIRE)

@@ -362,7 +362,8 @@ def test_wait_die_preserves_birth_ts_across_restarts():
             birth = np.array([7, 9, 11, 13], np.int64)
             node.retry.push(blk, np.full(4, int(aborted), np.int32), birth,
                             epoch=0, aborted=np.full(4, aborted, bool))
-            _, _, ts, _ = node._contribution(epoch=5)
+            _, _, ts, _ = node._contribution_into(
+                5, node._feed_acquire(), 0)
             return birth, ts
         finally:
             node.close()
@@ -588,7 +589,7 @@ def _drive_overlap_run(tmp_path, overlap: bool, vote: bool = False) -> dict:
                     host_overlap="on" if overlap else "off",
                     # arm the thread-ownership runtime asserts on BOTH
                     # sides: with overlap on, the wire/retire workers run
-                    # for real against the guards (any staged-work
+                    # for real against the guards (any worker-side
                     # mutation of dispatch-owned state raises), and the
                     # on==off byte-compare doubles as proof the guards
                     # themselves change nothing
@@ -676,13 +677,14 @@ def _drive_overlap_run(tmp_path, overlap: bool, vote: bool = False) -> dict:
 
 
 def test_host_overlap_bit_identical(tmp_path):
-    """The host-path pipeline acceptance bar: host_overlap=off (the
-    pre-pipeline serial loop) and =on (staged wire/retire workers,
-    zero-copy assembly) must produce bit-identical command logs,
-    byte-identical replica logs, identical replayed-state digests and
-    the same acked-tag multiset — under a backend that aborts and
-    retries (NO_WAIT at zipf 0.9), so the retirement->admission feedback
-    path is exercised, not just the happy path."""
+    """The property the host path rests on: host_overlap=off (the wire
+    and retire bodies called inline on the dispatch thread) and =on
+    (the same bodies on their worker threads) must produce bit-identical
+    command logs, byte-identical replica logs, identical replayed-state
+    digests and the same acked-tag multiset — the THREADING changes
+    nothing — under a backend that aborts and retries (NO_WAIT at zipf
+    0.9), so the retirement->admission feedback path is exercised, not
+    just the happy path."""
     on = _drive_overlap_run(tmp_path, True)
     off = _drive_overlap_run(tmp_path, False)
     assert len(on["log"]) > 0

@@ -21,7 +21,7 @@ definitions):
   and the fault-mask classification must agree with one declared model
   (`wiremodel.py`).
 * **own**    — thread-ownership of ServerNode state (dispatch / wire
-  worker / retire worker / codec pool): no worker writes state it does
+  worker / retire worker): no worker writes state it does
   not own (`deneva_tpu/runtime/ownercheck.py` is the declarations
   file; the same decls drive the `owner_check=true` runtime asserts).
 * **gate**   — default-off subsystems (geo/elastic/admission/fault)

@@ -7,12 +7,12 @@ ownercheck.py` — pure data, stdlib-only) so the linter and the
 Rules
 -----
 own-cross-thread-write  a function reachable from a worker entry point
-                        (wire worker / retire worker / codec pool)
-                        writes a ServerNode attribute owned by a
-                        different role.  The host-pipeline bit-identity
-                        contract is that workers stage PURE work; all
-                        state mutation stays at the dispatch thread's
-                        serial-loop positions.
+                        (wire worker / retire worker) writes a
+                        ServerNode attribute owned by a different role.
+                        The host path's bit-identity contract is that
+                        the bodies a worker may run are PURE; all state
+                        mutation stays at the dispatch thread's loop
+                        positions.
 own-undeclared-attr     a ServerNode attribute is assigned somewhere but
                         missing from the OWNER map — the declarations
                         file must stay exhaustive or the checker (and
@@ -48,7 +48,7 @@ def _self_attr_of(node: ast.AST) -> str | None:
 
 def _class_functions(mod, class_name: str) -> dict[str, list[ast.AST]]:
     """All function defs lexically inside a class (methods AND functions
-    nested in methods — the codec-pool closures), by name."""
+    nested in methods), by name."""
     out: dict[str, list[ast.AST]] = {}
     for fn, cls in walk_funcs(mod.tree):
         if cls == class_name:

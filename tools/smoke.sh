@@ -1,11 +1,10 @@
 #!/usr/bin/env bash
 # Shared smoke-gate runner: ONE timeout/reporting path for every timed
-# gate (the former smoke_chaos.sh / smoke_escrow.sh / smoke_overlap.sh
-# are now thin delegates into this script).
+# gate.
 #
 #   tools/smoke.sh chaos [scenario ...]   chaos harness (default lossy-net)
 #   tools/smoke.sh escrow                 TPC-C escrow floor gate
-#   tools/smoke.sh overlap                host-pipeline bit-identity + wirebench
+#   tools/smoke.sh overlap                host path: workers == inline, byte for byte
 #   tools/smoke.sh elastic                membership gate: elastic-grow /
 #                                         elastic-drain / elastic-kill-reassign
 #                                         (liveness + exactly-once invariants)
@@ -141,7 +140,6 @@ case "$SCEN" in
     run "$T" python -m pytest tests/test_wire_zero_copy.py \
         "tests/test_runtime.py::test_host_overlap_bit_identical" \
         -q -p no:cacheprovider
-    run "$T" python tools/wirebench.py --out /tmp/wirebench_smoke
     ;;
   elastic)
     T="${SMOKE_TIMEOUT_SECS:-${ELASTIC_TIMEOUT_SECS:-600}}"
