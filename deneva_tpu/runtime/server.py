@@ -1330,8 +1330,8 @@ class ServerNode:
                     fs["keys"][i, o:hi], fs["types"][i, o:hi],
                     fs["scal"][i, o:hi])
                 fs["active"][i, o:o + m] = True
-                # reused buffer: a short contribution's tail
-                self._zero_lanes(fs, i, o + m, hi)
+                if m < self.b_loc:      # reused buffer: a short one's tail
+                    self._zero_lanes(fs, i, o + m, hi)
             decode_s += time.monotonic() - t0
         return decode_s
 
@@ -2737,17 +2737,16 @@ class ServerNode:
                 break
             epoch0 += C
         clk.end()
-        return self._summarise(clk, measured, epoch0)
+        return self._summarise(measured, epoch0)
 
-    def _summarise(self, clk: StageClock, measured: dict | None,
-                   epoch0: int) -> Stats:
+    def _summarise(self, measured: dict | None, epoch0: int) -> Stats:
         """After the loop (its last group began at ``epoch0``): release
         what is held, tell clients and replicas, and reduce the window's
         counters (from the ``measured`` snapshot on) to `[summary]`, the
         `[device]` record and each armed plane's tagged line."""
         import jax
 
-        cfg, C = self.cfg, self.C
+        cfg, C, clk = self.cfg, self.C, self.clk
         # the stage clock's WINDOW values and the window's wall on
         # readings of its own (an empty window when the measurement
         # never began, like the counters below)

@@ -311,6 +311,26 @@ def test_self_fence_writes_sidecar_and_exits_18(tmp_path, monkeypatch):
         node.close()
 
 
+def test_fenced_run_ends_on_its_sidecar(tmp_path):
+    """A whole served run with fencing armed reaches its closing: the
+    fencing counters in `[summary]` and `node<N>.fencing.json` — the
+    table's digest under the FINAL map, which the chaos harness audits
+    against an independent replay."""
+    from deneva_tpu.runtime.logger import state_digest
+
+    node = _fencing_server("fence_whole_run", tmp_path)
+    try:
+        st = node.run()
+        assert st.counters["suspect_cnt"] == 0
+        with open(os.path.join(str(tmp_path), "node0.fencing.json")) as f:
+            side = json.load(f)
+        assert side["state_digest"] == state_digest(node.db)
+        assert side["epochs_run"] == st.counters["epoch_cnt"] > 0
+        assert side["map_version"] == 0 and side["reassign_epoch"] == -1
+    finally:
+        node.close()
+
+
 # ---- end-to-end scenario (the smoke gate runs all four) ----------------
 
 @pytest.mark.slow
